@@ -19,47 +19,6 @@ Design (TPU-first, not a port):
 
 __version__ = "0.1.0"
 
-# --- jax API compatibility -------------------------------------------
-# The codebase targets the post-0.6 jax surface (`jax.shard_map`,
-# `jax.typeof`, `jax.lax.pcast` and the vma "varying" type system).
-# Older jax (e.g. 0.4.x, where shard_map still lives under
-# jax.experimental and there is no vma typing) lacks all three; install
-# equivalents so every engine module — and the tests that call
-# `jax.shard_map` directly — run unchanged on either version:
-#   shard_map — re-exported from jax.experimental.shard_map.
-#   typeof    — the abstract value (no `vma` attribute; every use site
-#               already guards with getattr(..., "vma", frozenset())).
-#   pcast     — identity. pcast only DECLARES an array varying over a
-#               manual axis for the vma checker; without the checker
-#               the declaration has nothing to inform.
-import jax as _jax
-
-# True when running on pre-vma jax through the shims below. Tests that
-# pin BITWISE cross-lane invariants consult this: the contracts hold
-# exactly on the target jax, and to one ulp under the older XLA.
-JAX_COMPAT_SHIMS = not hasattr(_jax, "shard_map")
-
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _jax.shard_map = _shard_map
-if not hasattr(_jax, "typeof"):
-    _jax.typeof = _jax.core.get_aval
-if not hasattr(_jax.lax, "pcast"):
-    def _pcast_identity(x, axes=None, to=None):
-        return x
-
-    _jax.lax.pcast = _pcast_identity
-if not hasattr(_jax.lax, "axis_size"):
-    # static mesh-axis size inside shard_map; the pre-0.6 spelling is
-    # the (internal) axis env — returns the same python int
-    from jax._src import core as _src_core
-
-    def _axis_size(axis_name):
-        return _src_core.get_axis_env().axis_size(axis_name)
-
-    _jax.lax.axis_size = _axis_size
-
 from colearn_federated_learning_tpu.config import (  # noqa: F401
     ExperimentConfig,
     get_named_config,

@@ -163,7 +163,8 @@ REGISTRY: Dict[str, RecordSpec] = {
     "phase_cost_model": RecordSpec(
         required=("step_flops", "flop_source", "n_coords", "n_coords_full",
                   "param_bytes", "compute_bytes", "mfu_basis", "peak_flops",
-                  "peak_hbm_bytes_per_sec", "n_chips", "process_index",
+                  "peak_hbm_bytes_per_sec", "device_kind", "n_chips",
+                  "process_index",
                   "cohort_layout", "clients_per_lane", "gemm_rows",
                   "lora_all_steps", "mxu_tile_pad_fraction"),
         doc="static half of the roofline cost model (obs/roofline.py)",

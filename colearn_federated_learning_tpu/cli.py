@@ -640,6 +640,14 @@ def main(argv=None):
             print(obs_summary.format_summary(agg, path))
         return 0
 
+    # every command below compiles: place the persistent compilation
+    # cache first (JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache)
+    from colearn_federated_learning_tpu.utils.compile_cache import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
+
     # multi-host bring-up must precede any backend touch (SURVEY.md §3.5);
     # no-op unless COLEARN_COORDINATOR is set (TPU pods auto-detect inside)
     from colearn_federated_learning_tpu.parallel.distributed import (

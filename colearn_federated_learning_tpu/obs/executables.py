@@ -30,12 +30,14 @@ shape/dtype/sharding. A live HBM ledger tracks the high-water mark
 over the programs called in each flush window (``hbm_watermark``
 records + run peak in ``run_summary``).
 
-Degradation contract: any failure anywhere in the registry path —
-lowering, compiling, analysis harvesting, or calling the cached
-executable — permanently falls back to the plain jitted call for that
-program name and records partial (null-field) data. The registry must
-never change what a fit computes or whether it completes (budget
-aborts below are the one deliberate exception).
+Failure contract: a backend that lacks an ANALYSIS (cost/memory) still
+trains — those fields degrade to null. A program that fails to lower
+or compile, or an AOT executable that rejects its call, does not: the
+registry queues a ``warning`` record (``executable_lower_failed`` /
+``executable_call_failed``) and re-raises. It never re-dispatches
+through plain ``jit`` — that would compile the program a second time
+and let a run that could not use what it built finish as if nothing
+happened. The driver logs the queued records on every exit path.
 
 OOM preflight: with ``preflight=True`` the registry lowers and
 compiles but NEVER executes — wrapped calls return abstract
@@ -286,8 +288,6 @@ class ExecutableRegistry:
         self._cache: Dict[Any, Dict[str, Any]] = {}
         # name -> {"fingerprint", "paths", "compiles", "peak_bytes"}
         self._programs: Dict[str, Dict[str, Any]] = {}
-        # names whose AOT path failed once: plain jit calls from then on
-        self._aot_off: set = set()
         self._records: List[Dict[str, Any]] = []
         # flush-window program names (for the hbm_watermark record)
         self._window: set = set()
@@ -309,8 +309,6 @@ class ExecutableRegistry:
     def call(self, name: str, fn: Callable, args: tuple, kwargs: dict,
              *, static_argnums: Tuple[int, ...] = (),
              rounds_per_call: int = 1):
-        if name in self._aot_off and not self.preflight:
-            return fn(*args, **kwargs)
         try:
             key, _ = _cache_key(args, kwargs, static_argnums)
         except Exception:
@@ -324,26 +322,27 @@ class ExecutableRegistry:
             self._window.add(name)
             if self.preflight:
                 return hit["abstract_out"]
-            compiled = hit["compiled"]
-            if compiled is None:
-                return fn(*args, **kwargs)
-            try:
-                return compiled(*args, **kwargs)
-            except Exception as e:  # pragma: no cover - safety net
-                # fingerprint collision or input/layout drift the key
-                # missed: disable AOT for this name, warn, re-dispatch
-                # through jit (inputs are intact — the AOT call
-                # validates before executing)
-                self._aot_off.add(name)
-                self._records.append({
-                    "event": "warning",
-                    "warning": "executable_aot_fallback",
-                    "detail": f"{name}: {type(e).__name__}: {e}"[:300],
-                    "round": int(self.round),
-                })
-                return fn(*args, **kwargs)
+            return self._run(name, hit["compiled"], args, kwargs)
         return self._compile_and_call(name, fn, args, kwargs, key,
                                       static_argnums, rounds_per_call)
+
+    def _warn(self, kind: str, name: str, e: BaseException) -> None:
+        self._records.append({
+            "event": "warning",
+            "warning": kind,
+            "detail": f"{name}: {type(e).__name__}: {e}"[:300],
+            "round": int(self.round),
+        })
+
+    def _run(self, name, compiled, args, kwargs):
+        """Call the AOT executable. A rejected call (an input sharding
+        or layout the fingerprint missed) is recorded and re-raised —
+        see the module docstring's failure contract."""
+        try:
+            return compiled(*args, **kwargs)
+        except Exception as e:
+            self._warn("executable_call_failed", name, e)
+            raise
 
     # -- slow path: first sight of a fingerprint ------------------------
     def _compile_and_call(self, name, fn, args, kwargs, key,
@@ -356,19 +355,11 @@ class ExecutableRegistry:
                 lowered = fn.lower(*args, **kwargs)
                 compiled = lowered.compile()
             except Exception as e:
-                self._aot_off.add(name)
                 compile_ms = (time.perf_counter() - t0) * 1e3
                 self._emit_compiled(name, fingerprint, None, compile_ms,
                                     rounds_per_call)
-                self._records.append({
-                    "event": "warning",
-                    "warning": "executable_lower_failed",
-                    "detail": f"{name}: {type(e).__name__}: {e}"[:300],
-                    "round": int(self.round),
-                })
-                if self.preflight:
-                    raise
-                return fn(*args, **kwargs)
+                self._warn("executable_lower_failed", name, e)
+                raise
             compile_ms = (time.perf_counter() - t0) * 1e3
             stats = self._harvest(lowered, compiled)
             paths = self._paths_or_none(fn, args, kwargs, static_argnums)
@@ -403,19 +394,7 @@ class ExecutableRegistry:
             self._check_budget(name, stats, paths)
         if self.preflight:
             return abstract_out
-        try:
-            return compiled(*args, **kwargs)
-        except HbmBudgetError:
-            raise
-        except Exception as e:
-            self._aot_off.add(name)
-            self._records.append({
-                "event": "warning",
-                "warning": "executable_aot_fallback",
-                "detail": f"{name}: {type(e).__name__}: {e}"[:300],
-                "round": int(self.round),
-            })
-            return fn(*args, **kwargs)
+        return self._run(name, compiled, args, kwargs)
 
     # -- harvesting ------------------------------------------------------
     @staticmethod

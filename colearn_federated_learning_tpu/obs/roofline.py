@@ -51,6 +51,13 @@ from typing import Any, Dict, List, Optional, Sequence
 # peaks (single source of truth — bench.py imports these)
 # ---------------------------------------------------------------------------
 
+# The chip the constants below describe, as jax names it
+# (``jax.local_devices()[0].device_kind``). Every record that carries a
+# peak also carries the ``device_kind`` that actually ran, so a number
+# divided by these peaks on any other device is visible as such;
+# ``chip_smoke.py`` fails when the two differ. (The table keyed by
+# device_kind is ROADMAP S1(c).)
+PEAK_DEVICE_KIND = "TPU v5 lite"
 # Dense bf16 peak of one TPU v5e (v5 lite) chip; MFU = achieved / peak.
 PEAK_BF16_FLOPS = 197e12
 # The MXU retires f32 products at no better than half the bf16 rate, so

@@ -46,6 +46,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from colearn_federated_learning_tpu.obs.roofline import (
     SERVER_APPLY_PASSES_FUSED,
@@ -144,8 +145,18 @@ def _interpret(interpret):
     return interpret
 
 
-def _tile_struct(g):
-    return jax.ShapeDtypeStruct((g * _SUB, _LANE), jnp.float32)
+def out_struct(shape, dtype, inputs):
+    """``ShapeDtypeStruct`` for a kernel output, carrying the union of
+    the inputs' vma sets: inside a shard_map the output varies over the
+    mesh axes its inputs vary over (none, for the replicated server
+    update; ``clients`` for attention in the client lanes), and
+    shard_map's vma checker requires that stated explicitly."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in inputs))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
+def _tile_struct(g, inputs):
+    return out_struct((g * _SUB, _LANE), jnp.float32, inputs)
 
 
 _TILE_SPEC = pl.BlockSpec((_SUB, _LANE), lambda i: (i, 0))
@@ -168,13 +179,15 @@ def _reduce_apply_kernel(w_ref, s_ref, p_ref, m_ref, po_ref, mo_ref, do_ref,
                          *, lr: float, beta: float):
     # [K] ∙ [K, _SUB, _LANE] → [_SUB, _LANE]: the trust/weight-scaled
     # reduction; the weights already carry the 1/denominator, so the
-    # contraction IS the finished weighted mean. Broadcast-multiply +
-    # leading-axis sum (vreg adds over the K tile stack) rather than a
-    # dot — K is a cohort (tiny), the pass is bandwidth-bound, and the
-    # elementwise form lowers on every backend.
-    w = w_ref[0].astype(jnp.float32)  # [K]
-    s = s_ref[...].astype(jnp.float32)  # [K, _SUB, _LANE]
-    delta = jnp.sum(w[:, None, None] * s, axis=0)
+    # contraction IS the finished weighted mean. The [K] weights live
+    # in SMEM and are read as SCALARS: one scalar × tile FMA per client
+    # (K is a cohort — tiny — and the pass is bandwidth-bound). A [K]
+    # lane vector broadcast against the tile stack needs a
+    # vector<1xK> → vector<Kx1x1> shape cast, which Mosaic refuses
+    # ("infer-vector-layout: unsupported shape cast", libtpu 0.0.34).
+    delta = w_ref[0] * s_ref[0].astype(jnp.float32)
+    for c in range(1, s_ref.shape[0]):
+        delta = delta + w_ref[c] * s_ref[c].astype(jnp.float32)
     do_ref[...] = delta
     p = p_ref[...].astype(jnp.float32)
     if mo_ref is not None:
@@ -210,7 +223,7 @@ def fused_delta_apply(params, momentum, mean_delta, server_lr: float,
             kernel, grid=(g,),
             in_specs=[_TILE_SPEC, _TILE_SPEC, _TILE_SPEC],
             out_specs=[_TILE_SPEC, _TILE_SPEC],
-            out_shape=[_tile_struct(g), _tile_struct(g)],
+            out_shape=[_tile_struct(g, (d_t, p_t, m_t))] * 2,
             interpret=_interpret(interpret),
         )(d_t, p_t, m_t)
         return unflat_p(p_out.reshape(-1)[:n]), unflat_m(m_out.reshape(-1)[:n])
@@ -223,7 +236,7 @@ def fused_delta_apply(params, momentum, mean_delta, server_lr: float,
         kernel, grid=(g,),
         in_specs=[_TILE_SPEC, _TILE_SPEC],
         out_specs=_TILE_SPEC,
-        out_shape=_tile_struct(g),
+        out_shape=_tile_struct(g, (d_t, p_t)),
         interpret=_interpret(interpret),
     )(d_t, p_t)
     return unflat_p(p_out.reshape(-1)[:n]), None
@@ -249,9 +262,9 @@ def fused_reduce_apply(wire_stack, weights, params, momentum,
     flat_p, unflat_p = _flatten_tree(params)
     s_t, n, g = _pad_tiles(flat_s)  # [K, G*_SUB, _LANE]
     p_t = _pad_tiles(flat_p)[0]
-    w = weights.astype(jnp.float32).reshape(1, k)
+    w = weights.astype(jnp.float32).reshape(k)
     stack_spec = pl.BlockSpec((k, _SUB, _LANE), lambda i: (0, i, 0))
-    w_spec = pl.BlockSpec((1, k), lambda i: (0, 0))
+    w_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     if has_mom:
         flat_m, unflat_m = _flatten_tree(momentum)
         m_t = _pad_tiles(flat_m)[0]
@@ -263,7 +276,7 @@ def fused_reduce_apply(wire_stack, weights, params, momentum,
             kernel, grid=(g,),
             in_specs=[w_spec, stack_spec, _TILE_SPEC, _TILE_SPEC],
             out_specs=[_TILE_SPEC, _TILE_SPEC, _TILE_SPEC],
-            out_shape=[_tile_struct(g)] * 3,
+            out_shape=[_tile_struct(g, (w, s_t, p_t, m_t))] * 3,
             interpret=_interpret(interpret),
         )(w, s_t, p_t, m_t)
         new_mom = unflat_m(m_out.reshape(-1)[:n])
@@ -276,7 +289,7 @@ def fused_reduce_apply(wire_stack, weights, params, momentum,
             kernel, grid=(g,),
             in_specs=[w_spec, stack_spec, _TILE_SPEC],
             out_specs=[_TILE_SPEC, _TILE_SPEC],
-            out_shape=[_tile_struct(g)] * 2,
+            out_shape=[_tile_struct(g, (w, s_t, p_t))] * 2,
             interpret=_interpret(interpret),
         )(w, s_t, p_t)
         new_mom = None

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from colearn_federated_learning_tpu.ops.pallas_apply import out_struct
 from colearn_federated_learning_tpu.ops.ring_attention import (
     _merge_heads,
     _split_heads,
@@ -37,18 +38,6 @@ from colearn_federated_learning_tpu.ops.ring_attention import (
 )
 
 _NEG_BIG = -1e30
-
-
-def _out_shape_struct(shape, dtype, inputs):
-    """``ShapeDtypeStruct`` for the kernel output, carrying the union of
-    the inputs' vma sets on vma-aware jax; plain shape/dtype on pre-vma
-    jax (no ``vma=`` kwarg there, and no checker for it to inform)."""
-    vma = frozenset().union(*(
-        getattr(jax.typeof(x), "vma", frozenset()) for x in inputs
-    ))
-    if not vma:
-        return jax.ShapeDtypeStruct(shape, dtype)
-    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q: int, block_kv: int,
@@ -147,13 +136,7 @@ def _flash_fwd_impl(q, k, v, heads: int, causal: bool, block_q: int,
             pl.BlockSpec((1, tp, hd), lambda bh, i: (bh, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bq, hd), lambda bh, i: (bh, i, 0)),
-        # Inside shard_map (the round engine's clients mesh) inputs are
-        # device-varying; the kernel output varies the same way, and
-        # shard_map's vma checker requires that stated explicitly.
-        # (Pre-vma jax has no `vma=` kwarg and no checker to inform —
-        # only pass it when the inputs actually carry a vma set.)
-        out_shape=_out_shape_struct((b * h, tp, hd), q.dtype,
-                                    (qh, kh, vh)),
+        out_shape=out_struct((b * h, tp, hd), q.dtype, (qh, kh, vh)),
         interpret=interpret,
     )(qh, kh, vh)
     return _merge_heads(out.reshape(b, h, tp, hd)[:, :, :t])

@@ -43,18 +43,8 @@ def initialize(coordinator: Optional[str] = None,
     Idempotent: repeated calls after a successful bring-up are no-ops.
     """
     # Must not touch the backend (jax.process_count() would initialize
-    # it); inspect the distributed client state directly for idempotency.
-    already = getattr(jax.distributed, "is_initialized", None)
-    if already is not None:
-        if already():
-            return
-    elif getattr(
-        getattr(jax.distributed, "global_state", None), "client", None
-    ) is not None:
-        # older jax: no is_initialized(); probe the client directly.
-        # jax builds exposing NEITHER accessor fall through to
-        # initialize() (a repeated call then raises there — loud,
-        # instead of an AttributeError here masking the real state)
+    # it); ask the distributed client state directly.
+    if jax.distributed.is_initialized():
         return
     kwargs = {}
     if coordinator is not None:

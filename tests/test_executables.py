@@ -398,7 +398,9 @@ def test_flop_drift_gate_na_tolerant(tmp_path):
 def test_checked_in_history_passes_repo_budgets(capsys):
     budgets = json.load(open("BENCH_BUDGETS.json"))
     assert "flop_drift_pct_max" in budgets
-    assert cli.main(["bench-report", "--dir", "."]) == 0
+    assert cli.main(["bench-report", "--dir",
+                     "tests/fixtures/bench_history",
+                     "--baseline", "BENCH_BUDGETS.json"]) == 0
     assert "gates: PASS" in capsys.readouterr().out
 
 

@@ -190,6 +190,41 @@ def test_engine_rejects_fused_flag_without_fused_update():
         )
 
 
+def test_fused_update_lowers_for_tpu_on_a_multi_device_mesh(monkeypatch):
+    """What the four-chip host said (PR 21): a Mosaic kernel left in
+    the auto-partitioned region of a mesh wider than one chip does not
+    lower. Cross-lowering for TPU from here reproduces that, and shows
+    the engines' replicated manual region (_on_every_device) lowers to
+    the custom call."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from colearn_federated_learning_tpu.ops import pallas_apply
+    from colearn_federated_learning_tpu.parallel.mesh import build_client_mesh
+    from colearn_federated_learning_tpu.parallel.round_engine import (
+        _on_every_device,
+    )
+
+    monkeypatch.setattr(pallas_apply, "_interpret", lambda interpret: False)
+    mesh = build_client_mesh(4, devices=jax.devices()[:4])
+    rep = NamedSharding(mesh, P())
+    init, update = make_server_update_fn(
+        ServerConfig(optimizer="fedavgm", fused_apply=True)
+    )
+    params = _tree(np.random.default_rng(3))
+    args = (params, init(params), params)
+
+    def lower(fn):
+        return jax.jit(fn, in_shardings=rep).trace(*args).lower(
+            lowering_platforms=("tpu",))
+
+    with pytest.raises(NotImplementedError, match="automatically partitioned"):
+        lower(update)
+    assert "tpu_custom_call" in lower(_on_every_device(update, mesh)).as_text()
+    # the optax chain needs no manual region and is passed through
+    _, plain = make_server_update_fn(ServerConfig(optimizer="fedavgm"))
+    assert _on_every_device(plain, mesh) is plain
+
+
 # ---------------------------------------------------------------------------
 # e2e: the CI matrix — {weighted_mean, krum} × {reputation on/off},
 # fused vs unfused, both engines, interpret mode (the tier-1 smoke that

@@ -396,17 +396,8 @@ class TestPartialParticipation:
                 rng, cohort)
         ref, _, m_ref = self._mk(model, 8, n_clients, k)(*args)
         got, _, m_got = self._mk(model, lanes, n_clients, k)(*args)
-        from colearn_federated_learning_tpu import JAX_COMPAT_SHIMS
-
-        if JAX_COMPAT_SHIMS:
-            # pre-vma jax/XLA reassociates across the different lane
-            # blockings by one ulp; the bitwise contract is pinned on
-            # the target jax only
-            check = lambda a, b: np.testing.assert_allclose(  # noqa: E731
-                np.asarray(a), np.asarray(b), rtol=0, atol=1e-6)
-        else:
-            check = lambda a, b: np.testing.assert_array_equal(  # noqa: E731
-                np.asarray(a), np.asarray(b))
+        check = lambda a, b: np.testing.assert_array_equal(  # noqa: E731
+            np.asarray(a), np.asarray(b))
         jax.tree.map(check, ref, got)
         np.testing.assert_allclose(
             float(m_ref.train_loss), float(m_got.train_loss), rtol=1e-6

@@ -83,11 +83,13 @@ def test_gate_never_fires_on_missing_field(tmp_path):
 
 
 def test_checked_in_history_still_passes_repo_budgets(capsys):
-    # the repo's own BENCH_r01–r05 trajectory against the repo's own
+    # the recorded fixture history against the repo's own
     # BENCH_BUDGETS.json (which now carries host_exposed_pct_max)
     budgets = json.load(open("BENCH_BUDGETS.json"))
     assert "host_exposed_pct_max" in budgets
-    assert cli.main(["bench-report", "--dir", "."]) == 0
+    assert cli.main(["bench-report", "--dir",
+                     "tests/fixtures/bench_history",
+                     "--baseline", "BENCH_BUDGETS.json"]) == 0
     out = capsys.readouterr().out
     assert "gates: PASS" in out
     assert "host%" in out
