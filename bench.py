@@ -85,7 +85,6 @@ DISPATCH_BOUND_MFU_PCT = 5.0
 from colearn_federated_learning_tpu.obs.roofline import (  # noqa: E402
     PEAK_BF16_FLOPS,
     PEAK_F32_FLOPS,
-    host_exposed_pct as _host_exposed_pct,
     mfu_basis as _roofline_mfu_basis,
 )
 
@@ -121,8 +120,8 @@ _SHAPES = {
     # ISSUE 18: the headline config's device-control-plane twin —
     # identical workload + fusion, but cohort/churn/slab derivation is
     # lowered into the round program (server/device_plane.py) so host
-    # I/O collapses to flush boundaries. Bench-report's mode column and
-    # the host_exposed_pct gate read the two entries side by side.
+    # I/O collapses to flush boundaries. Bench-report's mode column
+    # reads the two entries side by side.
     "cifar10_fedavg_100_device": (4, 16, {"run.fuse_rounds": 4,
                                           "server.fused_apply": True,
                                           "run.control_plane": "device"}),
@@ -425,11 +424,6 @@ def bench_config(name: str):
     if name in DEVICE_MS_BASELINES:
         state, device_ms = _measure_device_ms(exp, state, warmup + timed)
     vs, vs_basis = _gate(name, rounds_per_sec, device_ms, flops_pct)
-    # host-exposed share of the timed wall (obs/roofline.py rule):
-    # the observability-tax number bench-report gates against
-    # host_exposed_pct_max — host spans the device idles through,
-    # over the timed region's wall clock
-    hep = _host_exposed_pct(phase_ms, dt)
     # measured-vs-analytic flop drift (run.obs.executables): the XLA
     # cost_analysis flops of the dominant compiled round program vs the
     # analytic model — None (n/a in bench-report) when the registry is
@@ -447,7 +441,6 @@ def bench_config(name: str):
         "static_check": _static_check_extra(),
         "vs_baseline_basis": vs_basis,
         "phase_ms": phase_ms,
-        "host_exposed_pct": None if hep is None else round(hep, 2),
         "flop_model_drift_pct": drift_pct,
         "client_updates_per_sec_per_chip": round(updates_per_sec_per_chip, 4),
         "n_chips": exp.n_chips,

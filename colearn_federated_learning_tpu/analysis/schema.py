@@ -86,7 +86,8 @@ REGISTRY: Dict[str, RecordSpec] = {
     ),
     "spans": RecordSpec(
         required=("round", "phases", "process_index"),
-        doc="per-phase timing aggregates at each metrics flush",
+        doc="per-phase timing aggregates at each metrics flush: "
+            "phases = {name: {count, total_ms, max_ms, self_ms}}",
     ),
     "device_memory": RecordSpec(
         required=("round",), open_fields=True,

@@ -223,74 +223,89 @@ def make_local_train_fn(model, client_cfg: ClientConfig, dp_cfg: DPConfig, task:
     def _make_step(global_params, train_x, train_y, lr_scale, grad_corr):
         """The per-client step body, shared VERBATIM by the per-client
         scan path and both megabatch phases — the layouts cannot drift
-        numerically because they run the same function."""
+        numerically because they run the same function.
+
+        Three named scopes split it for the device trace, beneath the
+        engine's ``round_local_train``: ``local_gather`` (the batch's
+        rows out of the corpus), ``local_grad`` (forward and backward, and
+        the batch-shard psum of the gradient where there is one —
+        opened OUTSIDE the transform, so the name is a plain path
+        component, ``vmap(local_grad)`` under the megabatch vmap, with
+        the forward under ``jvp(...)`` and the backward under
+        ``transpose(jvp(...))`` beneath it; DP's own scopes nest inside)
+        and ``local_opt`` (proximal / weight-decay terms and the
+        parameter and optimizer-state update). Metadata only."""
 
         def step(carry, inp):
             params, opt_state = carry
             step_idx, step_mask, key = inp
-            x = jnp.take(train_x, step_idx, axis=0)
-            y = jnp.take(train_y, step_idx, axis=0)
+            with jax.named_scope("local_gather"):
+                x = jnp.take(train_x, step_idx, axis=0)
+                y = jnp.take(train_y, step_idx, axis=0)
             step_n = _global_count(step_mask)  # identical on all batch shards
-            if dp_cfg.enabled:
-                loss, grads = dp_grad_fn(params, x, y, step_mask, key)
-            elif batch_axis is None:
-                loss, grads = grad_fn(params, x, y, step_mask)
-            else:
-                sum_loss, sum_grads = sum_grad_fn(
-                    _batch_varying(params), x, y, step_mask
-                )
-                denom = jnp.maximum(step_n, 1.0)
-                loss = jax.lax.psum(sum_loss, batch_axis) / denom
-                grads = jax.tree.map(
-                    lambda g: jax.lax.psum(g, batch_axis) / denom, sum_grads
-                )
-            if mu > 0.0:
-                # exact ∇ of μ/2‖w−w₀‖² — FedProx's proximal pull
-                grads = jax.tree.map(
-                    lambda g, p, p0: g + mu * (p - p0), grads, params, global_params
-                )
-            if grad_corr is not None:
-                grads = jax.tree.map(
-                    lambda g, cc: g + cc.astype(g.dtype), grads, grad_corr
-                )
-            # validity must be judged on the GLOBAL mask so batch shards
-            # never diverge on whether a padded step applied
-            if fused_sgd:
-                v = (step_n > 0).astype(jnp.float32)
-                wd = client_cfg.weight_decay
-                if wd:
-                    grads = jax.tree.map(
-                        lambda g, p: g + jnp.asarray(wd, g.dtype) * p.astype(g.dtype),
-                        grads, params,
-                    )
-                lr_eff = jnp.float32(client_cfg.lr) * v
-                if lr_scale is not None:
-                    lr_eff = lr_eff * lr_scale.astype(lr_eff.dtype)
-                beta = client_cfg.momentum
-                if beta:
-                    beta_eff = 1.0 - v * (1.0 - beta)
-                    opt_state = jax.tree.map(
-                        lambda m_, g: beta_eff.astype(m_.dtype) * m_
-                        + v.astype(g.dtype) * g.astype(m_.dtype),
-                        opt_state, grads,
-                    )
-                    direction = opt_state
+            with jax.named_scope("local_grad"):
+                if dp_cfg.enabled:
+                    loss, grads = dp_grad_fn(params, x, y, step_mask, key)
+                elif batch_axis is None:
+                    loss, grads = grad_fn(params, x, y, step_mask)
                 else:
-                    direction = grads
-                params = jax.tree.map(
-                    lambda p, d: p - lr_eff.astype(p.dtype) * d.astype(p.dtype),
-                    params, direction,
-                )
-            else:
-                updates, new_opt_state = opt.update(grads, opt_state, params)
-                if lr_scale is not None:
-                    updates = jax.tree.map(
-                        lambda u: u * lr_scale.astype(u.dtype), updates
+                    sum_loss, sum_grads = sum_grad_fn(
+                        _batch_varying(params), x, y, step_mask
                     )
-                new_params = optax.apply_updates(params, updates)
-                valid = step_n > 0
-                params = _select_tree(valid, new_params, params)
-                opt_state = _select_tree(valid, new_opt_state, opt_state)
+                    denom = jnp.maximum(step_n, 1.0)
+                    loss = jax.lax.psum(sum_loss, batch_axis) / denom
+                    grads = jax.tree.map(
+                        lambda g: jax.lax.psum(g, batch_axis) / denom,
+                        sum_grads,
+                    )
+            with jax.named_scope("local_opt"):
+                if mu > 0.0:
+                    # exact ∇ of μ/2‖w−w₀‖² — FedProx's proximal pull
+                    grads = jax.tree.map(
+                        lambda g, p, p0: g + mu * (p - p0), grads, params, global_params
+                    )
+                if grad_corr is not None:
+                    grads = jax.tree.map(
+                        lambda g, cc: g + cc.astype(g.dtype), grads, grad_corr
+                    )
+                # validity must be judged on the GLOBAL mask so batch shards
+                # never diverge on whether a padded step applied
+                if fused_sgd:
+                    v = (step_n > 0).astype(jnp.float32)
+                    wd = client_cfg.weight_decay
+                    if wd:
+                        grads = jax.tree.map(
+                            lambda g, p: g + jnp.asarray(wd, g.dtype) * p.astype(g.dtype),
+                            grads, params,
+                        )
+                    lr_eff = jnp.float32(client_cfg.lr) * v
+                    if lr_scale is not None:
+                        lr_eff = lr_eff * lr_scale.astype(lr_eff.dtype)
+                    beta = client_cfg.momentum
+                    if beta:
+                        beta_eff = 1.0 - v * (1.0 - beta)
+                        opt_state = jax.tree.map(
+                            lambda m_, g: beta_eff.astype(m_.dtype) * m_
+                            + v.astype(g.dtype) * g.astype(m_.dtype),
+                            opt_state, grads,
+                        )
+                        direction = opt_state
+                    else:
+                        direction = grads
+                    params = jax.tree.map(
+                        lambda p, d: p - lr_eff.astype(p.dtype) * d.astype(p.dtype),
+                        params, direction,
+                    )
+                else:
+                    updates, new_opt_state = opt.update(grads, opt_state, params)
+                    if lr_scale is not None:
+                        updates = jax.tree.map(
+                            lambda u: u * lr_scale.astype(u.dtype), updates
+                        )
+                    new_params = optax.apply_updates(params, updates)
+                    valid = step_n > 0
+                    params = _select_tree(valid, new_params, params)
+                    opt_state = _select_tree(valid, new_opt_state, opt_state)
             return (params, opt_state), loss * step_n
 
         return step

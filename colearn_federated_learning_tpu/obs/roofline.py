@@ -95,11 +95,16 @@ PHASES = (
 # it (counting it again would double-book that wall). The executable
 # registry's own spans (`obs.executables` AOT lower+compile and
 # `obs.preflight`) bracket compile work the `compile` listener already
-# books — counting them would charge each compilation twice. Every
-# other span (host_inputs, placement, fetch, eval, checkpoint,
-# stream_slab, ...) is host time the device sits idle through.
-_NON_HOST_EXPOSED_SPANS = ("round", "round.dispatch", "compile",
-                           "obs.executables", "obs.preflight")
+# books — counting them would charge each compilation twice.
+# `round.run` is the bracket of one dispatch call (its children are
+# counted by name), `round.prefetch` the worker's bracket, which runs
+# beside the main thread, and `round.device_wait` is the host waiting
+# for a device that is busy: none of it is exposed. Every other span
+# (host_inputs, placement, fetch, eval, checkpoint, stream_slab, ...)
+# is host time the device sits idle through.
+_NON_HOST_EXPOSED_SPANS = ("round", "round.run", "round.prefetch",
+                           "round.dispatch", "round.device_wait",
+                           "compile", "obs.executables", "obs.preflight")
 
 # Attribution sub-spans nested INSIDE an already-counted host span: the
 # parent's bracket (`round.host_inputs`) contains their wall time, so
@@ -114,24 +119,6 @@ def _is_host_exposed(name: str) -> bool:
     return (name not in _NON_HOST_EXPOSED_SPANS
             and not name.startswith(_SUBSPAN_PREFIXES))
 
-
-def host_exposed_pct(phase_ms: Dict[str, float], wall_s: float) -> Optional[float]:
-    """Fraction of a timed region's wall clock the device sat idle
-    behind host work, as a percentage: the sum of every span that is
-    NOT dispatch/compile (same `_NON_HOST_EXPOSED_SPANS` rule the
-    waterfall uses) over the wall. bench.py stamps this into every
-    result's extras and `bench_report` gates it against
-    ``host_exposed_pct_max`` — the budget that keeps host-side
-    accounting (ledger stats, population windows, digest fetches) from
-    quietly eating the round loop. ``None`` when the wall is
-    unmeasured, so historical entries render n/a, never divide by 0."""
-    if not wall_s or wall_s <= 0:
-        return None
-    host_ms = sum(
-        ms for name, ms in (phase_ms or {}).items()
-        if _is_host_exposed(name)
-    )
-    return 100.0 * (host_ms / 1000.0) / float(wall_s)
 
 # Byte-model pass counts (documented constants, not magic numbers):
 # local train touches the params 4× per step (fwd read, bwd read, grad
@@ -769,7 +756,6 @@ def load_bench_history(bench_dir: str) -> List[Dict[str, Any]]:
             # control-plane mode (run.control_plane, ISSUE 18): entries
             # predating the knob (r01–r05) render n/a
             "control_plane": extra.get("control_plane"),
-            "host_exposed_pct": extra.get("host_exposed_pct"),
             # measured-vs-analytic flop drift (executable registry,
             # ISSUE 20): r01–r19 entries predate the extra → n/a
             "flop_model_drift_pct": extra.get("flop_model_drift_pct"),
@@ -974,18 +960,6 @@ def bench_report(entries: Sequence[Dict[str, Any]],
                 f"mfu_pct {latest['mfu_pct']:.2f} < budget floor "
                 f"{float(mfu_min):.2f} ({latest['file']})"
             )
-        # host-exposed ceiling: the observability tax budget — fires
-        # only when the entry carries the field (histories predating it
-        # render n/a, never a gate), so BENCH_r01+ keeps passing
-        host_max = budgets.get("host_exposed_pct_max")
-        if (host_max is not None
-                and latest.get("host_exposed_pct") is not None
-                and latest["host_exposed_pct"] > float(host_max)):
-            violations.append(
-                f"host_exposed_pct {latest['host_exposed_pct']:.1f} "
-                f"> budget ceiling {float(host_max):.1f} "
-                f"({latest['file']})"
-            )
         # measured-vs-analytic flop drift ceiling: the cost-model truth
         # gate — |drift| over budget means the analytic phase model and
         # the XLA cost_analysis of the compiled round program no longer
@@ -1122,7 +1096,7 @@ def format_bench_report(report: Dict[str, Any], bench_dir: str = "") -> str:
     lines.append(
         f"{'entry':<18}{'r/s':>8}{'vs_base':>9}{'mfu%':>8}"
         f"{'basis':>11}{'dtype':>10}{'dev ms':>8}"
-        f"{'chips':>7}{'upd/s/chip':>12}{'host%':>7}{'mode':>8}"
+        f"{'chips':>7}{'upd/s/chip':>12}{'mode':>8}"
     )
     for e in entries:
         lines.append(
@@ -1135,7 +1109,6 @@ def format_bench_report(report: Dict[str, Any], bench_dir: str = "") -> str:
             f"{_na(e.get('device_ms_per_round'), '{:.1f}'):>8}"
             f"{_na(e.get('n_chips')):>7}"
             f"{_na(e.get('updates_per_sec_per_chip'), '{:.1f}'):>12}"
-            f"{_na(e.get('host_exposed_pct'), '{:.1f}'):>7}"
             f"{_na(e.get('control_plane')):>8}"
         )
     latest = report.get("latest")

@@ -5,13 +5,28 @@ Design constraints, in order:
 1. **Off is free.** With ``run.obs.spans=false`` a ``span()`` call
    returns a shared no-op context manager — no clock reads, no
    allocation — so the round loop's hot path pays one attribute check.
-2. **On is cheap.** An enabled span is two ``perf_counter`` reads and
-   one dict update under a lock (spans fire from the fit loop AND the
-   stream-prefetch worker thread). Chrome-trace event objects are only
-   built when ``run.obs.trace=true``.
+2. **On is cheap.** An enabled span is two ``perf_counter`` reads, a
+   push and a pop on its thread's own stack, one
+   ``jax.profiler.TraceAnnotation`` (a flag check while no profiler
+   session runs) and one dict update under a lock (spans fire from the
+   fit loop AND the stream-prefetch worker thread). Chrome-trace event
+   objects are only built when ``run.obs.trace=true``.
 3. **Drain-at-flush.** The driver drains per-phase aggregates at its
    metrics-flush boundaries and logs ONE ``spans`` record per window —
    the JSONL stays one-line-per-round-scale, not one-line-per-span.
+
+One clock: while a ``jax.profiler`` session runs (``--profile N``, the
+benchmark's traced run) every enabled span is also an event of its name
+on the ``/host:CPU`` plane of that trace, on the thread that opened it
+and with its arguments as stats — beside the device planes, so a gap
+between device ops can be laid against what the program was doing.
+
+Work split from wait: each thread keeps a stack of its open spans, so a
+span knows its parent and the aggregates carry ``self_ms`` — a span's
+duration minus what the spans opened inside it, on the same thread,
+cover. The ``round`` argument names the request: a span opened inside
+one that carries it carries it too, and the prefetch worker sets it to
+the first round of the dispatch its entry is for.
 
 Retrace attribution: ``jax.monitoring`` fires a
 ``.../backend_compile_duration`` event for every XLA compilation; a
@@ -76,8 +91,24 @@ def _install_listener() -> None:
         pass  # no jax / no monitoring API: spans still work, no retrace attribution
 
 
+# span arguments that pass from a span to the spans opened inside it on
+# the same thread: the identifier that spans of one dispatch share
+_INHERITED_ARGS = ("round",)
+
+
+class _Lane:
+    """One thread's open spans, and its lane in the Chrome trace."""
+
+    __slots__ = ("index", "stack")
+
+    def __init__(self, index: int):
+        self.index = index
+        self.stack: List["_Span"] = []
+
+
 class _Span:
-    __slots__ = ("_tracer", "_name", "_args", "_start")
+    __slots__ = ("_tracer", "_name", "_args", "_start", "_lane",
+                 "_children", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args=None):
         self._tracer = tracer
@@ -85,11 +116,30 @@ class _Span:
         self._args = args
 
     def __enter__(self):
-        self._start = self._tracer._clock()
+        tracer = self._tracer
+        self._lane = lane = tracer._lane()
+        if lane.stack:
+            above = lane.stack[-1]._args or {}
+            passed = {k: above[k] for k in _INHERITED_ARGS if k in above}
+            if passed:
+                self._args = {**passed, **(self._args or {})}
+        lane.stack.append(self)
+        self._children = 0.0
+        self._annotation = tracer._annotate(self._name, **(self._args or {}))
+        self._annotation.__enter__()
+        self._start = tracer._clock()
         return self
 
     def __exit__(self, *exc):
-        self._tracer._record(self._name, self._start, self._tracer._clock(),
+        end = self._tracer._clock()
+        self._annotation.__exit__(*exc)
+        stack = self._lane.stack
+        stack.pop()
+        dur = end - self._start
+        if stack:
+            stack[-1]._children += dur
+        self._tracer._record(self._name, self._start, dur,
+                             dur - self._children, self._lane.index,
                              self._args)
         return False
 
@@ -115,7 +165,12 @@ class Tracer:
         self.process_index = int(process_index)
         self._clock = clock or time.perf_counter
         self._lock = threading.Lock()
-        self._agg: Dict[str, List[float]] = {}  # name -> [count, total_s, max_s]
+        # name -> [count, total_s, max_s, self_s]
+        self._agg: Dict[str, List[float]] = {}
+        # per-thread span stacks; a thread's lane index is the order in
+        # which it first opened a span here (the Chrome trace's tid)
+        self._local = threading.local()
+        self._lanes = 0
         self._events: List[Dict[str, Any]] = []
         # cap on accumulated Chrome-trace events (run.obs.
         # trace_max_events): long runs otherwise grow trace.json without
@@ -129,6 +184,9 @@ class Tracer:
         self._compile_secs = 0.0
         self._compile_max = 0.0
         if enabled:
+            from jax.profiler import TraceAnnotation
+
+            self._annotate = TraceAnnotation
             _install_listener()
             _ACTIVE.add(self)
 
@@ -144,24 +202,32 @@ class Tracer:
             return _NULL_SPAN
         return _Span(self, name, args or None)
 
-    def _record(self, name: str, start: float, end: float,
-                args=None) -> None:
-        dur = end - start
+    def _lane(self) -> _Lane:
+        lane = getattr(self._local, "lane", None)
+        if lane is None:
+            with self._lock:
+                lane = self._local.lane = _Lane(self._lanes)
+                self._lanes += 1
+        return lane
+
+    def _record(self, name: str, start: float, dur: float, self_s: float,
+                lane: int, args=None) -> None:
         with self._lock:
             agg = self._agg.get(name)
             if agg is None:
-                self._agg[name] = [1, dur, dur]
+                self._agg[name] = [1, dur, dur, self_s]
             else:
                 agg[0] += 1
                 agg[1] += dur
                 if dur > agg[2]:
                     agg[2] = dur
+                agg[3] += self_s
             if self.trace:
                 event = {
                     "name": name,
                     "ph": "X",
                     "pid": self.process_index,
-                    "tid": threading.get_ident() & 0xFFFF,
+                    "tid": lane,
                     "ts": (start - self._t0) * 1e6,  # µs, run-relative
                     "dur": dur * 1e6,
                 }
@@ -170,6 +236,7 @@ class Tracer:
                 self._append_event(event)
 
     def _note_compile(self, duration: float) -> None:
+        lane = self._lane().index  # the compiling thread's
         with self._lock:
             self._compiles += 1
             self._compile_secs += duration
@@ -181,7 +248,7 @@ class Tracer:
                     "name": "compile",
                     "ph": "X",
                     "pid": self.process_index,
-                    "tid": threading.get_ident() & 0xFFFF,
+                    "tid": lane,
                     # the monitoring hook fires at compile END; back-date
                     # the block so the timeline shows when it ran
                     "ts": max(0.0, (now - self._t0 - duration)) * 1e6,
@@ -217,8 +284,9 @@ class Tracer:
 
     def drain(self) -> Dict[str, Dict[str, float]]:
         """Return and reset the per-phase aggregates since the last
-        drain: ``{phase: {count, total_ms, max_ms}}``, with compiles
-        (retraces included) reported as the ``compile`` pseudo-phase."""
+        drain: ``{phase: {count, total_ms, max_ms, self_ms}}``, with
+        compiles (retraces included) reported as the ``compile``
+        pseudo-phase (which nothing nests in: all of it is self time)."""
         with self._lock:
             agg, self._agg = self._agg, {}
             compiles, self._compiles = self._compiles, 0
@@ -229,14 +297,16 @@ class Tracer:
                 "count": int(c),
                 "total_ms": round(t * 1000.0, 3),
                 "max_ms": round(m * 1000.0, 3),
+                "self_ms": round(own * 1000.0, 3),
             }
-            for name, (c, t, m) in sorted(agg.items())
+            for name, (c, t, m, own) in sorted(agg.items())
         }
         if compiles:
             out["compile"] = {
                 "count": compiles,
                 "total_ms": round(csecs * 1000.0, 3),
                 "max_ms": round(cmax * 1000.0, 3),
+                "self_ms": round(csecs * 1000.0, 3),
             }
         return out
 

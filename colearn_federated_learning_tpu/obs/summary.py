@@ -134,11 +134,17 @@ def summarize_records(records: List[Dict[str, Any]]) -> Dict[str, Any]:
         if ev == "spans":
             for name, agg in (rec.get("phases") or {}).items():
                 cur = phases.setdefault(
-                    name, {"count": 0, "total_ms": 0.0, "max_ms": 0.0}
+                    name, {"count": 0, "total_ms": 0.0, "max_ms": 0.0,
+                           "self_ms": 0.0}
                 )
                 cur["count"] += int(agg.get("count", 0))
                 cur["total_ms"] += float(agg.get("total_ms", 0.0))
                 cur["max_ms"] = max(cur["max_ms"], float(agg.get("max_ms", 0.0)))
+                # logs from before the tracer kept a span stack have no
+                # self time: all of a span then counts as its own
+                cur["self_ms"] += float(
+                    agg.get("self_ms", agg.get("total_ms", 0.0))
+                )
             continue
         if ev == "health":
             kind = rec.get("kind", "?")
@@ -315,8 +321,8 @@ def format_summary(summary: Dict[str, Any], path: str = "") -> str:
         )
         lines.append("")
         lines.append(
-            f"{'phase':<24}{'count':>8}{'total s':>11}{'mean ms':>10}"
-            f"{'max ms':>10}{'share':>8}"
+            f"{'phase':<24}{'count':>8}{'total s':>11}{'self s':>11}"
+            f"{'mean ms':>10}{'max ms':>10}{'share':>8}"
         )
         for name in sorted(phases, key=lambda n: -phases[n]["total_ms"]):
             p = phases[name]
@@ -324,6 +330,7 @@ def format_summary(summary: Dict[str, Any], path: str = "") -> str:
             share = p["total_ms"] / base if base else 0.0
             lines.append(
                 f"{name:<24}{p['count']:>8}{p['total_ms'] / 1000.0:>11.3f}"
+                f"{p.get('self_ms', p['total_ms']) / 1000.0:>11.3f}"
                 f"{mean:>10.2f}{p['max_ms']:>10.2f}{share:>7.0%} "
             )
     else:

@@ -67,27 +67,32 @@ def make_dp_grad_fn(loss_fn, cfg: DPConfig, batch_axis: str | None = None):
 
         def micro_step(acc, inp):
             xs, ys, ms = inp
-            losses, grads = jax.vmap(single_example_grad, in_axes=(None, 0, 0))(
-                params, xs, ys
-            )  # grads: pytree with leading [mb]
+            with jax.named_scope("dp_example_grad"):
+                losses, grads = jax.vmap(
+                    single_example_grad, in_axes=(None, 0, 0)
+                )(params, xs, ys)  # grads: pytree with leading [mb]
             # The privacy-critical math runs in f32 no matter what dtype
             # training uses (run.local_param_dtype may be bf16): the clip
             # norm is an f32 sum of squares of the exact released values,
             # so ‖scale·g‖₂ ≤ l2_clip holds in f32 and the accountant's
             # sensitivity assumption stays valid.
-            grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
-            norms = jnp.sqrt(
-                sum(
-                    jnp.sum(jnp.square(g.reshape(mb, -1)), axis=1)
-                    for g in jax.tree.leaves(grads)
+            with jax.named_scope("dp_clip"):
+                grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
+                norms = jnp.sqrt(
+                    sum(
+                        jnp.sum(jnp.square(g.reshape(mb, -1)), axis=1)
+                        for g in jax.tree.leaves(grads)
+                    )
                 )
-            )
-            scale = jnp.minimum(1.0, cfg.l2_clip / jnp.maximum(norms, 1e-12)) * ms
-            clipped_sum = jax.tree.map(
-                lambda g: jnp.einsum("b,b...->...", scale, g), grads
-            )
-            acc_g, acc_loss = acc
-            return (trees.tree_add(acc_g, clipped_sum), acc_loss + (losses * ms).sum()), None
+                scale = jnp.minimum(
+                    1.0, cfg.l2_clip / jnp.maximum(norms, 1e-12)
+                ) * ms
+                clipped_sum = jax.tree.map(
+                    lambda g: jnp.einsum("b,b...->...", scale, g), grads
+                )
+                acc_g, acc_loss = acc
+                acc_g = trees.tree_add(acc_g, clipped_sum)
+            return (acc_g, acc_loss + (losses * ms).sum()), None
 
         # Initial accumulators derive their sharding type from the data
         # (0·Σm), so the scan carry type-checks identically inside a
@@ -112,22 +117,24 @@ def make_dp_grad_fn(loss_fn, cfg: DPConfig, batch_axis: str | None = None):
         """Shared mechanism tail: Gaussian noise on the CLIPPED SUM,
         then the fixed-denominator mean — identical for both clipping
         strategies (they differ only in how Σ sᵢ·gᵢ is computed)."""
-        denom = jnp.maximum(n, 1.0)
-        keys = jax.random.split(rng, len(jax.tree.leaves(params)))
-        keys = jax.tree.unflatten(jax.tree.structure(params), list(keys))
-        sigma = cfg.noise_multiplier * cfg.l2_clip
-        # Noise is drawn and added in f32 (an exact Gaussian at σ, as the
-        # accountant assumes); the cast back to the training dtype is
-        # post-processing, which preserves the DP guarantee.
-        noisy = jax.tree.map(
-            lambda g, k, p: (
-                (g + sigma * jax.random.normal(k, g.shape, jnp.float32)) / denom
-            ).astype(p.dtype),
-            g_sum,
-            keys,
-            params,
-        )
-        return loss_sum / denom, noisy
+        with jax.named_scope("dp_noise"):
+            denom = jnp.maximum(n, 1.0)
+            keys = jax.random.split(rng, len(jax.tree.leaves(params)))
+            keys = jax.tree.unflatten(jax.tree.structure(params), list(keys))
+            sigma = cfg.noise_multiplier * cfg.l2_clip
+            # Noise is drawn and added in f32 (an exact Gaussian at σ, as
+            # the accountant assumes); the cast back to the training dtype
+            # is post-processing, which preserves the DP guarantee.
+            noisy = jax.tree.map(
+                lambda g, k, p: (
+                    (g + sigma * jax.random.normal(k, g.shape, jnp.float32))
+                    / denom
+                ).astype(p.dtype),
+                g_sum,
+                keys,
+                params,
+            )
+            return loss_sum / denom, noisy
 
     def dp_grads_two_pass(params, x, y, m, rng):
         """Ghost-norm-style exact clipping in its JAX-native form
@@ -189,25 +196,33 @@ def make_dp_grad_fn(loss_fn, cfg: DPConfig, batch_axis: str | None = None):
 
         def norm_micro(_, inp):
             xs, ys = inp
-            losses, sqs = jax.vmap(example_sqnorm)(xs, ys)
+            with jax.named_scope("dp_example_grad"):
+                losses, sqs = jax.vmap(example_sqnorm)(xs, ys)
             return 0.0, (losses, sqs)
 
         _, (losses, sqnorms) = jax.lax.scan(norm_micro, 0.0, (xm, ym))
-        losses = losses.reshape(b)
-        norms = jnp.sqrt(sqnorms.reshape(b))
-        # clip scales in f32 (privacy-critical, as in the microbatch path)
-        scale = jnp.minimum(1.0, cfg.l2_clip / jnp.maximum(norms, 1e-12)) * m
-        # pass 2: one batched weighted backward. loss_fn(mask=scale) is
-        # Σ sᵢ·lᵢ / max(Σ sᵢ, 1) (the masked-mean contract every loss in
-        # this codebase follows — the same max-with-1 floor as the
-        # engines' degenerate denominators); the denominator does not
-        # depend on θ, so scaling the gradient by the SAME floored value
-        # recovers the clipped SUM exactly, including when Σ sᵢ < 1.
-        s_den = jnp.maximum(scale.sum(), 1.0)
-        _, g_mean = jax.value_and_grad(loss_fn)(vparams, x, y, scale)
-        g_sum = jax.tree.map(
-            lambda g: g.astype(jnp.float32) * s_den, g_mean
-        )
+        # pass 2 is this mode's clipped-sum accumulation: the whole of
+        # it, its batched backward included, counts as clipping
+        with jax.named_scope("dp_clip"):
+            losses = losses.reshape(b)
+            norms = jnp.sqrt(sqnorms.reshape(b))
+            # clip scales in f32 (privacy-critical, as in the microbatch
+            # path)
+            scale = jnp.minimum(
+                1.0, cfg.l2_clip / jnp.maximum(norms, 1e-12)
+            ) * m
+            # pass 2: one batched weighted backward. loss_fn(mask=scale)
+            # is Σ sᵢ·lᵢ / max(Σ sᵢ, 1) (the masked-mean contract every
+            # loss in this codebase follows — the same max-with-1 floor
+            # as the engines' degenerate denominators); the denominator
+            # does not depend on θ, so scaling the gradient by the SAME
+            # floored value recovers the clipped SUM exactly, including
+            # when Σ sᵢ < 1.
+            s_den = jnp.maximum(scale.sum(), 1.0)
+            _, g_mean = jax.value_and_grad(loss_fn)(vparams, x, y, scale)
+            g_sum = jax.tree.map(
+                lambda g: g.astype(jnp.float32) * s_den, g_mean
+            )
         loss_sum = (losses * m).sum()
         n = m.sum()
         if batch_axis is not None:

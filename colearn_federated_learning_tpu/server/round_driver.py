@@ -1973,6 +1973,16 @@ class Experiment:
             n_ex = self._put(n_ex, self._client_sharding)
         return idx, mask, n_ex, train_x, train_y
 
+    def _on_worker(self, first_round: int, build, *args):
+        """The prefetch worker's bracket around one build: span
+        ``round.prefetch``, whose ``round`` is the first round (1-based)
+        of the steady-state dispatch that will consume what is built —
+        the identifier the main thread's ``round.run`` of that dispatch
+        carries too, and the worker's sampler / slab / churn spans
+        inherit."""
+        with self.tracer.span("round.prefetch", round=first_round + 1):
+            return build(*args)
+
     def _build_prefetch_entry(self, round_idx: int, spe: Optional[int],
                               place: bool) -> Dict[str, Any]:
         """Worker-thread body: build (and, double-buffered, place) one
@@ -2059,8 +2069,8 @@ class Experiment:
         if ex is None:
             return
         self._chunk_prefetch[start] = ex.submit(
-            self._build_chunk_slab_entry, start, fuse,
-            self._prefetch_spe(start),
+            self._on_worker, start, self._build_chunk_slab_entry, start,
+            fuse, self._prefetch_spe(start),
         )
 
     def _ensure_executor(self):
@@ -2114,7 +2124,8 @@ class Experiment:
                     continue
             place = self._double_buffer and fuse == 1
             self._prefetch[t] = ex.submit(
-                self._build_prefetch_entry, t, self._prefetch_spe(t), place
+                self._on_worker, t - t % fuse, self._build_prefetch_entry,
+                t, self._prefetch_spe(t), place,
             )
 
     def _round_inputs(self, round_idx: int, place: bool = True,
@@ -2887,9 +2898,11 @@ class Experiment:
         fetch, no sampler/churn/slab python), then feeds the unchanged
         per-client oracle loop."""
         with self.tracer.span("round.host_inputs"):
-            sched = jax.device_get(self._device_schedule_jit(
+            sched = self._device_schedule_jit(
                 self._device_arrays, jnp.int32(round_idx)
-            ))
+            )
+        with self.tracer.span("round.device_wait", what="schedule"):
+            sched = jax.device_get(sched)
         self._note_device_sched(round_idx, 1, sched)
         rng = jax.random.fold_in(state["rng_key"], round_idx)
         kw = {}
@@ -3157,208 +3170,221 @@ class Experiment:
 
     def run_round(self, state: Dict[str, Any], round_idx: int,
                   fuse_override: Optional[int] = None) -> Dict[str, Any]:
-        """``fuse_override=1`` forces a single unfused round through the
+        """One dispatch: a round, or a fused chunk of rounds.
+        ``fuse_override=1`` forces a single unfused round through the
         lazily-built fuse=1 engine twin — the catch-up path for resumes
-        that land off a chunk boundary (see _fit_body)."""
-        if self.fedbuff:
-            return self._run_async_round(state, round_idx)
-        if self._hier:
-            return self._run_hier_round(state, round_idx)
-        if self._cp_device:
-            # device control plane: the program derives its own
-            # schedule — none of the host input machinery below runs
-            return self._run_device_round(
-                state, round_idx,
+        that land off a chunk boundary (see _fit_body).
+
+        Span ``round.run`` brackets the whole call and names the
+        dispatch's first round (1-based, as the records count): the
+        spans below inherit that ``round``, and what runs under none of
+        them — this loop's own Python, the observers' bookkeeping — is
+        ``round.run``'s self time. The body stays inline under the
+        ``with``: one more Python frame beneath the trace of a ViT-sized
+        round program cost 12 s of set-up on the chip's host (PERF.md
+        section 6, PR 23)."""
+        with self.tracer.span("round.run", round=round_idx + 1):
+            if self.fedbuff:
+                return self._run_async_round(state, round_idx)
+            if self._hier:
+                return self._run_hier_round(state, round_idx)
+            if self._cp_device:
+                # device control plane: the program derives its own
+                # schedule — none of the host input machinery below runs
+                return self._run_device_round(
+                    state, round_idx,
+                    self.cfg.run.fuse_rounds if fuse_override is None
+                    else fuse_override,
+                )
+            if (self._snapshot_refresh and round_idx > 0
+                    and round_idx % self._ledger_cfg.log_every == 0):
+                # snapshot/sketch refresh BEFORE this round samples: the
+                # cohort for rounds [r, r + log_every) is a pure function of
+                # (seed, round, ledger@r) — round 0 keeps the all-unseen
+                # uniform prior (the zero snapshot/sketch init_state seeds)
+                self._refresh_adaptive_snapshot(round_idx)
+            fuse = (
                 self.cfg.run.fuse_rounds if fuse_override is None
-                else fuse_override,
+                else fuse_override
             )
-        if (self._snapshot_refresh and round_idx > 0
-                and round_idx % self._ledger_cfg.log_every == 0):
-            # snapshot/sketch refresh BEFORE this round samples: the
-            # cohort for rounds [r, r + log_every) is a pure function of
-            # (seed, round, ledger@r) — round 0 keeps the all-unseen
-            # uniform prior (the zero snapshot/sketch init_state seeds)
-            self._refresh_adaptive_snapshot(round_idx)
-        fuse = (
-            self.cfg.run.fuse_rounds if fuse_override is None
-            else fuse_override
-        )
-        if fuse > 1:
-            return self._run_fused_chunk(state, round_idx, fuse)
-        round_fn = self.round_fn
-        if self.cfg.run.fuse_rounds > 1:
-            round_fn = self._unfused_round_fn()
-        (cohort, idx, mask, n_ex, train_x, train_y,
-         n_host) = self._round_inputs(round_idx)
-        if self._population is not None:
-            self._population.observe_cohort(
-                round_idx, cohort, n_host,
-                self.sampler.take_draw_stats(round_idx),
-            )
-        rng = jax.random.fold_in(state["rng_key"], round_idx)
-        # Byzantine mask for this round's cohort: which sampled slots
-        # the adversary owns. An ARRAY input alongside n_ex (no
-        # retrace); poisson pad slots (id == num_clients) can never be
-        # compromised. byzantine_count is recorded for every attack
-        # kind (label_flip included — its slots attack through data).
-        akw = {}
-        if self.attack_kind:
-            byz_h = np.isin(np.asarray(cohort), self.compromised)
-            self._attack_stats[round_idx] = int(byz_h.sum())
-            if self._attack_upload:
-                byz = jnp.asarray(byz_h.astype(np.float32))
-                if self._client_sharding is not None:
-                    byz = self._put(byz, self._client_sharding)
-                akw["byz"] = byz
-        if self.gossip:
-            extra = ()
-            if self._gossip_partial:
-                extra = (self._put(
-                    jnp.asarray(np.asarray(cohort, np.int32)),
-                    self._data_sharding,
-                ),)
-            with self.tracer.span("round.dispatch"):
-                replicas, mean_params, metrics = round_fn(
-                    state["replicas"], train_x, train_y, idx, mask, n_ex,
-                    rng, *extra, **akw,
+            if fuse > 1:
+                return self._run_fused_chunk(state, round_idx, fuse)
+            round_fn = self.round_fn
+            if self.cfg.run.fuse_rounds > 1:
+                round_fn = self._unfused_round_fn()
+            (cohort, idx, mask, n_ex, train_x, train_y,
+             n_host) = self._round_inputs(round_idx)
+            if self._population is not None:
+                self._population.observe_cohort(
+                    round_idx, cohort, n_host,
+                    self.sampler.take_draw_stats(round_idx),
                 )
-            return {
-                "params": mean_params,
-                "server_opt_state": state["server_opt_state"],
-                "round": round_idx + 1,
-                "rng_key": state["rng_key"],
-                "replicas": replicas,
-                "_metrics": metrics,
-            }
-        if self.store_state:
-            # scaffold/feddyn carry c_global on top of the per-client
-            # store; error feedback is store-only. One branch covers
-            # both — the round fn's extra leading state arg (c_global)
-            # and return slot exist exactly when self.stateful.
-            common = (state["params"], state["server_opt_state"],
-                      train_x, train_y, idx, mask, n_ex, rng)
-            glob = (state["c_global"],) if self.stateful else ()
-            ledger = None
-            if self._data_sharding is not None:
-                # device-resident store: the cohort gather/scatter runs
-                # INSIDE the round program (donated, so the store is
-                # updated in place) — no host sync, multi-host capable
-                cohort_dev = self._put(
-                    jnp.asarray(np.asarray(cohort, np.int32)),
-                    self._data_sharding,
-                )
-                ltail = (state["ledger"],) if self._ledger_on else ()
-                with self._bucket_compile_span(round_idx, int(idx.shape[1])), \
-                        self.tracer.span("round.dispatch"):
-                    out = round_fn(
-                        *common, *glob, state["c_clients"], cohort_dev,
-                        *ltail,
+            rng = jax.random.fold_in(state["rng_key"], round_idx)
+            # Byzantine mask for this round's cohort: which sampled slots
+            # the adversary owns. An ARRAY input alongside n_ex (no
+            # retrace); poisson pad slots (id == num_clients) can never be
+            # compromised. byzantine_count is recorded for every attack
+            # kind (label_flip included — its slots attack through data).
+            akw = {}
+            if self.attack_kind:
+                byz_h = np.isin(np.asarray(cohort), self.compromised)
+                self._attack_stats[round_idx] = int(byz_h.sum())
+                if self._attack_upload:
+                    byz = jnp.asarray(byz_h.astype(np.float32))
+                    if self._client_sharding is not None:
+                        byz = self._put(byz, self._client_sharding)
+                    akw["byz"] = byz
+            if self.gossip:
+                extra = ()
+                if self._gossip_partial:
+                    extra = (self._put(
+                        jnp.asarray(np.asarray(cohort, np.int32)),
+                        self._data_sharding,
+                    ),)
+                with self.tracer.span("round.dispatch"):
+                    replicas, mean_params, metrics = round_fn(
+                        state["replicas"], train_x, train_y, idx, mask, n_ex,
+                        rng, *extra, **akw,
                     )
-                if self._ledger_on:
-                    *head, c_clients, ledger, metrics = out
+                return {
+                    "params": mean_params,
+                    "server_opt_state": state["server_opt_state"],
+                    "round": round_idx + 1,
+                    "rng_key": state["rng_key"],
+                    "replicas": replicas,
+                    "_metrics": metrics,
+                }
+            if self.store_state:
+                # scaffold/feddyn carry c_global on top of the per-client
+                # store; error feedback is store-only. One branch covers
+                # both — the round fn's extra leading state arg (c_global)
+                # and return slot exist exactly when self.stateful.
+                common = (state["params"], state["server_opt_state"],
+                          train_x, train_y, idx, mask, n_ex, rng)
+                glob = (state["c_global"],) if self.stateful else ()
+                ledger = None
+                if self._data_sharding is not None:
+                    # device-resident store: the cohort gather/scatter runs
+                    # INSIDE the round program (donated, so the store is
+                    # updated in place) — no host sync, multi-host capable
+                    cohort_dev = self._put(
+                        jnp.asarray(np.asarray(cohort, np.int32)),
+                        self._data_sharding,
+                    )
+                    ltail = (state["ledger"],) if self._ledger_on else ()
+                    with self._bucket_compile_span(round_idx, int(idx.shape[1])), \
+                            self.tracer.span("round.dispatch"):
+                        out = round_fn(
+                            *common, *glob, state["c_clients"], cohort_dev,
+                            *ltail,
+                        )
+                    if self._ledger_on:
+                        *head, c_clients, ledger, metrics = out
+                    else:
+                        *head, c_clients, metrics = out
                 else:
-                    *head, c_clients, metrics = out
-            else:
-                # sequential oracle: host-resident numpy store with an
-                # explicit per-round gather/scatter. Poisson pad slots
-                # carry id == num_clients (OOB by construction): gather
-                # reads row 0 in their place (harmless — pad rows are
-                # fully masked) and the scatter SKIPS them, mirroring
-                # the sharded engine's take-fill/scatter-drop semantics.
-                rows = np.asarray(cohort)
-                real = rows < self.fed.num_clients
-                safe = np.where(real, rows, 0)
-                c_cohort = jax.tree.map(
-                    lambda a: jnp.asarray(a[safe]), state["c_clients"]
-                )
-                lkw = {}
-                if self._ledger_on:
-                    lkw = dict(
-                        ledger=state["ledger"],
-                        ledger_ids=jnp.asarray(
-                            np.asarray(cohort, np.int32)
+                    # sequential oracle: host-resident numpy store with an
+                    # explicit per-round gather/scatter. Poisson pad slots
+                    # carry id == num_clients (OOB by construction): gather
+                    # reads row 0 in their place (harmless — pad rows are
+                    # fully masked) and the scatter SKIPS them, mirroring
+                    # the sharded engine's take-fill/scatter-drop semantics.
+                    rows = np.asarray(cohort)
+                    real = rows < self.fed.num_clients
+                    safe = np.where(real, rows, 0)
+                    c_cohort = jax.tree.map(
+                        lambda a: jnp.asarray(a[safe]), state["c_clients"]
+                    )
+                    lkw = {}
+                    if self._ledger_on:
+                        lkw = dict(
+                            ledger=state["ledger"],
+                            ledger_ids=jnp.asarray(
+                                np.asarray(cohort, np.int32)
+                            ),
+                        )
+                    with self._bucket_compile_span(round_idx, int(idx.shape[1])), \
+                            self.tracer.span("round.dispatch"):
+                        out = round_fn(
+                            *common, *(glob or (None,)), c_cohort, **lkw,
+                        )
+                    if self._ledger_on:
+                        *head, new_c_cohort, ledger, metrics = out
+                    else:
+                        *head, new_c_cohort, metrics = out
+                    with self.tracer.span("round.device_wait",
+                                          what="client_state"):
+                        fetched = jax.device_get(new_c_cohort)
+                    jax.tree.map(
+                        lambda store, f: store.__setitem__(
+                            rows[real], f[real]
                         ),
+                        state["c_clients"], fetched,
                     )
-                with self._bucket_compile_span(round_idx, int(idx.shape[1])), \
-                        self.tracer.span("round.dispatch"):
-                    out = round_fn(
-                        *common, *(glob or (None,)), c_cohort, **lkw,
-                    )
+                    c_clients = state["c_clients"]
+                new_state = {
+                    "params": head[0],
+                    "server_opt_state": head[1],
+                    "round": round_idx + 1,
+                    "rng_key": state["rng_key"],
+                    "c_clients": c_clients,
+                    "_metrics": metrics,
+                }
                 if self._ledger_on:
-                    *head, new_c_cohort, ledger, metrics = out
-                else:
-                    *head, new_c_cohort, metrics = out
-                fetched = jax.device_get(new_c_cohort)
-                jax.tree.map(
-                    lambda store, f: store.__setitem__(
-                        rows[real], f[real]
-                    ),
-                    state["c_clients"], fetched,
-                )
-                c_clients = state["c_clients"]
-            new_state = {
-                "params": head[0],
-                "server_opt_state": head[1],
-                "round": round_idx + 1,
-                "rng_key": state["rng_key"],
-                "c_clients": c_clients,
-                "_metrics": metrics,
-            }
+                    new_state["ledger"] = ledger
+                if self.stateful:
+                    new_state["c_global"] = head[2]
+                return new_state
+            kw = dict(akw)
+            if self.secagg and self.cfg.server.secagg_mode == "pairwise":
+                with self.tracer.span("round.secagg_keys"):
+                    kw["pair_seeds"] = self._pairwise_seeds(round_idx, n_host)
             if self._ledger_on:
-                new_state["ledger"] = ledger
-            if self.stateful:
-                new_state["c_global"] = head[2]
-            return new_state
-        kw = dict(akw)
-        if self.secagg and self.cfg.server.secagg_mode == "pairwise":
-            with self.tracer.span("round.secagg_keys"):
-                kw["pair_seeds"] = self._pairwise_seeds(round_idx, n_host)
-        if self._ledger_on:
-            with self.tracer.span("round.host_inputs.slot_assign"):
-                cohort_ids = jnp.asarray(
-                    self._ledger_slot_ids(cohort, round_idx, state)
+                with self.tracer.span("round.host_inputs.slot_assign"):
+                    cohort_ids = jnp.asarray(
+                        self._ledger_slot_ids(cohort, round_idx, state)
+                    )
+                if self._data_sharding is not None:
+                    # sharded: positional trailing (byz, ledger, cohort) so
+                    # the ledger input stays donatable
+                    with self._bucket_compile_span(round_idx, int(idx.shape[1])), \
+                            self.tracer.span("round.dispatch"):
+                        params, opt_state, ledger, metrics = round_fn(
+                            state["params"], state["server_opt_state"],
+                            train_x, train_y, idx, mask, n_ex, rng,
+                            kw.get("byz"), state["ledger"],
+                            self._put(cohort_ids, self._data_sharding),
+                        )
+                else:
+                    with self._bucket_compile_span(round_idx, int(idx.shape[1])), \
+                            self.tracer.span("round.dispatch"):
+                        params, opt_state, ledger, metrics = round_fn(
+                            state["params"], state["server_opt_state"],
+                            train_x, train_y, idx, mask, n_ex, rng,
+                            ledger=state["ledger"], ledger_ids=cohort_ids,
+                            **kw,
+                        )
+                return {
+                    "params": params,
+                    "server_opt_state": opt_state,
+                    "round": round_idx + 1,
+                    "rng_key": state["rng_key"],
+                    "ledger": ledger,
+                    "_metrics": metrics,
+                }
+            with self._bucket_compile_span(round_idx, int(idx.shape[1])), \
+                    self.tracer.span("round.dispatch"):
+                params, opt_state, metrics = round_fn(
+                    state["params"], state["server_opt_state"],
+                    train_x, train_y, idx, mask, n_ex, rng, **kw,
                 )
-            if self._data_sharding is not None:
-                # sharded: positional trailing (byz, ledger, cohort) so
-                # the ledger input stays donatable
-                with self._bucket_compile_span(round_idx, int(idx.shape[1])), \
-                        self.tracer.span("round.dispatch"):
-                    params, opt_state, ledger, metrics = round_fn(
-                        state["params"], state["server_opt_state"],
-                        train_x, train_y, idx, mask, n_ex, rng,
-                        kw.get("byz"), state["ledger"],
-                        self._put(cohort_ids, self._data_sharding),
-                    )
-            else:
-                with self._bucket_compile_span(round_idx, int(idx.shape[1])), \
-                        self.tracer.span("round.dispatch"):
-                    params, opt_state, ledger, metrics = round_fn(
-                        state["params"], state["server_opt_state"],
-                        train_x, train_y, idx, mask, n_ex, rng,
-                        ledger=state["ledger"], ledger_ids=cohort_ids,
-                        **kw,
-                    )
             return {
                 "params": params,
                 "server_opt_state": opt_state,
                 "round": round_idx + 1,
                 "rng_key": state["rng_key"],
-                "ledger": ledger,
                 "_metrics": metrics,
             }
-        with self._bucket_compile_span(round_idx, int(idx.shape[1])), \
-                self.tracer.span("round.dispatch"):
-            params, opt_state, metrics = round_fn(
-                state["params"], state["server_opt_state"],
-                train_x, train_y, idx, mask, n_ex, rng, **kw,
-            )
-        return {
-            "params": params,
-            "server_opt_state": opt_state,
-            "round": round_idx + 1,
-            "rng_key": state["rng_key"],
-            "_metrics": metrics,
-        }
 
     def _run_fused_chunk(self, state: Dict[str, Any], round_idx: int,
                          fuse: int) -> Dict[str, Any]:
@@ -3477,19 +3503,24 @@ class Experiment:
                 else self._fused_cohort_sharding,
             )
             n_ex_f = self._put(np.stack(n_exs), self._fused_client_sharding)
-            # rng keys are tiny device scalars derived identically on
-            # every process; stack on host (normalizing typed PRNG keys
-            # — a restored checkpoint's rng_key comes back typed — to
-            # their raw uint32 data, which fold_in/split accept with
-            # identical bits), replicate like other per-round inputs
-            def _key_data(k):
-                if jax.dtypes.issubdtype(k.dtype, jax.dtypes.prng_key):
-                    k = jax.random.key_data(k)
-                return np.asarray(k)
+        # rng keys are tiny device scalars derived identically on every
+        # process; stack on host (normalizing typed PRNG keys — a
+        # restored checkpoint's rng_key comes back typed — to their raw
+        # uint32 data, which fold_in/split accept with identical bits),
+        # replicate like other per-round inputs
+        def _key_data(k):
+            if jax.dtypes.issubdtype(k.dtype, jax.dtypes.prng_key):
+                k = jax.random.key_data(k)
+            return np.asarray(k)
 
-            rngs_f = self._put(
-                np.stack([_key_data(r) for r in rngs]), self._data_sharding
-            )
+        # the keys were derived ON the device, behind the dispatch in
+        # flight: reading them back holds the host until that dispatch
+        # has finished. That is waiting, not placement, and has a span
+        # of its own.
+        with self.tracer.span("round.device_wait", what="rng_keys"):
+            keys_h = [_key_data(r) for r in rngs]
+        with self.tracer.span("round.placement"):
+            rngs_f = self._put(np.stack(keys_h), self._data_sharding)
             tail = ()
             if byz_rows:
                 tail = (self._put(
@@ -3658,9 +3689,12 @@ class Experiment:
         ids = np.asarray(cohort, np.int64)
         if self._pager is None:
             return ids.astype(np.int32)
+        def fetch_hot():
+            with self.tracer.span("round.device_wait", what="ledger_hot"):
+                return np.asarray(jax.device_get(state["ledger"]))
+
         slots, new_slots, seed_rows = self._pager.assign(
-            ids, round_idx,
-            fetch_hot=lambda: np.asarray(jax.device_get(state["ledger"])),
+            ids, round_idx, fetch_hot=fetch_hot,
         )
         if len(new_slots):
             upd = self._put(jnp.asarray(seed_rows), self._data_sharding)
@@ -3708,7 +3742,8 @@ class Experiment:
         written back into the cold mmap and the merged view scanned —
         client ids throughout, never slots, so records/reports/snapshots
         are layout-independent (paged ≡ dense, test-pinned)."""
-        hot = np.asarray(jax.device_get(self._ledger_ref))
+        with self.tracer.span("round.device_wait", what="ledger"):
+            hot = np.asarray(jax.device_get(self._ledger_ref))
         if self._pager is not None:
             return self._pager.active_rows(hot)
         active = np.flatnonzero(hot[:, 0] > 0)

@@ -402,27 +402,3 @@ def test_checked_in_history_passes_repo_budgets(capsys):
                      "tests/fixtures/bench_history",
                      "--baseline", "BENCH_BUDGETS.json"]) == 0
     assert "gates: PASS" in capsys.readouterr().out
-
-
-# ---------------------------------------------------------------------------
-# observability tax: obs-on keeps host_exposed under the bench ceiling
-# ---------------------------------------------------------------------------
-
-
-def test_obs_on_host_exposed_under_bench_ceiling(tmp_path):
-    from colearn_federated_learning_tpu.obs.roofline import host_exposed_pct
-
-    _, _, records = _fit(_tiny_cfg(out=str(tmp_path), rounds=4))
-    phase_ms = {}
-    for rec in _events(records, "spans"):
-        for name, agg in (rec.get("phases") or {}).items():
-            phase_ms[name] = phase_ms.get(name, 0.0) + float(
-                agg.get("total_ms", 0.0))
-    assert "obs.executables" in phase_ms  # registry work is spanned...
-    run_sum = _events(records, "run_summary")[-1]
-    hep = host_exposed_pct(phase_ms, float(run_sum["wall_time_sec"]))
-    # ...and excluded: the AOT compiles (seconds on this smoke) must
-    # not book as host-exposed time, or obs-on would blow the budget
-    budgets = json.load(open("BENCH_BUDGETS.json"))
-    assert hep is not None
-    assert hep < float(budgets["host_exposed_pct_max"])

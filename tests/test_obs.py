@@ -75,9 +75,97 @@ def test_tracer_trace_export_is_wellformed_and_nested(tmp_path):
     assert c["ts"] + c["dur"] <= p["ts"] + p["dur"]
 
 
+def test_self_ms_is_duration_minus_child_spans():
+    """Nested and sibling spans on a fake clock that ticks once per
+    read: every span lasts 1 s plus what it holds."""
+    clock = iter(float(t) for t in range(100))
+    tracer = Tracer(clock=lambda: next(clock))
+    with tracer.span("run"):                # [1, 10]
+        with tracer.span("inputs"):         # [2, 5]
+            with tracer.span("sampler"):    # [3, 4]
+                pass
+        with tracer.span("wait"):           # [6, 7]
+            pass
+        with tracer.span("wait"):           # [8, 9]
+            pass
+    agg = tracer.drain()
+    assert agg["run"]["total_ms"] == pytest.approx(9000.0)
+    # minus inputs (3 s) and the two waits (1 s each), not the sampler
+    # again: that is inside inputs already
+    assert agg["run"]["self_ms"] == pytest.approx(4000.0)
+    assert agg["inputs"]["self_ms"] == pytest.approx(2000.0)
+    assert agg["sampler"]["self_ms"] == agg["sampler"]["total_ms"]
+    assert agg["wait"]["count"] == 2
+    assert agg["wait"]["self_ms"] == pytest.approx(2000.0)
+    for name, a in agg.items():
+        assert set(a) == {"count", "total_ms", "max_ms", "self_ms"}, name
+
+
+def test_spans_of_two_threads_nest_apart_and_have_lanes_of_their_own():
+    """A span opened on the worker while the main thread's span is open
+    is not that span's child: it neither shortens its self time nor
+    inherits its round, and the two threads' Chrome events sit in lanes
+    0 and 1 (``get_ident() & 0xFFFF`` could merge two threads)."""
+    import threading
+
+    ticks = iter(float(t) for t in range(100))
+    lock = threading.Lock()
+
+    def clock():
+        with lock:
+            return next(ticks)
+
+    tracer = Tracer(trace=True, clock=clock)
+    opened, done = threading.Event(), threading.Event()
+
+    def worker():
+        assert opened.wait(10)
+        with tracer.span("round.prefetch", round=5):
+            with tracer.span("round.host_inputs.sampler"):
+                pass
+        done.set()
+
+    t = threading.Thread(target=worker)
+    t.start()
+    with tracer.span("round.run", round=1):
+        opened.set()
+        assert done.wait(10)
+        with tracer.span("round.dispatch", fuse=4):
+            pass
+    t.join(10)
+    assert not t.is_alive()
+    agg = tracer.drain()
+    # run: [1, 8] holds the worker's four ticks and dispatch [6, 7]
+    assert agg["round.run"]["total_ms"] == pytest.approx(7000.0)
+    assert agg["round.run"]["self_ms"] == pytest.approx(6000.0)
+    assert agg["round.prefetch"]["self_ms"] == pytest.approx(2000.0)
+    events = {e["name"]: e for e in tracer._events}
+    assert events["round.run"]["tid"] == events["round.dispatch"]["tid"] == 0
+    assert events["round.prefetch"]["tid"] == 1
+    assert events["round.host_inputs.sampler"]["tid"] == 1
+    # the request identifier passes down a thread's own stack only
+    assert events["round.dispatch"]["args"] == {"round": 1, "fuse": 4}
+    assert events["round.host_inputs.sampler"]["args"] == {"round": 5}
+
+
+def test_an_own_round_argument_wins_over_the_inherited_one():
+    tracer = Tracer(trace=True)
+    with tracer.span("round.run", round=1):
+        with tracer.span("round.prefetch", round=9):
+            with tracer.span("leaf", what="x"):
+                pass
+    with tracer.span("outside"):
+        pass
+    events = {e["name"]: e for e in tracer._events}
+    assert events["round.prefetch"]["args"] == {"round": 9}
+    assert events["leaf"]["args"] == {"round": 9, "what": "x"}
+    assert "args" not in events["outside"]
+
+
 def test_tracer_disabled_is_noop():
     tracer = Tracer(enabled=False)
     assert tracer.span("anything") is _NULL_SPAN  # shared singleton
+    assert tracer.span("round.run", round=3) is _NULL_SPAN
     with tracer.span("anything"):
         pass
     assert tracer.drain() == {}
@@ -373,6 +461,76 @@ def test_profile_event_logged_and_trace_closed(tmp_path):
     # the wrap leaked one open)
     jax.profiler.start_trace(str(tmp_path / "p2"))
     jax.profiler.stop_trace()
+
+
+def _host_plane_spans(trace_dir, prefix="round."):
+    """[(name, start, end, line index, stats)] of the ``/host:CPU``
+    plane of the newest trace under ``trace_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                            recursive=True))[-1]
+    plane = ProfileData.from_file(path).find_plane_with_name("/host:CPU")
+    return [
+        (e.name, e.start_ns, e.start_ns + e.duration_ns, i, dict(e.stats))
+        for i, line in enumerate(plane.lines) for e in line.events
+        if e.name.startswith(prefix)
+    ]
+
+
+def test_profiler_session_holds_the_round_spans_on_the_host_plane(tmp_path):
+    """One clock: under a ``jax.profiler`` session the program's spans
+    are events of ``/host:CPU``, nested as the program nests them, each
+    with the first round of its dispatch as argument ``round``."""
+    import jax
+
+    from colearn_federated_learning_tpu.server.round_driver import Experiment
+
+    cfg = _tiny_cfg(tmp_path, "sharded", **{
+        "run.fuse_rounds": 2, "server.num_rounds": 8,
+        "server.eval_every": 0, "run.out_dir": "",
+    })
+    exp = Experiment(cfg, echo=False)
+    try:
+        state = exp._place_state(exp.init_state())
+        state = exp.run_round(state, 0)  # compiles, starts the worker
+        state.pop("_metrics")
+        jax.profiler.start_trace(str(tmp_path / "prof"))
+        try:
+            for r in (2, 4):
+                state = exp.run_round(state, r)
+                jax.block_until_ready(state.pop("_metrics"))
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        exp._stop_prefetch()
+    spans = _host_plane_spans(str(tmp_path / "prof"))
+    runs = [s for s in spans if s[0] == "round.run"]
+    assert [s[4]["round"] for s in runs] == [3, 5]
+    for _, lo, hi, line, stats in runs:
+        inside = {s[0]: s for s in spans
+                  if s[3] == line and lo <= s[1] and s[2] <= hi and s[0] != "round.run"}
+        for name in ("round.host_inputs", "round.placement",
+                     "round.device_wait", "round.dispatch"):
+            assert name in inside, (name, sorted(inside))
+            assert inside[name][4]["round"] == stats["round"]
+        assert inside["round.device_wait"][4]["what"] == "rng_keys"
+        assert inside["round.dispatch"][4]["fuse"] == 2
+        # waiting is not placement: the two never overlap
+        wait = inside["round.device_wait"]
+        for s in spans:
+            if s[0] == "round.placement" and s[3] == line:
+                assert s[2] <= wait[1] or wait[2] <= s[1]
+    # the worker's spans sit on a line of their own and name the
+    # dispatch their entry is for
+    worker = [s for s in spans if s[0] == "round.prefetch"]
+    assert worker and {s[3] for s in worker}.isdisjoint({s[3] for s in runs})
+    assert {s[4]["round"] for s in worker} <= {5, 7, 9}
+    sub = [s for s in spans if s[0] == "round.host_inputs.sampler"
+           and s[3] == worker[0][3]]
+    assert sub and all("round" in s[4] for s in sub)
 
 
 def test_summary_resolution_errors(tmp_path):
