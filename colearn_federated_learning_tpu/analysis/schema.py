@@ -167,7 +167,9 @@ REGISTRY: Dict[str, RecordSpec] = {
                   "peak_hbm_bytes_per_sec", "device_kind", "n_chips",
                   "process_index",
                   "cohort_layout", "clients_per_lane", "gemm_rows",
-                  "lora_all_steps", "mxu_tile_pad_fraction"),
+                  "lora_all_steps", "mxu_tile_pad_fraction",
+                  "windowed_conv_share",
+                  "shared_weight_phase"),
         doc="static half of the roofline cost model (obs/roofline.py)",
     ),
     "phase_cost": RecordSpec(

@@ -107,6 +107,26 @@ def _select_tree(pred, new, old):
     return jax.tree.map(lambda n, o: jnp.where(pred, n, o), new, old)
 
 
+def windowed_conv_share(params) -> float:
+    """Share of parameter elements in 2-D convolution kernels with a
+    window: rank 4 ``[kh, kw, I, O]``, ``kh·kw > 1``, not depthwise
+    (``I > 1``). A pointwise 1x1 kernel is a GEMM over batch·H·W and does
+    not count. 0.983 for ResNet-18; 0.04 for LeNet-5; 0.0 for
+    MobileNetV2 (pointwise and depthwise), bert_tiny and the LSTM."""
+    windowed = sum(
+        int(p.size) for p in jax.tree.leaves(params)
+        if jnp.ndim(p) == 4 and p.shape[2] > 1 and p.shape[0] * p.shape[1] > 1
+    )
+    return windowed / max(1, trees.tree_size(params))
+
+
+def shared_weight_phase(params) -> bool:
+    """Whether the megabatch block trainer runs its first local step on
+    the shared weights (``make_local_train_fn``): not for a model of
+    windowed convolutions."""
+    return windowed_conv_share(params) <= 0.5
+
+
 class _DecomposedLoRA:
     """Megabatch view of a LoRA model: ``apply`` delegates to
     ``apply_decomposed`` (models/lora.py) so the frozen base is never
@@ -149,6 +169,17 @@ def make_local_train_fn(model, client_cfg: ClientConfig, dp_cfg: DPConfig, task:
     to GEMM-shape reassociation (test-pinned). ``grad_corr`` (the
     stateful algorithms' per-client correction) is not supported in the
     block signature — config.validate() rejects the pairing.
+
+    A model of windowed convolutions (:func:`shared_weight_phase` false:
+    ``windowed_conv_share > 0.5``) runs no shared-weight phase: its
+    block is ``jax.vmap(local_train)``, every step diverged. A
+    convolution's GEMM rows are batch·H·W already, so one shared weight
+    buys it no rows, and on the v5e its shared step is the slow one (3x3
+    weight-gradient convolutions over the megabatch's activation
+    layout; PERF.md, PR 24), while models of GEMMs (pointwise
+    convolutions, Dense, attention) lose rounds/s without it. No model
+    of the zoo lies between 0.04 and 0.98, and nothing in that range has
+    been measured.
 
     ``batch_axis``: when the mesh carries a second axis that data-parallels
     each client's minibatch (mesh.py ``BATCH_AXIS``), every shard holds
@@ -379,6 +410,12 @@ def make_local_train_fn(model, client_cfg: ClientConfig, dp_cfg: DPConfig, task:
                 "megabatch block training does not support grad_corr "
                 "(stateful algorithms are spatial-layout only)"
             )
+        if not shared_weight_phase(global_params):
+            # no shared-weight phase (factory docstring): the spatial
+            # layout over the whole block
+            return jax.vmap(
+                local_train, in_axes=(None, None, None, 0, 0, 0, None)
+            )(global_params, train_x, train_y, idx, mask, keys, lr_scale)
         global_params = _cast_params(global_params)
         step = _make_step(global_params, train_x, train_y, lr_scale, None)
         steps = idx.shape[1]

@@ -1023,7 +1023,12 @@ class RunConfig:
     #               — and the remaining steps run as a lane-local vmap
     #               over the diverged per-client params (one batched
     #               GEMM per layer instead of K_local sequential
-    #               launches). A pure performance knob: the wire shapes
+    #               launches). A model of windowed convolutions (3x3
+    #               and larger kernels over half its elements) skips
+    #               the shared first step — its GEMM rows are
+    #               batch·H·W already — and runs every step diverged
+    #               (client/trainer.py shared_weight_phase). A pure
+    #               performance knob: the wire shapes
     #               ([K] weights, [K,2] mask specs, the [K,·] upload
     #               stack, psum/robust-reduce aggregation, ledger stats)
     #               are unchanged and megabatch ≡ spatial is parity-

@@ -773,7 +773,8 @@ def make_sharded_round_fn(model, client_cfg, dp_cfg, task, mesh, server_update,
     ``[K_local·batch, ...]`` megabatch against one un-batched weight,
     and the remaining (diverged-weights) steps scanned as a lane-local
     vmap — one batched GEMM per layer instead of K_local sequential
-    launches (client/trainer.py ``megabatch``). Purely a performance
+    launches (client/trainer.py ``megabatch``; a model of windowed
+    convolutions runs its first step that way too). Purely a performance
     layout: every wire shape — the ``[K]`` weights/participation, the
     ``[K, 2]`` on-device mask spec, the ``[K, ·]`` upload stack, the
     psum/robust-reduce aggregation contract, ledger stats — is

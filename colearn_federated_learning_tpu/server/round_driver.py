@@ -21,6 +21,8 @@ import numpy as np
 from colearn_federated_learning_tpu.client.trainer import (
     make_eval_fn,
     make_local_train_fn,
+    shared_weight_phase,
+    windowed_conv_share,
 )
 from colearn_federated_learning_tpu.config import DPConfig, ExperimentConfig
 from colearn_federated_learning_tpu.data import build_federated_data
@@ -4279,6 +4281,16 @@ class Experiment:
                 "lora_all_steps": lora_all_steps,
                 "mxu_tile_pad_fraction": round(
                     mxu_tile_pad_fraction(rows), 4
+                ),
+                # whether the megabatch block trainer runs its
+                # shared-weight first step, and the share its rule reads
+                # (client/trainer.py); false off that layout
+                "windowed_conv_share": round(
+                    windowed_conv_share(state["params"]), 4
+                ),
+                "shared_weight_phase": bool(
+                    cfg.run.cohort_layout == "megabatch"
+                    and shared_weight_phase(state["params"])
                 ),
             })
         if start_round == 0 and self._poisson:

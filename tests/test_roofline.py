@@ -366,6 +366,11 @@ def test_megabatch_smoke_roofline_padding_drop(tmp_path):
     # THE smoke assertion: megabatch reclaims MXU row-tile padding
     assert (m_mb["mxu_tile_pad_fraction"]
             < m_sp["mxu_tile_pad_fraction"])
+    # lenet5 (windowed kernels 4 % of it) keeps the block trainer's
+    # shared-weight phase; the spatial layout has none
+    assert m_sp["windowed_conv_share"] == m_mb["windowed_conv_share"] < 0.5
+    assert (m_sp["shared_weight_phase"], m_mb["shared_weight_phase"]) == (
+        False, True)
     # batch 16 spatial → 1 - 16/128; megabatch 64 rows → 1 - 64/128
     assert m_sp["mxu_tile_pad_fraction"] == pytest.approx(0.875)
     assert m_mb["mxu_tile_pad_fraction"] == pytest.approx(0.5)

@@ -2,6 +2,7 @@
 shard_map/psum round engine over a clients=8 CPU mesh must match the
 sequential reference loop."""
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -25,8 +26,20 @@ class _Fed:
         self.client_indices = client_indices
 
 
-def _setup(cohort=8, n=256):
-    model = build_model("lenet5", num_classes=10)
+class _ConvNet(nn.Module):
+    """Its second 3x3 kernel is 81 % of it: the megabatch block trainer
+    runs it without a shared-weight phase (client/trainer.py), where
+    LeNet-5 (4 %) keeps one."""
+
+    @nn.compact
+    def __call__(self, x, train: bool = False):
+        x = nn.relu(nn.Conv(8, (3, 3), strides=(2, 2))(x))
+        x = nn.relu(nn.Conv(16, (3, 3), strides=(2, 2))(x))
+        return nn.Dense(10)(x.mean(axis=(1, 2)))
+
+
+def _setup(cohort=8, n=256, conv_model=False):
+    model = _ConvNet() if conv_model else build_model("lenet5", num_classes=10)
     params = init_params(model, (28, 28, 1), seed=0)
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.uniform(0, 1, (n, 28, 28, 1)).astype(np.float32))
@@ -580,11 +593,13 @@ class TestCohortLayout:
     (fused↔unfused via the driver, resume crossings) stay bitwise —
     those run the same per-round programs."""
 
-    def _pair(self, cohort=8, lanes=2, fuse=1, **kw):
+    def _pair(self, cohort=8, lanes=2, fuse=1, conv_model=False,
+              client=None, **kw):
         """(spatial_fn, megabatch_fn) engine twins plus shared inputs."""
-        model, params, x, y, idx, mask, n_ex = _setup(cohort=cohort)
-        ccfg = ClientConfig(local_epochs=2, batch_size=8, lr=0.1,
-                            momentum=0.9)
+        model, params, x, y, idx, mask, n_ex = _setup(
+            cohort=cohort, conv_model=conv_model)
+        ccfg = ClientConfig(**{"local_epochs": 2, "batch_size": 8,
+                               "lr": 0.1, "momentum": 0.9, **(client or {})})
         scfg = ServerConfig(optimizer="mean", server_lr=1.0,
                             cohort_size=cohort)
         init, server_update = make_server_update_fn(scfg)
@@ -737,6 +752,49 @@ class TestCohortLayout:
         np.testing.assert_allclose(
             float(m_mb.train_loss), float(m_sq.train_loss), rtol=1e-5
         )
+
+    @pytest.mark.parametrize("fuse,client", [
+        (1, {}), (2, {}), (1, {"optimizer": "adamw", "lr": 1e-3}),
+    ], ids=["sgd-fuse1", "sgd-fuse2", "adamw-fuse1"])
+    def test_conv_dominated_block_matches_spatial_and_sequential(
+            self, fuse, client):
+        """A model of windowed convolutions trains its block without the
+        shared-weight phase: inside the lanes' shard_map (the loop's
+        carry, adam's fresh step count included, must be device-varying
+        going in) and the fused scan it lands on the spatial twin, and
+        at fuse 1 on the sequential engine's megabatch block."""
+        from colearn_federated_learning_tpu.client.trainer import (
+            shared_weight_phase,
+        )
+
+        model, params, opt_state, args, fns = self._pair(
+            fuse=fuse, conv_model=True, client=client)
+        assert not shared_weight_phase(params)
+        x, y, idx, mask, n_ex = args
+        if fuse == 1:
+            ins = (x, y, idx, mask, n_ex, jax.random.PRNGKey(9))
+        else:
+            ins = (x, y, jnp.stack([idx, idx]), jnp.stack([mask, mask]),
+                   jnp.stack([n_ex, n_ex]),
+                   jnp.stack([jax.random.PRNGKey(9), jax.random.PRNGKey(10)]))
+        p_sp, _, m_sp = fns["spatial"](params, opt_state, *ins)
+        p_mb, _, m_mb = fns["megabatch"](params, opt_state, *ins)
+        self._assert_layout_parity(p_sp, p_mb)
+        np.testing.assert_allclose(
+            np.asarray(m_sp.train_loss), np.asarray(m_mb.train_loss),
+            rtol=1e-5,
+        )
+        if fuse == 1:
+            ccfg = ClientConfig(**{"local_epochs": 2, "batch_size": 8,
+                                   "lr": 0.1, "momentum": 0.9, **client})
+            scfg = ServerConfig(optimizer="mean", server_lr=1.0,
+                                cohort_size=8)
+            seq = make_sequential_round_fn(
+                model, ccfg, DPConfig(), "classify",
+                make_server_update_fn(scfg)[1], cohort_layout="megabatch",
+            )
+            p_sq, _, _ = seq(params, opt_state, *ins)
+            self._assert_layout_parity(p_sq, p_mb)
 
     def test_unaligned_resume_crossing(self, tmp_path):
         """A megabatch run resumed at a NON-chunk-aligned round (the
