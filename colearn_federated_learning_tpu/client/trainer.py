@@ -25,7 +25,8 @@ Algorithm hooks:
 
 from __future__ import annotations
 
-from typing import NamedTuple
+import contextlib
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +40,10 @@ from colearn_federated_learning_tpu.utils import trees
 class LocalMetrics(NamedTuple):
     loss: jnp.ndarray  # mask-weighted mean train loss over the round
     examples: jnp.ndarray  # real examples processed
+    # {name: mask-weighted mean over the round} of the counters a model
+    # with an auxiliary output reports (``model.aux_counters``); empty
+    # for every other model
+    aux: Any = ()
 
 
 def make_client_optimizer(cfg: ClientConfig) -> optax.GradientTransformation:
@@ -72,7 +77,8 @@ def normalize_input(x, dtype=jnp.float32):
     return x
 
 
-def make_loss_fn(model, task: str, reduction: str = "mean"):
+def make_loss_fn(model, task: str, reduction: str = "mean",
+                 with_counters: bool = False):
     """Masked loss. classify: y [B] ints; lm: y [B,T] next tokens.
 
     ``reduction="sum"`` returns the plain mask-weighted sum — what the
@@ -84,6 +90,16 @@ def make_loss_fn(model, task: str, reduction: str = "mean"):
     input scaling, every matmul/conv, activations, and the backward —
     runs bf16 end-to-end; the loss itself stays f32 (the cross-entropy
     head's logits are f32 by model design).
+
+    A model may return ``(logits, aux)`` instead of logits
+    (``models/keye.py``): ``aux["loss"]`` ``[B]`` is an auxiliary loss
+    per example, added to the example's cross-entropy before the mask
+    (coefficient 1; what it moves is the model's business, through
+    ``stop_gradient``), and ``aux["counters"]`` holds ``[B]`` counters
+    named by ``model.aux_counters``. ``with_counters`` makes the loss
+    function return ``(loss, {name: mask-weighted mean})`` for
+    ``jax.value_and_grad(..., has_aux=True)``. For a model that returns
+    logits alone nothing here differs from before.
     """
     in_dtype = getattr(model, "compute_dtype", jnp.float32)
 
@@ -91,14 +107,27 @@ def make_loss_fn(model, task: str, reduction: str = "mean"):
         logits = model.apply(
             {"params": params}, normalize_input(x, in_dtype), train=True
         )
+        aux = None
+        if isinstance(logits, tuple):
+            logits, aux = logits
         if task == "classify":
             ce = optax.softmax_cross_entropy_with_integer_labels(logits, y)
         else:  # lm: mean over tokens within each example
-            ce = optax.softmax_cross_entropy_with_integer_labels(logits, y).mean(-1)
+            # (a name for the device trace where the model has its own)
+            with (jax.named_scope("lm_head") if aux is not None
+                  else contextlib.nullcontext()):
+                ce = optax.softmax_cross_entropy_with_integer_labels(logits, y).mean(-1)
+        if aux is not None:
+            ce = ce + aux["loss"]
         weighted = (ce * m).sum()
-        if reduction == "sum":
-            return weighted
-        return weighted / jnp.maximum(m.sum(), 1.0)
+        loss = weighted if reduction == "sum" else (
+            weighted / jnp.maximum(m.sum(), 1.0))
+        if not with_counters:
+            return loss
+        return loss, {
+            k: (v * m).sum() / jnp.maximum(m.sum(), 1.0)
+            for k, v in aux["counters"].items()
+        }
 
     return loss_fn
 
@@ -221,7 +250,19 @@ def make_local_train_fn(model, client_cfg: ClientConfig, dp_cfg: DPConfig, task:
         # megabatch parity vs spatial is pinned at the documented
         # GEMM-reassociation tolerance.
         model = _DecomposedLoRA(model)
-    grad_fn = jax.value_and_grad(make_loss_fn(model, task))
+    # counters of a model with an auxiliary output (models/keye.py);
+    # () for every other model, whose step is the one it always was
+    aux_names = tuple(getattr(model, "aux_counters", ()))
+    if aux_names and (megabatch or batch_axis is not None or dp_cfg.enabled):
+        # config.validate() says so first, by name
+        raise ValueError(
+            "a model with an auxiliary loss trains in the spatial layout, "
+            "without DP-SGD and without a batch mesh axis"
+        )
+    grad_fn = jax.value_and_grad(
+        make_loss_fn(model, task, with_counters=bool(aux_names)),
+        has_aux=bool(aux_names),
+    )
     sum_grad_fn = jax.value_and_grad(make_loss_fn(model, task, reduction="sum"))
     mu = client_cfg.prox_mu
     if dp_cfg.enabled:
@@ -277,6 +318,8 @@ def make_local_train_fn(model, client_cfg: ClientConfig, dp_cfg: DPConfig, task:
             with jax.named_scope("local_grad"):
                 if dp_cfg.enabled:
                     loss, grads = dp_grad_fn(params, x, y, step_mask, key)
+                elif aux_names:
+                    (loss, counters), grads = grad_fn(params, x, y, step_mask)
                 elif batch_axis is None:
                     loss, grads = grad_fn(params, x, y, step_mask)
                 else:
@@ -337,6 +380,11 @@ def make_local_train_fn(model, client_cfg: ClientConfig, dp_cfg: DPConfig, task:
                     valid = step_n > 0
                     params = _select_tree(valid, new_params, params)
                     opt_state = _select_tree(valid, new_opt_state, opt_state)
+            if aux_names:
+                return (params, opt_state), (
+                    loss * step_n,
+                    {k: counters[k] * step_n for k in aux_names},
+                )
             return (params, opt_state), loss * step_n
 
         return step
@@ -386,9 +434,16 @@ def make_local_train_fn(model, client_cfg: ClientConfig, dp_cfg: DPConfig, task:
             unroll=scan_unroll,
         )
         n = _global_count(mask)
+        if aux_names:
+            weighted_losses, weighted_aux = weighted_losses
+            aux = {k: v.sum() / jnp.maximum(n, 1.0)
+                   for k, v in weighted_aux.items()}
         mean_loss = weighted_losses.sum() / jnp.maximum(n, 1.0)
+        if aux_names:
+            return params, LocalMetrics(loss=mean_loss, examples=n, aux=aux)
         return params, LocalMetrics(loss=mean_loss, examples=n)
 
+    local_train.aux_names = aux_names
     if not megabatch:
         return local_train
 
@@ -463,6 +518,8 @@ def make_eval_fn(model, task: str):
 
     def eval_batch(params, x, y, m):
         logits = model.apply({"params": params}, normalize_input(x), train=False)
+        if isinstance(logits, tuple):  # (logits, aux): see make_loss_fn
+            logits = logits[0]
         if task == "classify":
             ce = optax.softmax_cross_entropy_with_integer_labels(logits, y)
             correct = (jnp.argmax(logits, -1) == y).astype(jnp.float32)

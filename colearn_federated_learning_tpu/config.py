@@ -2102,6 +2102,27 @@ class ExperimentConfig:
             raise ValueError(
                 f"unknown dp.clipping {self.dp.clipping!r}"
             )
+        from colearn_federated_learning_tpu.models import returns_aux_loss
+
+        if returns_aux_loss(self.model.name):
+            # the model returns (logits, aux) and its auxiliary loss
+            # reaches the gradient through the plain per-client step only
+            # (client/trainer.make_loss_fn); name the pairing here
+            # instead of failing inside flax
+            unsupported = [
+                what for what, on in (
+                    ("model.lora.enabled", self.model.lora.enabled),
+                    ("run.cohort_layout='megabatch'",
+                     self.run.cohort_layout == "megabatch"),
+                    ("dp.enabled", self.dp.enabled),
+                    ("run.batch_shards > 1", self.run.batch_shards > 1),
+                ) if on
+            ]
+            if unsupported:
+                raise ValueError(
+                    f"model {self.model.name!r} (it returns an auxiliary "
+                    f"loss) does not support: {', '.join(unsupported)}"
+                )
         lora = self.model.lora
         if lora.enabled:
             from colearn_federated_learning_tpu.models.lora import (
@@ -3008,6 +3029,36 @@ def _vit_lora_dp() -> ExperimentConfig:
     )
 
 
+def _keye_silo_lm() -> ExperimentConfig:
+    """Cross-silo FedAvg on the language decoder of Keye-VL-2.0-30B-A3B
+    as one chip of an 8-way expert-parallel deployment holds it
+    (models/keye.py: 16 of 128 experts, an eighth of the vocabulary, 4 of
+    48 layers; every width as published): 8 silos continue training on
+    long private documents, 2 local AdamW steps of one 8,192-token
+    sequence per round. Spatial layout, no DP, no LoRA (validate() names
+    what this model does not support)."""
+    return ExperimentConfig(
+        name="keye_silo_lm",
+        algorithm="fedavg",
+        model=ModelConfig(
+            name="keye_decoder",
+            num_classes=0,
+            kwargs={"vocab_size": 18992, "seq_len": 8192, "layers": 4,
+                    "experts_held": 16},
+        ),
+        data=DataConfig(
+            name="synthetic_text",
+            num_clients=8,
+            partition="silo",
+            max_examples_per_client=2,
+        ),
+        client=ClientConfig(local_epochs=1, batch_size=1, lr=1e-4,
+                            optimizer="adamw", weight_decay=0.01),
+        server=ServerConfig(num_rounds=100, cohort_size=8, eval_every=0),
+        run=RunConfig(compute_dtype="bfloat16", local_param_dtype="bfloat16"),
+    )
+
+
 _NAMED = {
     "mnist_fedavg_2": _mnist_fedavg_2,
     "cifar10_fedavg_100": _cifar10_fedavg_100,
@@ -3019,6 +3070,7 @@ _NAMED = {
     "cifar10_krum_byzantine": _cifar10_krum_byzantine,
     "bert_lora_federated": _bert_lora_federated,
     "vit_lora_dp": _vit_lora_dp,
+    "keye_silo_lm": _keye_silo_lm,
 }
 
 

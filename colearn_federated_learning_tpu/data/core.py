@@ -338,6 +338,22 @@ def _load_shakespeare(cfg: DataConfig, vocab_size: int = 90, seq_len: int = 80, 
     return tx, ty, ex, ey, {"source": "synthetic", "input_shape": (seq_len,)}, vocab_size, "lm"
 
 
+@dataset_registry.register("synthetic_text")
+def _load_synthetic_text(cfg: DataConfig, vocab_size: int = 90,
+                         seq_len: int = 80, **kwargs):
+    """Documents of ``seq_len`` tokens over ``vocab_size`` ids from the
+    sparse Markov chain of :func:`_synthetic_text`, one document per
+    sequence (no packing): the corpus of the long-document language
+    configs (``keye_silo_lm``), which have no real files to find."""
+    rng = np.random.default_rng(_stable_seed("synthetic_text"))
+    successors = rng.integers(0, vocab_size, size=(vocab_size, 4))
+    tx, ty = _synthetic_text(rng, _scaled_train_size(cfg), seq_len, vocab_size,
+                             successors)
+    ex, ey = _synthetic_text(rng, cfg.synthetic_test_size, seq_len, vocab_size,
+                             successors)
+    return tx, ty, ex, ey, {"source": "synthetic", "input_shape": (seq_len,)}, vocab_size, "lm"
+
+
 # ---------------------------------------------------------------------------
 # entry point
 # ---------------------------------------------------------------------------

@@ -6,6 +6,15 @@ modules with pure-pytree params so FedAvg's weighted-sum is plain tree
 arithmetic, and all use static shapes + GroupNorm-style normalization
 (no batch statistics crossing client boundaries — BatchNorm is both bad
 FL practice and a running-stats headache for functional aggregation).
+
+What ``model.apply({"params": p}, x, train=...)`` may return: the logits
+(classify ``[B, C]``, lm ``[B, T, V]``, float32), or ``(logits, aux)``
+where ``aux["loss"]`` is a ``[B]`` auxiliary loss the trainer adds to
+each example's cross-entropy and ``aux["counters"]`` a dict of ``[B]``
+counters named by the module's ``aux_counters`` (``keye.py``: the
+indexer's loss; ``client/trainer.make_loss_fn`` says how they reach the
+round's metrics). Its registered factory carries ``aux_counters`` too
+(:func:`returns_aux_loss`), which is how ``config.validate()`` knows.
 """
 
 from __future__ import annotations
@@ -61,6 +70,13 @@ def build_model(name: str, num_classes: int, **kwargs):
     return factory(num_classes=num_classes, **kwargs)
 
 
+def returns_aux_loss(name: str) -> bool:
+    """Whether model ``name`` returns ``(logits, aux)``: its registered
+    factory is marked with the ``aux_counters`` its module reports."""
+    return name in model_registry.names() and bool(
+        getattr(model_registry.get(name), "aux_counters", ()))
+
+
 def model_input_spec(name: str, **kwargs) -> Tuple[Tuple[int, ...], Any]:
     """(example input shape without batch dim, dtype) for a model family."""
     try:
@@ -92,3 +108,4 @@ from colearn_federated_learning_tpu.models import mobilenet  # noqa: E402,F401
 from colearn_federated_learning_tpu.models import bert  # noqa: E402,F401
 from colearn_federated_learning_tpu.models import vit  # noqa: E402,F401
 from colearn_federated_learning_tpu.models import lstm  # noqa: E402,F401
+from colearn_federated_learning_tpu.models import keye  # noqa: E402,F401

@@ -145,6 +145,33 @@ def instrument(name: str, fn: Callable, *,
 # fingerprinting
 
 
+def _on_roomy_stack(fn, *args, **kwargs):
+    """``fn(*args, **kwargs)`` with room on the interpreter's frame
+    stack: see the assignment below."""
+    return fn(*args, **kwargs)
+
+
+# CPython (3.11 to 3.13) keeps Python frames on a per-thread stack of
+# 16 KiB chunks and unmaps a chunk the moment the frame that opened it
+# returns. Tracing the ViT round makes 14.7 M calls at a depth of 60 to
+# 100 frames, which is about 16 KiB: where the busiest calls straddle a
+# chunk's end, every one of them maps a chunk, takes a page fault in it
+# and unmaps it again, 50 times the cost of a call (7 us against 0.15).
+# Which calls straddle it is decided by the BYTES of every frame
+# beneath, so a new local in the trainer's step, or one more frame
+# under run_round, moved the ViT cells' warm set-up by 17-24 % with an
+# identical program (PERF.md, PR 23 and PR 25; on the CPU the same
+# lowering takes 6.0 to 12.2 s and 12 k to 378 k page faults as frames
+# are put beneath it, period 16 KiB). A frame too large for a chunk is
+# given a chunk of its own that is twice its size: this one asks for
+# 1 MiB, so every frame the trace pushes after it has a second MiB of
+# room and crosses nothing (on the chip's host the ViT round traces in
+# 6.2 s instead of 39.5). The frame's slots are never touched, so the
+# memory is never resident.
+_on_roomy_stack.__code__ = _on_roomy_stack.__code__.replace(
+    co_stacksize=(1 << 20) // 8)
+
+
 def _leaf_desc(x) -> tuple:
     """Hashable per-leaf descriptor with exactly jit's cache-key
     granularity: aval (shape/dtype/weak_type) + sharding for arrays,
@@ -352,7 +379,7 @@ class ExecutableRegistry:
             fingerprint = _fingerprint_hex(name, key)
             t0 = time.perf_counter()
             try:
-                lowered = fn.lower(*args, **kwargs)
+                lowered = _on_roomy_stack(fn.lower, *args, **kwargs)
                 compiled = lowered.compile()
             except Exception as e:
                 compile_ms = (time.perf_counter() - t0) * 1e3

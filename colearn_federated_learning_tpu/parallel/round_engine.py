@@ -21,7 +21,7 @@ tensors and one ``device_get`` of scalar metrics.
 from __future__ import annotations
 
 from functools import partial
-from typing import NamedTuple
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -67,6 +67,10 @@ def _on_every_device(server_update, mesh):
 class RoundMetrics(NamedTuple):
     train_loss: jnp.ndarray  # cohort example-weighted mean local loss
     examples: jnp.ndarray  # total real examples processed
+    # {name: cohort mean, weighted like train_loss} of a model's own
+    # counters (client/trainer.LocalMetrics.aux); empty for a model
+    # that has none. The plain synchronous path fills it.
+    aux: Any = ()
 
 
 def apply_store_shard_ownership(fed, replica_fallback: bool = True):
@@ -1196,7 +1200,7 @@ def make_sharded_round_fn(model, client_cfg, dp_cfg, task, mesh, server_update,
                 # weight — numerator AND denominator (a true reweighted
                 # mean), and the loss metric weights identically
                 b_w = b_w * b_tr.astype(b_w.dtype)
-            d_acc, w_acc, n_acc, l_acc, dc_acc = acc
+            d_acc, w_acc, n_acc, l_acc, dc_acc, a_acc = acc
             ys = {}
             # per-client deltas in f32 (bf16 local weights upcast here, so
             # client-side mixed precision never degrades the aggregation);
@@ -1303,8 +1307,12 @@ def make_sharded_round_fn(model, client_cfg, dp_cfg, task, mesh, server_update,
                     lambda a, nc, ci: a + (nc - ci).sum(0), dc_acc, new_c_block, b_c
                 )
                 ys["c"] = new_c_block
+            # a model's own counters, weighted like the loss ({} for a
+            # model without any: no leaf, the program is unchanged)
+            a_acc = {k: a + (b_w * m_b.aux[k]).sum()
+                     for k, a in a_acc.items()}
             return (d_acc, w_acc + b_w.sum(), n_acc + b_n.sum(),
-                    l_acc + (b_w * m_b.loss).sum(), dc_acc), ys
+                    l_acc + (b_w * m_b.loss).sum(), dc_acc, a_acc), ys
 
         n_blocks = idx.shape[0] // width
         scan_in = (idx, mask, n_ex, keys)
@@ -1332,10 +1340,11 @@ def make_sharded_round_fn(model, client_cfg, dp_cfg, task, mesh, server_update,
             d0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.int32), params)
         else:
             d0 = trees.tree_zeros_like(params)
+        a0 = {k: jnp.zeros(()) for k in getattr(local_train, "aux_names", ())}
         acc0 = _pcast_varying(
-            (d0, jnp.zeros(()), jnp.zeros(()), jnp.zeros(()), dc0),
+            (d0, jnp.zeros(()), jnp.zeros(()), jnp.zeros(()), dc0, a0),
         )
-        (d_sum, w_sum, n_sum, l_sum, dc_sum), ys = jax.lax.scan(
+        (d_sum, w_sum, n_sum, l_sum, dc_sum, a_sum), ys = jax.lax.scan(
             per_block, acc0, blocked
         )
         # The aggregation collective — the reference's NCCL allreduce
@@ -1351,6 +1360,9 @@ def make_sharded_round_fn(model, client_cfg, dp_cfg, task, mesh, server_update,
             lambda a: a.reshape((idx.shape[0],) + a.shape[2:]), t
         )
         out = {"n": n_sum, "loss": l_sum / denom}
+        if a_sum:
+            out["aux"] = {k: jax.lax.psum(a, CLIENT_AXIS) / denom
+                          for k, a in a_sum.items()}
         # Under client-level DP the mean's denominator is the FIXED
         # public cohort size, never the realized weight sum — a
         # data-dependent denominator is itself private and would break
@@ -1442,6 +1454,8 @@ def make_sharded_round_fn(model, client_cfg, dp_cfg, task, mesh, server_update,
     if client_dp_noise > 0.0:
         in_specs += (P(),)  # central DP noise key, replicated
     out_specs = {"n": P(), "loss": P()}
+    if getattr(local_train, "aux_names", ()):
+        out_specs["aux"] = P()
     if emit_stack or client_ledger:
         out_specs["deltas"] = P(CLIENT_AXIS)
     if client_ledger:
@@ -1790,7 +1804,7 @@ def make_sharded_round_fn(model, client_cfg, dp_cfg, task, mesh, server_update,
         if client_ledger:
             new_ledger = _ledger_update(out, wire, delta, n_ex, ledger,
                                         cohort)
-        metrics = RoundMetrics(out["loss"], out["n"])
+        metrics = RoundMetrics(out["loss"], out["n"], out.get("aux", ()))
         if client_ledger:
             return new_params, new_opt_state, new_ledger, metrics
         return new_params, new_opt_state, metrics

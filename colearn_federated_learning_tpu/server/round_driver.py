@@ -4494,6 +4494,10 @@ class Experiment:
                     "train_loss": float(m.train_loss),
                     "examples": float(m.examples),
                 }
+                # a model's own counters (RoundMetrics.aux; none but
+                # for models/keye.py)
+                record.update({k: float(v) for k, v
+                               in dict(getattr(m, "aux", ())).items()})
                 comm = self._comm_stats.pop(ridx, None)
                 fail = self._fail_stats.pop(ridx, None)
                 if comm:

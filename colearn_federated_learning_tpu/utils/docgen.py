@@ -298,6 +298,7 @@ def config_reference_markdown() -> str:
         lines.append("")
         if section == "attack":
             lines += [_THREAT_MODEL]
+    lines += _model_kwargs_section("keye_decoder", _KEYE_KWARGS_BLURB)
     names = config_mod.list_named_configs()
     named = ", ".join(f"`{n}`" for n in names)
     lines += [
@@ -312,6 +313,39 @@ def config_reference_markdown() -> str:
     if appendix:
         lines += [appendix]
     return "\n".join(lines)
+
+
+_KEYE_KWARGS_BLURB = (
+    "The language decoder of Keye-VL-2.0-30B-A3B as one chip of an "
+    "expert-parallel deployment holds it (models/keye.py; named config "
+    "`keye_silo_lm`). The defaults are the published widths; `layers`, "
+    "`experts_held` (with `expert_offset`, the first global id held) and "
+    "`vocab_size` are the chip's share. `index_topk` keys are selected "
+    "per query by the indexer, which learns from a loss of its own; "
+    "`q_chunk` (queries per attention chunk) and `moe_tile` (rows per "
+    "expert tile) are tilings that change no value. Where "
+    "`experts_held` is less than `num_experts` the gates are constants "
+    "of the backward pass (the router is not trained: ops/moe.route). "
+    "The model does not support "
+    "`model.lora.enabled`, `run.cohort_layout=megabatch`, `dp.enabled` "
+    "or `run.batch_shards > 1` (validate() names them)."
+)
+
+
+def _model_kwargs_section(name: str, blurb: str):
+    """`model.kwargs` of one zoo family, from its factory's signature."""
+    import inspect
+
+    from colearn_federated_learning_tpu.models import model_registry
+
+    lines = [f"## `model.kwargs` of `{name}`", "", blurb, "",
+             "| kwarg | default |", "|---|---|"]
+    for p in inspect.signature(model_registry.get(name)).parameters.values():
+        if p.kind is p.VAR_KEYWORD or p.name in (
+                "num_classes", "compute_dtype", "param_dtype"):
+            continue
+        lines.append(f"| `{p.name}` | {_fmt(p.default)} |")
+    return lines + [""]
 
 
 def capability_matrix_appendix() -> str:

@@ -17,6 +17,17 @@ def tree_zeros_like(tree):
     return jax.tree.map(jnp.zeros_like, tree)
 
 
+def zeros_varying_like(ref, shape=None, dtype=None):
+    """Zeros of ``shape`` / ``dtype`` (default: ``ref``'s) that vary over
+    the same ``shard_map`` axes as ``ref``: the initial carry of a loop
+    whose body mixes it with ``ref`` must have the body's type. Outside
+    ``shard_map`` these are plain zeros."""
+    z = jnp.zeros(ref.shape if shape is None else shape,
+                  ref.dtype if dtype is None else dtype)
+    vma = tuple(getattr(jax.typeof(ref), "vma", ()))
+    return jax.lax.pcast(z, vma, to="varying") if vma else z
+
+
 def tree_add(a, b):
     return jax.tree.map(jnp.add, a, b)
 

@@ -15,6 +15,19 @@ from colearn_federated_learning_tpu.server.round_driver import Experiment
 # partition kind, dp.enabled, model family, task) is untouched.
 _SHRINK = {
     "mnist_fedavg_2": {},
+    # the Keye decoder at a toy size: every mechanism (selection at
+    # T > topk, 2 of 8 experts held, the indexer's loss) stays on
+    "keye_silo_lm": {
+        "model.kwargs.vocab_size": 8, "model.kwargs.seq_len": 32,
+        "model.kwargs.layers": 1, "model.kwargs.hidden": 32,
+        "model.kwargs.heads": 4, "model.kwargs.kv_heads": 2,
+        "model.kwargs.head_dim": 8, "model.kwargs.num_experts": 8,
+        "model.kwargs.experts_held": 2, "model.kwargs.experts_per_token": 2,
+        "model.kwargs.expert_width": 16, "model.kwargs.index_heads": 2,
+        "model.kwargs.index_head_dim": 8, "model.kwargs.index_topk": 8,
+        "model.kwargs.mrope_section": [1, 1, 2], "model.kwargs.q_chunk": 16,
+        "model.kwargs.moe_tile": 4, "run.local_param_dtype": "",
+    },
     "cifar10_fedavg_100": {"data.num_clients": 16, "model.kwargs.width": 16},
     # the north-star config keeps its FULL 1000-client federation — the
     # point is sampling/partitioning/index-tensor behavior at that scale;

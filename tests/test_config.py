@@ -24,6 +24,7 @@ def test_named_configs_exist():
         "cifar10_krum_byzantine",
         "bert_lora_federated",
         "vit_lora_dp",
+        "keye_silo_lm",  # PR 25: the sparse-expert language decoder
     ])
     for name in list_named_configs():
         cfg = get_named_config(name)

@@ -402,3 +402,32 @@ def test_checked_in_history_passes_repo_budgets(capsys):
                      "tests/fixtures/bench_history",
                      "--baseline", "BENCH_BUDGETS.json"]) == 0
     assert "gates: PASS" in capsys.readouterr().out
+
+
+def test_lowering_runs_on_a_frame_with_a_chunk_of_its_own():
+    """``_on_roomy_stack`` forwards the call and asks the interpreter
+    for a frame larger than its 16 KiB stack chunks, so that frames
+    pushed after it (a whole trace) cross no chunk's end; with it a
+    loop of calls at any depth takes next to no page faults."""
+    import resource
+
+    roomy = exec_mod._on_roomy_stack
+    assert roomy(lambda a, b=0: (a, b), 1, b=2) == (1, 2)
+    assert roomy.__code__.co_stacksize * 8 >= 1 << 20
+    with pytest.raises(ZeroDivisionError):
+        roomy(lambda: 1 / 0)
+
+    def leaf():
+        a = b = c = d = e = f = g = h = None  # noqa: F841 (a wider frame)
+
+    def at_depth(depth, n):
+        if depth:
+            return at_depth(depth - 1, n)
+        before = resource.getrusage(resource.RUSAGE_SELF).ru_minflt
+        for _ in range(n):
+            leaf()
+        return resource.getrusage(resource.RUSAGE_SELF).ru_minflt - before
+
+    # some depth in any 16 KiB of frames straddles a chunk's end without it
+    worst = max(roomy(at_depth, depth, 2000) for depth in range(0, 200, 2))
+    assert worst < 200
