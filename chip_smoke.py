@@ -323,6 +323,56 @@ def check_flash_attention() -> None:
                                ATTN_TOL_VS_HIGHEST)
 
 
+def check_selected_attention() -> None:
+    """``selected_attention``'s three kernels, natively, at the Keye
+    decoder's widths (a chunk of 512 queries over 2,048 keys, 32 / 4
+    heads of 128, bf16): output, the heads' mean and the three gradients
+    against a dense masked softmax in XLA on the same bf16 inputs."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from colearn_federated_learning_tpu.ops.sparse_attention import (
+        selected_attention,
+    )
+
+    tq, tk, heads, kv, hd = 512, 2048, 32, 4, 128
+    ks = jax.random.split(jax.random.PRNGKey(26), 5)
+    q = jax.random.normal(ks[0], (tq, heads, hd), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (tk, kv, hd), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (tk, kv, hd), jnp.bfloat16)
+    keep = (jax.random.uniform(ks[3], (tq, tk)) < 0.25).at[:, -1].set(True)
+    ct = jax.random.normal(ks[4], (tq, heads * hd), jnp.float32)
+
+    def dense(q, k, v, keep):
+        kr, vr = (jnp.repeat(a, heads // kv, axis=1) for a in (k, v))
+        s = jnp.einsum("qhd,khd->hqk", q, kr,
+                       preferred_element_type=jnp.float32) * hd ** -0.5
+        p = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1)
+        out = jnp.einsum("hqk,khd->qhd", p.astype(v.dtype), vr,
+                         preferred_element_type=jnp.float32)
+        return out.astype(q.dtype).reshape(tq, heads * hd), p.mean(0)
+
+    def both(fn):
+        def loss(q, k, v):
+            out, weights = fn(q, k, v, keep)
+            return (out.astype(jnp.float32) * ct).sum(), (out, weights)
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2), has_aux=True))
+
+    (_, (out, weights)), grads = both(selected_attention)(q, k, v)
+    (_, (want, want_w)), want_g = both(dense)(q, k, v)
+    if np.any(np.asarray(weights)[~np.asarray(keep)]):
+        raise RuntimeError("selected_attention: weight off the kept set")
+    _require_close("selected_attention out", out, want, ATTN_TOL["bfloat16"])
+    _require_close("selected_attention heads' mean", weights, want_w, 1e-4)
+    for name, g, w in zip(("dq", "dk", "dv"), grads, want_g):
+        scale = float(jnp.abs(w.astype(jnp.float32)).max())
+        _require_close(f"selected_attention {name} / {scale:.3g}",
+                       g.astype(jnp.float32) / scale,
+                       w.astype(jnp.float32) / scale,
+                       ATTN_TOL["bfloat16"])
+
+
 def main() -> int:
     t_start = time.time()
     # (a) the compile cache, before the first compile
@@ -375,6 +425,7 @@ def main() -> int:
                          (32, 32, 3), seed=0)
     check_pallas_apply(params, k=16)
     check_flash_attention()
+    check_selected_attention()
 
     say(f"total wall {time.time() - t_start:.1f}s")
     print(json.dumps({"ok": True, "device": {

@@ -35,8 +35,9 @@ masks, attention's outputs, log-sum-exps and head-mean weights are
 kept (``checkpoint_name`` ``attn_select``, ``attn_out``, ``attn_lse``,
 ``attn_weights``) and so is the expert layer's output (``moe_out``), so
 the bisection, attention's forward pass and the experts' run once per
-layer and step; attention's backward pass recomputes the
-scores of its own chunk (``ops/sparse_attention.selected_attention``).
+layer and step; attention's backward kernel recomputes the scores of
+its own chunk, a tile at a time in VMEM (``ops/sparse_attention.
+selected_attention``: no array of per-head scores reaches HBM).
 """
 
 from __future__ import annotations

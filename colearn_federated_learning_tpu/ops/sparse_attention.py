@@ -5,10 +5,11 @@ attention runs over that set only.
 
 Everything works on one chunk of queries at a time against the keys the
 chunk can see (static extents), so no ``[T, T]`` array per head is ever
-held: a chunk's index scores are ``[chunk, keys]`` float32, its attention
-scores ``[heads, chunk, keys]``. The four pieces are separate functions so
-that the model can put them under the scopes ``attn_indexer``,
-``attn_select`` and ``attn_sparse``:
+held: a chunk's index scores are ``[chunk, keys]`` float32, and its
+attention scores exist one ``[block_q, block_k]`` tile of one head at a
+time, in VMEM. The four pieces are separate functions so that the model
+can put them under the scopes ``attn_indexer``, ``attn_select`` and
+``attn_sparse``:
 
 - :func:`index_scores`: ``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])``
   times the two scale factors, accumulated in float32.
@@ -18,20 +19,30 @@ that the model can put them under the scopes ``attn_indexer``,
   float32 scores (32 counting passes, no sort), then the first ties.
 - :func:`selected_attention`: softmax attention over the kept pairs, with
   grouped key-value heads; also returns the attention weights averaged
-  over the heads, the indexer's training target.
+  over the heads, the indexer's training target. Three Pallas TPU
+  kernels (forward, heads' mean, backward); off the chip they run in
+  interpret mode.
 - :func:`index_kl`: ``KL(p || softmax over the kept pairs of I)`` summed
   over the chunk's queries.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from colearn_federated_learning_tpu.ops.pallas_apply import out_struct
 from colearn_federated_learning_tpu.utils.trees import zeros_varying_like
 
 _NEG_BIG = -1e30
+_LANES = 128
+_VMEM_LIMIT = 96 * 2 ** 20
+_NT = (((1,), (1,)), ((), ()))  # a [m, d] . b [n, d] -> [m, n]
 
 
 def index_scores(q_idx, k_idx, w_idx):
@@ -79,77 +90,308 @@ def select_topk(scores, causal, topk: int):
     return causal & (above | (tie & first_ties))
 
 
-def _scores(q, k, keep):
-    tq, h, hd = q.shape
-    g = k.shape[1]
-    qg = q.reshape(tq, g, h // g, hd)
-    s = jnp.einsum("qgrd,kgd->grqk", qg, k,
-                   preferred_element_type=jnp.float32) * (hd ** -0.5)
-    return qg, jnp.where(keep[None, None], s, _NEG_BIG)
+def _interpret():
+    """Off the chip the kernels run in Pallas interpret mode (exact,
+    slow), as ``ops/pallas_apply.py``'s do: the CPU tests run this code."""
+    return jax.default_backend() != "tpu"
 
 
-@jax.custom_vjp
-def selected_attention(q, k, v, keep):
+def _across(x, n: int):
+    """``[rows, _LANES]`` with every lane of a row alike -> ``[rows, n]``."""
+    if n % x.shape[1] == 0:
+        return jnp.tile(x, (1, n // x.shape[1]))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _heads(rep: int, hd: int, body, carry=None):
+    """``body(r, that head's hd columns of a [rows, rep * hd] block,
+    carry)`` for the ``rep`` query heads of a key-value group. A loop the
+    lowering unrolls: a Python loop has every head traced (the round
+    program's warm compile-or-load 16.3 s against 14.5, PERF.md PR 26:
+    16 chunks x 3 kernels are traced in every process), a rolled loop
+    keeps one head's products from overlapping the next one's vector work
+    (2.32 ms against 1.81 for the three kernels at 8,192 keys)."""
+    return jax.lax.fori_loop(
+        0, rep,
+        lambda r, c: body(r, pl.ds(pl.multiple_of(r * hd, hd), hd), c),
+        carry, unroll=True)
+
+
+def _forward_kernel(q_ref, k_ref, v_ref, keep_ref, out_ref, lse_ref,
+                    m_ref, l_ref, acc_ref, *, rep: int, hd: int,
+                    scale: float):
+    """One tile of queries of one key-value group against one tile of its
+    keys: the online-softmax recurrence of each of the group's ``rep``
+    query heads. The key tiles are the innermost grid axis; the running
+    maximum, sum (lane-replicated) and accumulator live in scratch."""
+    j = pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_BIG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    kept = keep_ref[...] != 0  # [block_q, block_k], every head's mask
+    k, v = k_ref[...], v_ref[...]
+
+    def head(r, cols, _):
+        s = jax.lax.dot_general(
+            q_ref[:, cols], k, _NT,
+            preferred_element_type=jnp.float32) * scale
+        s = jnp.where(kept, s, _NEG_BIG)
+        m_prev = m_ref[r]
+        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+        # selection is scattered: while a row's first kept key lies in a
+        # later tile its maximum is still _NEG_BIG and exp(s - m) is 1 on
+        # the masked pairs, so p is masked itself
+        p = jnp.where(kept, jnp.exp(s - _across(m_new, s.shape[1])), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[r] = alpha * l_ref[r] + p.sum(-1, keepdims=True)
+        m_ref[r] = m_new
+        acc_ref[r] = acc_ref[r] * _across(alpha, hd) + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+
+    _heads(rep, hd, head)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        def write(r, cols, _):
+            total = l_ref[r]
+            out_ref[:, cols] = (
+                acc_ref[r] / _across(total, hd)).astype(out_ref.dtype)
+            lse_ref[r] = m_ref[r] + jnp.log(total)
+
+        _heads(rep, hd, write)
+
+
+def _head_mean_kernel(q_ref, k_ref, lse_ref, keep_ref, w_ref, *, rep: int,
+                      hd: int, scale: float, heads: int):
+    """The heads' mean of the attention weights of one ``[block_q,
+    block_k]`` tile: the scores again, normalised by each head's final
+    log-sum-exp, summed over the group's heads here and over the groups
+    along the innermost grid axis. The mask goes on once, over the sum
+    (an unkept pair's exponential may be anything, inf included)."""
+    g = pl.program_id(2)
+
+    @pl.when(g == 0)
+    def _():
+        w_ref[...] = jnp.zeros_like(w_ref)
+
+    k = k_ref[...]
+
+    def head(r, cols, total):
+        s = jax.lax.dot_general(
+            q_ref[:, cols], k, _NT,
+            preferred_element_type=jnp.float32) * scale
+        return total + jnp.exp(s - _across(lse_ref[r], s.shape[1]))
+
+    # the sum starts from zeros of its own and not from the block, whose
+    # reads carry the mesh axes the operands vary over; a loop carry does not
+    total = w_ref[...] + _heads(rep, hd, head, jnp.zeros(w_ref.shape,
+                                                         jnp.float32))
+    w_ref[...] = total
+
+    @pl.when(g == pl.num_programs(2) - 1)
+    def _():
+        w_ref[...] = jnp.where(keep_ref[...] != 0, total / heads, 0.0)
+
+
+def _backward_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, lse_ref,
+                     delta_ref, dq_ref, dk_ref, dv_ref, dq_acc, *, rep: int,
+                     hd: int, scale: float):
+    """One tile of keys of one group against one tile of its queries,
+    keys as rows (``keep_ref`` is the mask transposed), so that the
+    rows' log-sum-exp and ``delta`` are lane vectors and ``dK`` / ``dV``
+    are plain products: the scores once, ``p``, ``dP``, ``dS`` in VMEM,
+    ``dK`` and ``dV`` summed over the group's heads, ``dQ`` accumulated
+    over the key tiles (the innermost grid axis) in float32 scratch."""
+    j = pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    kept = keep_ref[...] != 0  # [block_k, block_q]
+    k, v = k_ref[...], v_ref[...]
+
+    def head(r, cols, sums):
+        q, do = q_ref[:, cols], do_ref[:, cols]
+        s = jax.lax.dot_general(k, q, _NT,
+                                preferred_element_type=jnp.float32) * scale
+        # normalised; exactly 0 where masked
+        p = jnp.exp(jnp.where(kept, s, _NEG_BIG) - lse_ref[pl.ds(r, 1), :])
+        dp = jax.lax.dot_general(v, do, _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[pl.ds(r, 1), :]) * scale
+        dq_acc[:, cols] += jnp.dot(ds.T.astype(k.dtype), k,
+                                   preferred_element_type=jnp.float32)
+        return (sums[0] + jnp.dot(ds.astype(q.dtype), q,
+                                  preferred_element_type=jnp.float32),
+                sums[1] + jnp.dot(p.astype(do.dtype), do,
+                                  preferred_element_type=jnp.float32))
+
+    zeros = jnp.zeros(dk_ref.shape, jnp.float32)
+    dk, dv = _heads(rep, hd, head, (zeros, zeros))
+    dk_ref[...] = dk.astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+
+
+def _rows(x, block: int):
+    """``[T, heads, hd]`` -> ``[T padded to a multiple of block, heads *
+    hd]``: the layout the model holds, one head per ``hd`` columns."""
+    t = x.shape[0]
+    return jnp.pad(x.reshape(t, -1), ((0, -t % block), (0, 0)))
+
+
+def _tiled(q, k, v, keep, block_q: int, block_k: int):
+    """What every kernel call starts from: block sizes ``min(block,
+    extent)``, ``q`` / ``k`` / ``v`` as padded rows, the int8 keep mask
+    padded with nothing kept, and the number of query and key tiles."""
+    tq, tk = q.shape[0], k.shape[0]
+    bq, bk = min(block_q, tq), min(block_k, tk)
+    keep8 = jnp.pad(keep.astype(jnp.int8), ((0, -tq % bq), (0, -tk % bk)))
+    return (bq, bk, _rows(q, bq), _rows(k, bk), _rows(v, bk), keep8,
+            keep8.shape[0] // bq, keep8.shape[1] // bk)
+
+
+def _call(name, kernel, ins, outs, **grid):
+    """``pl.pallas_call`` of ``kernel`` (``name`` is what a device trace
+    calls it) on ``ins`` with outputs ``outs`` (``(shape, dtype)`` each);
+    the tiles a kernel accumulates over, keys or groups, are its
+    innermost grid axis. Inside a manual mesh
+    region (the round engine's client lanes) the interpreter cannot type
+    the kernel's constants against operands that vary over the mesh, so
+    off the chip every lane runs the call on all lanes' operands, which
+    do not vary, and keeps its own result."""
+    def call(*ins):
+        return pl.pallas_call(
+            kernel, name=name,
+            out_shape=[out_struct(*o, ins) for o in outs],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=_VMEM_LIMIT),
+            interpret=_interpret(), **grid)(*ins)
+
+    lanes = tuple(frozenset().union(*(jax.typeof(x).vma for x in ins)))
+    if not (lanes and _interpret()):
+        return call(*ins)
+    me = jax.lax.axis_index(lanes)
+    mine = jnp.arange(jax.lax.psum(1, lanes)) == me
+
+    def gather(x):  # a sum in which every other lane's term is zero
+        slot = mine.reshape((-1,) + (1,) * x.ndim)
+        return jax.lax.psum(jnp.where(slot, x[None], jnp.zeros_like(x)), lanes)
+
+    return [o[me] for o in jax.vmap(call)(*map(gather, ins))]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def selected_attention(q, k, v, keep, block_q: int = 512,
+                       block_k: int = 512):
     """``q``: ``[Tq, H, hd]``; ``k``, ``v``: ``[Tk, G, hd]`` with ``H`` a
     multiple of ``G`` (query head ``h`` reads key-value head ``h // (H //
     G)``); ``keep``: ``[Tq, Tk]`` bool, at least one key kept per query.
-    Scores, their exponentials and the row sums are float32. Returns
-    (``[Tq, H * hd]`` in ``q``'s dtype, ``[Tq, Tk]`` float32 attention
-    weights averaged over the heads: the indexer's target, a constant
-    that carries no gradient).
+    Scores, maxima, exponentials, sums and accumulators are float32; the
+    exponentials are rounded to ``v``'s dtype once for their product with
+    ``v``. Returns (``[Tq, H * hd]`` in ``q``'s dtype, ``[Tq, Tk]``
+    float32 attention weights averaged over the heads, exactly 0 off
+    ``keep``: the indexer's target, a constant that carries no gradient).
 
-    The ``[H, Tq, Tk]`` arrays are what this costs on the chip (every
-    pass over them is HBM traffic), so the softmax is never normalised
-    at that size: the exponentials are rounded to ``v``'s dtype once,
-    their product with ``v`` is divided by the row sums, and the heads'
-    mean reads the same rounded array. The backward pass is written out
-    for the same reason: it keeps the output and the rows' log-sum-exp
-    (``checkpoint_name`` ``attn_out`` / ``attn_lse``, so that a caller's
-    rematerialisation policy can keep them and skip this forward pass),
-    recomputes the scores once and needs neither a second row maximum
-    nor a second product with ``v``."""
-    return _selected_attention_fwd(q, k, v, keep)[0]
+    Three Pallas kernels in which a ``[block_q, block_k]`` tile of one
+    head's scores lives and dies in VMEM, so no ``[H, Tq, Tk]`` array
+    ever reaches HBM: the forward pass (online softmax over the key
+    tiles; the ``H // G`` query heads of a group share its ``k`` / ``v``
+    tile and the keep tile), the heads' mean (the scores a second time,
+    normalised by each head's final log-sum-exp) and the backward pass,
+    which keeps the output and the rows' log-sum-exp (``checkpoint_name``
+    ``attn_out`` / ``attn_lse``, so that a caller's rematerialisation
+    policy can keep them and skip the forward kernels), recomputes a
+    tile's scores once and forms ``p``, ``dP`` and ``dS`` there. Extents
+    that are no multiple of their block are padded with nothing kept."""
+    return _selected_attention_fwd(q, k, v, keep, block_q, block_k)[0]
 
 
-def _selected_attention_fwd(q, k, v, keep):
+def _selected_attention_fwd(q, k, v, keep, block_q, block_k):
     tq, h, hd = q.shape
-    _, s = _scores(q, k, keep)
-    top = s.max(-1, keepdims=True)
-    e = jnp.exp(s - top)  # exactly 0 where masked
-    total = e.sum(-1)  # [G, R, Tq]
-    inv = 1.0 / total
-    e = e.astype(v.dtype)
-    out = jnp.einsum("grqk,kgd->qgrd", e, v,
-                     preferred_element_type=jnp.float32)
-    out = (out * inv.transpose(2, 0, 1)[..., None]).astype(q.dtype)
-    weights = jnp.einsum("grqk,grq->qk", e, inv.astype(e.dtype),
-                         preferred_element_type=jnp.float32) / h
-    out = checkpoint_name(out.reshape(tq, h * hd), "attn_out")
-    lse = checkpoint_name(top[..., 0] + jnp.log(total), "attn_lse")
-    return (out, weights), (q, k, v, keep, out, lse)
+    tk, g, _ = k.shape
+    rep = h // g
+    bq, bk, q2, k2, v2, keep8, nq, nk = _tiled(q, k, v, keep, block_q,
+                                               block_k)
+    sizes = dict(rep=rep, hd=hd, scale=hd ** -0.5)
+    out, lse = _call(
+        "attn_sparse_forward", functools.partial(_forward_kernel, **sizes),
+        (q2, k2, v2, keep8),
+        [(q2.shape, q.dtype), ((h, q2.shape[0], _LANES), jnp.float32)],
+        grid=(g, nq, nk),
+        in_specs=[
+            pl.BlockSpec((bq, rep * hd), lambda g_, i, j: (i, g_)),
+            pl.BlockSpec((bk, hd), lambda g_, i, j: (j, g_)),
+            pl.BlockSpec((bk, hd), lambda g_, i, j: (j, g_)),
+            pl.BlockSpec((bq, bk), lambda g_, i, j: (i, j)),
+        ],
+        out_specs=[
+            pl.BlockSpec((bq, rep * hd), lambda g_, i, j: (i, g_)),
+            pl.BlockSpec((rep, bq, _LANES), lambda g_, i, j: (g_, i, 0)),
+        ],
+        scratch_shapes=[pltpu.VMEM((rep, bq, _LANES), jnp.float32),
+                        pltpu.VMEM((rep, bq, _LANES), jnp.float32),
+                        pltpu.VMEM((rep, bq, hd), jnp.float32)])
+    weights, = _call(
+        "attn_sparse_head_mean",
+        functools.partial(_head_mean_kernel, heads=h, **sizes),
+        (q2, k2, lse, keep8), [(keep8.shape, jnp.float32)],
+        grid=(nq, nk, g),
+        in_specs=[
+            pl.BlockSpec((bq, rep * hd), lambda i, j, g_: (i, g_)),
+            pl.BlockSpec((bk, hd), lambda i, j, g_: (j, g_)),
+            pl.BlockSpec((rep, bq, _LANES), lambda i, j, g_: (g_, i, 0)),
+            pl.BlockSpec((bq, bk), lambda i, j, g_: (i, j)),
+        ],
+        out_specs=[pl.BlockSpec((bq, bk), lambda i, j, g_: (i, j))])
+    out = checkpoint_name(out[:tq], "attn_out")
+    lse = checkpoint_name(lse[:, :tq, 0].reshape(g, rep, tq), "attn_lse")
+    return (out, weights[:tq, :tk]), (q, k, v, keep, out, lse)
 
 
-def _selected_attention_bwd(res, cotangents):
+def _selected_attention_bwd(block_q, block_k, res, cotangents):
     q, k, v, keep, out, lse = res
     d_out = cotangents[0]  # the weights are a constant target
     tq, h, hd = q.shape
-    qg, s = _scores(q, k, keep)
-    p = jnp.exp(s - lse[..., None])  # normalised; exactly 0 where masked
-    do = d_out.reshape(qg.shape)
-    dp = jnp.einsum("qgrd,kgd->grqk", do, v,
-                    preferred_element_type=jnp.float32)
-    delta = (do.astype(jnp.float32)
-             * out.reshape(qg.shape).astype(jnp.float32)).sum(-1)
-    ds = (p * (dp - delta.transpose(1, 2, 0)[..., None])
-          * (hd ** -0.5)).astype(q.dtype)
-    dv = jnp.einsum("grqk,qgrd->kgd", p.astype(v.dtype), do,
-                    preferred_element_type=jnp.float32)
-    dq = jnp.einsum("grqk,kgd->qgrd", ds, k,
-                    preferred_element_type=jnp.float32)
-    dk = jnp.einsum("grqk,qgrd->kgd", ds, qg,
-                    preferred_element_type=jnp.float32)
-    return (dq.reshape(q.shape).astype(q.dtype), dk.astype(k.dtype),
-            dv.astype(v.dtype), None)
+    tk, g, _ = k.shape
+    rep = h // g
+    bq, bk, q2, k2, v2, keep8, nq, nk = _tiled(q, k, v, keep, block_q,
+                                               block_k)
+    delta = (d_out.astype(jnp.float32) * out.astype(jnp.float32)).reshape(
+        tq, g, rep, hd).sum(-1).transpose(1, 2, 0)
+    pad = ((0, 0), (0, 0), (0, -tq % bq))
+    ins = (q2, k2, v2, keep8.T, _rows(d_out, bq), jnp.pad(lse, pad),
+           jnp.pad(delta, pad))
+    rows = pl.BlockSpec((bq, rep * hd), lambda g_, i, j: (i, g_))
+    keys = pl.BlockSpec((bk, hd), lambda g_, i, j: (j, g_))
+    stats = pl.BlockSpec((None, rep, bq), lambda g_, i, j: (g_, 0, i))
+    sums = pl.BlockSpec((None, bk, hd), lambda g_, i, j: (i, j, g_))
+    # one tile of queries: dK and dV leave the kernel whole, in k's dtype;
+    # more: float32 partial sums, one per query tile
+    sum_dtype = k.dtype if nq == 1 else jnp.float32
+    dq, dk, dv = _call(
+        "attn_sparse_backward",
+        functools.partial(_backward_kernel, rep=rep, hd=hd,
+                          scale=hd ** -0.5), ins,
+        [(q2.shape, q.dtype)] + [((nq,) + k2.shape, sum_dtype)] * 2,
+        grid=(g, nq, nk),
+        in_specs=[rows, keys, keys,
+                  pl.BlockSpec((bk, bq), lambda g_, i, j: (j, i)),
+                  rows, stats, stats],
+        out_specs=[rows, sums, sums],
+        scratch_shapes=[pltpu.VMEM((bq, rep * hd), jnp.float32)])
+    return (dq[:tq].reshape(q.shape),
+            dk.sum(0)[:tk].reshape(k.shape).astype(k.dtype),
+            dv.sum(0)[:tk].reshape(v.shape).astype(v.dtype), None)
 
 
 selected_attention.defvjp(_selected_attention_fwd, _selected_attention_bwd)

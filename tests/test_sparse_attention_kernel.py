@@ -1,0 +1,286 @@
+"""ops/sparse_attention.selected_attention: the three Pallas kernels
+(forward, heads' mean, backward) in interpret mode on the CPU against the
+attention equations of the plain reference (tests/reference/
+keye_decoder.py: a float32 softmax over the kept pairs of each head,
+rounded to the compute dtype once for its product with v; the heads'
+mean of those weights), and the compiled text of the gradient for a
+described v5e."""
+
+import importlib.util
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from colearn_federated_learning_tpu.models import build_model
+from colearn_federated_learning_tpu.ops import sparse_attention
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+_spec = importlib.util.spec_from_file_location(
+    "keye_ref", os.path.join(HERE, "reference", "keye_decoder.py"))
+ref = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(ref)
+
+HD = 16
+
+
+def reference_attention(q, k, v, keep, compute):
+    """The reference's ``head_weights`` / ``head_output`` on given q, k,
+    v: (``[Tq, H * hd]``, the heads' mean of the weights)."""
+    tq, heads, hd = q.shape
+    group = jnp.arange(heads) // (heads // k.shape[1])
+    qh, kh, vh = (q.transpose(1, 0, 2), k.transpose(1, 0, 2)[group],
+                  v.transpose(1, 0, 2)[group])
+
+    def head_weights(qh, kh):
+        s = jnp.dot(qh, kh.T, preferred_element_type=ref.ISLAND) * hd ** -0.5
+        prob = jax.nn.softmax(jnp.where(keep, s, ref.NEG), axis=-1)
+        return jnp.where(keep, prob, 0.0)
+
+    weights = jax.vmap(head_weights)(qh, kh)
+    out = jnp.einsum("hqk,hkd->qhd", weights.astype(compute), vh)
+    return out.reshape(tq, heads * hd), weights.mean(0)
+
+
+def inputs(tq, tk, heads, kv, dtype, seed=0, topk=8):
+    """A chunk of ``tq`` queries, the last of ``tk`` positions, with the
+    reference's selection of ``topk`` keys per query."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q = jax.random.normal(ks[0], (tq, heads, HD)).astype(dtype)
+    k = jax.random.normal(ks[1], (tk, kv, HD)).astype(dtype)
+    v = jax.random.normal(ks[2], (tk, kv, HD)).astype(dtype)
+    keep = ref.selection(jax.random.normal(ks[3], (tk, tk)), topk)[tk - tq:]
+    ct = jax.random.normal(ks[4], (tq, heads * HD))
+    return q, k, v, keep, ct
+
+
+def both(fn, q, k, v, keep, ct):
+    """(out, weights, (dq, dk, dv)) for the cotangent ``ct`` of out."""
+    def loss(q, k, v):
+        out, weights = fn(q, k, v, keep)
+        return (out.astype(jnp.float32) * ct).sum(), (out, weights)
+    (_, (out, weights)), grads = jax.value_and_grad(
+        loss, (0, 1, 2), has_aux=True)(q, k, v)
+    return out, weights, grads
+
+
+def close(got, want, tol):
+    want = np.asarray(want, np.float32)
+    np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                               atol=tol * max(1.0, np.abs(want).max()))
+
+
+@pytest.mark.parametrize("rep", [1, 4, 8])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 3e-5),
+                                       (jnp.bfloat16, 2e-2)])
+def test_forward_weights_and_gradients_match_the_reference(dtype, tol, rep):
+    """Several query tiles and key tiles (blocks of 8 and 16), grouped
+    heads; bfloat16 is held to the float32 reference of the same inputs."""
+    q, k, v, keep, ct = inputs(16, 48, 2 * rep, 2, dtype)
+    got = both(lambda *a: sparse_attention.selected_attention(*a, 8, 16),
+               q, k, v, keep, ct)
+    want = both(lambda *a: reference_attention(*a, jnp.float32),
+                *(a.astype(jnp.float32) for a in (q, k, v)), keep, ct)
+    close(got[0], want[0], tol)
+    close(got[1], want[1], 1e-6 if dtype == jnp.float32 else 2e-3)
+    for g, w in zip(got[2], want[2]):
+        assert g.dtype == dtype
+        close(g, w, tol)
+    assert got[0].dtype == dtype and got[1].dtype == jnp.float32
+
+
+@pytest.mark.parametrize("tq,tk", [(16, 32), (16, 40), (12, 40), (8, 8),
+                                   (12, 21)])
+def test_extents_that_are_and_are_not_a_multiple_of_the_block(tq, tk):
+    q, k, v, keep, ct = inputs(tq, tk, 4, 2, jnp.float32, seed=tq + tk,
+                               topk=5)
+    got = both(lambda *a: sparse_attention.selected_attention(*a, 8, 16),
+               q, k, v, keep, ct)
+    want = both(lambda *a: reference_attention(*a, jnp.float32),
+                q, k, v, keep, ct)
+    assert got[0].shape == (tq, 4 * HD) and got[1].shape == (tq, tk)
+    close(got[0], want[0], 3e-5)
+    close(got[1], want[1], 1e-6)
+    for g, w in zip(got[2], want[2]):
+        assert g.shape == w.shape
+        close(g, w, 3e-5)
+
+
+@pytest.mark.parametrize("case", ["last_tile_only", "one_key"])
+def test_a_query_whose_kept_keys_come_late(case):
+    """Selection is scattered: through every key tile but the last the
+    running maximum of such a row is still the mask value, and
+    ``exp(s - m)`` is 1 on its masked pairs."""
+    tq, tk = 8, 64
+    q, k, v, _, ct = inputs(tq, tk, 4, 2, jnp.float32, seed=3)
+    keep = np.zeros((tq, tk), bool)
+    if case == "last_tile_only":
+        keep[:, 48:] = np.asarray(
+            jax.random.bernoulli(jax.random.PRNGKey(4), 0.4, (tq, 16)))
+        keep[:, 63] = True
+    else:
+        keep[np.arange(tq), 56 + np.arange(tq)] = True
+        keep[3] = False
+        keep[3, 2] = True  # and one whose only key lies in the first tile
+    keep = jnp.asarray(keep)
+    got = both(lambda *a: sparse_attention.selected_attention(*a, 8, 16),
+               q, k, v, keep, ct)
+    want = both(lambda *a: reference_attention(*a, jnp.float32),
+                q, k, v, keep, ct)
+    close(got[0], want[0], 3e-5)
+    for g, w in zip(got[2], want[2]):
+        close(g, w, 3e-5)
+    if case == "one_key":
+        np.testing.assert_array_equal(np.asarray(got[1]),
+                                      np.asarray(keep, np.float32))
+        rows = np.asarray(v)[np.argmax(np.asarray(keep), -1)]  # [tq, G, hd]
+        np.testing.assert_allclose(
+            np.asarray(got[0]).reshape(tq, 2, 2, HD),
+            np.broadcast_to(rows[:, :, None], (tq, 2, 2, HD)), atol=1e-6)
+        # a softmax over one key is constant
+        np.testing.assert_allclose(np.asarray(got[2][0]), 0.0, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_weights_are_exactly_zero_off_keep_and_rows_sum_to_one(dtype):
+    """``index_kl`` tests ``target > 0``: an unkept pair must read 0.0,
+    whatever its score (here up to 60 above the row's kept ones)."""
+    q, k, v, keep, _ = inputs(16, 48, 8, 2, dtype, seed=7)
+    k = jnp.where(keep.any(0)[:, None, None], k, 4 * k)
+    _, weights = sparse_attention.selected_attention(q, k, v, keep, 8, 16)
+    weights, keep = np.asarray(weights), np.asarray(keep)
+    assert weights.dtype == np.float32
+    assert not np.any(weights[~keep])
+    assert np.all(weights[keep] > 0)
+    np.testing.assert_allclose(weights.sum(-1), 1.0, atol=1e-5)
+
+
+def _kernel_calls(jaxpr, found):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn.params["name"])
+        for value in eqn.params.values():
+            for item in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(item, "jaxpr", item)
+                if hasattr(inner, "eqns"):
+                    _kernel_calls(inner, found)
+    return found
+
+
+def test_the_forward_kernels_run_once_a_step_under_rematerialisation():
+    """Under ``vmap`` and the decoder layer's policy the output, the
+    log-sum-exp and the weights are kept, so the gradient's program
+    holds each kernel once: nothing of attention is recomputed."""
+    q, k, v, keep, ct = inputs(8, 16, 4, 2, jnp.float32)
+
+    def block(q, k, v):
+        out, weights = sparse_attention.selected_attention(q, k, v, keep)
+        weights = jax.ad_checkpoint.checkpoint_name(weights, "attn_weights")
+        return (out * ct).sum() + (jax.lax.stop_gradient(weights)
+                                   * (q.sum() + k.sum())).sum()
+
+    def loss(policy, q, k, v):
+        layer = jax.checkpoint(block, policy=policy)
+        return jax.vmap(layer)(q[None], k[None], v[None]).sum()
+
+    names = jax.checkpoint_policies.save_only_these_names(
+        "attn_out", "attn_lse", "attn_weights")
+    for policy, forward in ((names, 1),
+                            (jax.checkpoint_policies.nothing_saveable, 2)):
+        calls = _kernel_calls(jax.make_jaxpr(jax.grad(
+            lambda *a: loss(policy, *a), (0, 1, 2)))(q, k, v).jaxpr, [])
+        assert sorted(calls) == sorted(
+            ["attn_sparse_backward"]
+            + ["attn_sparse_forward", "attn_sparse_head_mean"] * forward
+        ), policy
+
+
+def _dry_keye():
+    sizes = dict(vocab_size=8, seq_len=32, layers=2, hidden=64, heads=4,
+                 kv_heads=2, head_dim=16, num_experts=8, experts_held=2,
+                 expert_offset=2, experts_per_token=3, expert_width=32,
+                 index_heads=2, index_head_dim=8, index_topk=8,
+                 mrope_section=(2, 3, 3), q_chunk=8, moe_tile=4)
+    # 2 index heads: the indexer's [index_heads, q_chunk, keys] scores
+    # must not have the shape of attention's
+    return build_model("keye_decoder", 0, **sizes), sizes
+
+
+def _loss_and_grad(model):
+    def loss(params, tokens):
+        logits, aux = model.apply({"params": params}, tokens, train=True)
+        return logits.mean() + aux["loss"].sum()
+    return jax.value_and_grad(loss)
+
+
+def test_the_decoders_gradient_holds_no_per_head_score_array():
+    """The regression this kernel exists to prevent, at the rehearsal
+    size: no ``[G, H/G, q_chunk, keys]`` array in the lowered gradient
+    (the parent's holds one per chunk and pass)."""
+    model, s = _dry_keye()
+    tokens = jnp.zeros((1, s["seq_len"]), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), tokens)["params"])
+    text = jax.jit(_loss_and_grad(model)).lower(params, tokens).as_text()
+    g, rep = s["kv_heads"], s["heads"] // s["kv_heads"]
+    keys = "(8|16|24|32)"  # a chunk sees 8 .. 32 keys
+    assert not re.search(
+        rf"tensor<(1x)?{g}x{rep}x{s['q_chunk']}x{keys}xf32>", text)
+    assert not re.search(
+        rf"tensor<(1x)?{s['heads']}x{s['q_chunk']}x{keys}xf32>", text)
+
+
+@pytest.fixture(scope="module")
+def chip_mesh():
+    """A ``clients`` mesh over one described v5e chip."""
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return jax.sharding.Mesh(np.array(topo.devices[:1]), ("clients",))
+
+
+def test_compiled_for_a_v5e_the_scores_stay_in_the_kernels(chip_mesh,
+                                                           monkeypatch):
+    """One decoder layer at the published widths (2,048 tokens: four
+    chunks of 512 queries), bfloat16, inside a manual ``clients`` region
+    as the round engine runs it, compiled for a described v5e with the
+    kernels as Mosaic calls: Mosaic accepts their tiling, the kernels'
+    loop carries type-check against operands that vary over the mesh,
+    every chunk has its three kernels (forward and heads' mean kept
+    through the layer's rematerialisation), and no float32 buffer of the
+    program has the shape of a chunk's per-head scores."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    monkeypatch.setattr(sparse_attention, "_interpret", lambda: False)
+    model = build_model("keye_decoder", 0, seq_len=2048, layers=1,
+                        vocab_size=1024, compute_dtype=jnp.bfloat16,
+                        param_dtype=jnp.bfloat16)
+    everywhere = NamedSharding(chip_mesh, P())
+    tokens = jax.ShapeDtypeStruct(
+        (1, 2048), jnp.int32, sharding=NamedSharding(chip_mesh, P("clients")))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=everywhere),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 2048), jnp.int32))["params"]))
+
+    def lane(params, tokens):
+        # a client's own copy of the weights, as the trainer holds them
+        params = jax.lax.pcast(params, ("clients",), to="varying")
+        loss, grads = _loss_and_grad(model)(params, tokens)
+        return jax.lax.psum((loss, grads), "clients")
+
+    step = jax.jit(jax.shard_map(lane, mesh=chip_mesh,
+                                 in_specs=(P(), P("clients")), out_specs=P()))
+    text = step.lower(params, tokens).compile().as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 3 * 4
+    keys = "(512|1024|1536|2048)"  # what a chunk sees here
+    assert not re.search(rf"f32\[(1,)?4,8,512,{keys}\]", text)
+    assert not re.search(rf"f32\[(1,)?32,512,{keys}\]", text)
