@@ -5,10 +5,10 @@ Three pure-host analyzers turn the repo's hand-maintained correctness
 disciplines into checked artifacts:
 
 - :mod:`analysis.capability` — enumerates the config pairing space,
-  runs ``config.validate()`` and the engine-compat mirror
-  (``parallel.round_engine._check_engine_compat``) on every pairing,
-  emits the checked-in ``capability_matrix.json``, and fails on any
-  validate()↔mirror disagreement or reason-less rejection.
+  runs ``config.validate()`` (the one place that refuses a pairing) on
+  every pairing, and fails where the verdicts or reasons differ from
+  the checked-in ``capability_matrix.json`` golden, or on a reason-less
+  rejection.
 - :mod:`analysis.seed_purity` — AST lint of the program-path and
   record-producing modules for wall-clock reads, unseeded RNG, and
   bare ``assert`` in library code, against the checked-in

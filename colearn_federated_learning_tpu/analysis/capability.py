@@ -1,205 +1,182 @@
-"""Capability-matrix extractor + validate()↔engine-mirror drift detector
-(`colearn check` analyzer a).
+"""Capability matrix: which features may be combined, extracted from
+the one place that decides it (`colearn check` analyzer a).
 
-The config exclusion matrix lives in TWO hand-maintained places:
-``config.ExperimentConfig.validate()`` (the authoritative, config-level
-superset) and ``parallel.round_engine._check_engine_compat`` (the
-engine-level mirror that protects direct ``make_*_round_fn`` callers).
-PRs 6–12 each added clauses with no machine check that the two agree.
+``config.ExperimentConfig.validate()`` is the only refusal site: the
+engine factories take a validated config's values and refuse no pairing
+themselves (``parallel/round_engine.make_sharded_round_fn``). This
+module enumerates a curated FEATURE catalog (each feature = the
+canonical-valid override set that turns one subsystem on), asks
+``validate()`` about every feature singleton and every pairing, and
+compares the answer with the checked-in ``capability_matrix.json`` —
+the golden set of rejected pairings and their reasons. A pairing that
+flips either way, or a reason that changes by a letter, fails
+`colearn check` (and tests/test_static_analysis.py, feature by
+feature) and names itself; `colearn check --update-matrix` regenerates
+the artifact for review. That golden set is the oracle for turning
+``validate()``'s pairing rules into a table (ROADMAP.md D1).
 
-This module enumerates a curated FEATURE catalog (each feature = the
-canonical-valid override set that turns one subsystem on), evaluates
-every feature singleton and pairing through both layers, and emits the
-machine-readable ``capability_matrix.json`` — the contract artifact the
-ROADMAP item-2 round-program refactor must preserve or shrink.
-
-Verdicts per pairing: ``validate`` (ok / the rejection reason) and
-``mirror`` (ok / reason / ``n/a`` when the pairing never builds a
-centralized engine — gossip/fedbuff own their own factories). A pairing
-DRIFTS when (1) validate accepts but the mirror rejects — the config
-layer would admit a run that dies at engine construction — or (2)
-validate rejects, the mirror accepts, and BOTH features are in the
-mirror's vocabulary (``mirror_visible``) — a direct engine caller could
-build the unsound combination the mirror exists to refuse. Rejections
-without a reason string fail outright.
-
-Reconciliations this analyzer has already forced are listed in
-``RECONCILIATIONS`` (shipped in the matrix artifact for provenance).
+The artifact holds the catalog (overrides + note per feature), the
+counts, and ``rejected``: ``{"a+b": reason}``. A pairing that is not
+listed there validates; a pairing whose two override sets set one knob
+to different values is ill-posed and skipped. Rejections without a
+reason string fail outright.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 from colearn_federated_learning_tpu.config import ExperimentConfig
 
 MATRIX_FILENAME = "capability_matrix.json"
-MATRIX_VERSION = 1
-
-# mirror reconciliations shipped with the analyzer (ISSUE 13 satellite):
-# one line each, naming the pairing class and the fix
-RECONCILIATIONS = [
-    "scaffold x example_dp: mirror gained example_dp (DP noise would "
-    "enter the persistent c state; validate() already rejected it)",
-    "feddyn x example_dp: mirror gained example_dp (same stateful-"
-    "trajectory reasoning; validate() already rejected it)",
-    "upload-attack x example_dp: mirror gained example_dp (a Byzantine "
-    "client does not run the DP-SGD mechanism; validate() already "
-    "rejected it)",
-    "feddyn x robust/compression/clip: guard lifted from "
-    "_feddyn_prepare into the shared mirror so the extractor compares "
-    "one contract surface (the prepare-time guard stays for direct "
-    "callers)",
-]
+MATRIX_VERSION = 2
 
 
 class Feature:
     """One subsystem in its canonical-valid form: the dotted overrides
-    that enable it, and whether the engine-compat mirror can see it
-    (``mirror_visible`` — drives drift enforceability)."""
+    that enable it."""
 
-    def __init__(self, overrides: Dict[str, Any], mirror_visible: bool,
-                 note: str = ""):
+    def __init__(self, overrides: Dict[str, Any], note: str = ""):
         self.overrides = overrides
-        self.mirror_visible = mirror_visible
         self.note = note
 
 
 # The feature catalog. Every singleton MUST validate clean (checked at
 # extraction — a failing singleton means the catalog itself is broken).
 FEATURES: Dict[str, Feature] = {
-    "sequential_engine": Feature({"run.engine": "sequential"}, False,
+    "sequential_engine": Feature({"run.engine": "sequential"},
                                  "the bit-parity oracle engine"),
     "scaffold": Feature({"algorithm": "scaffold", "client.momentum": 0.0},
-                        True, "client control variates"),
-    "feddyn": Feature({"algorithm": "feddyn"}, True,
+                        "client control variates"),
+    "feddyn": Feature({"algorithm": "feddyn"},
                       "dynamic regularization"),
-    "fedbuff": Feature({"algorithm": "fedbuff"}, False,
+    "fedbuff": Feature({"algorithm": "fedbuff"},
                        "async buffered aggregation (own engine)"),
-    "gossip": Feature({"algorithm": "gossip"}, False,
+    "gossip": Feature({"algorithm": "gossip"},
                       "decentralized DFedAvg (own engine)"),
-    "example_dp": Feature({"dp.enabled": True}, True,
+    "example_dp": Feature({"dp.enabled": True},
                           "example-level local DP-SGD"),
     "client_dp": Feature({"server.dp_client_noise_multiplier": 1.0,
-                          "server.clip_delta_norm": 1.0}, True,
+                          "server.clip_delta_norm": 1.0},
                          "central client-level DP (DP-FedAvg)"),
     "secagg": Feature({"server.secure_aggregation": True,
-                       "server.clip_delta_norm": 1.0}, True,
+                       "server.clip_delta_norm": 1.0},
                       "ring-mask secure aggregation"),
     "secagg_pairwise": Feature({"server.secure_aggregation": True,
                                 "server.clip_delta_norm": 1.0,
-                                "server.secagg_mode": "pairwise"}, True,
+                                "server.secagg_mode": "pairwise"},
                                "Bonawitz pairwise-mask protocol shape"),
-    "attack_sign_flip": Feature({"attack.kind": "sign_flip"}, True,
+    "attack_sign_flip": Feature({"attack.kind": "sign_flip"},
                                 "boosted sign-flip upload attack"),
-    "attack_alie": Feature({"attack.kind": "alie"}, True,
+    "attack_alie": Feature({"attack.kind": "alie"},
                            "colluding a-little-is-enough attack"),
-    "attack_label_flip": Feature({"attack.kind": "label_flip"}, False,
+    "attack_label_flip": Feature({"attack.kind": "label_flip"},
                                  "host-side data poisoning (never "
                                  "reaches the engine)"),
-    "robust_median": Feature({"server.aggregator": "median"}, True,
+    "robust_median": Feature({"server.aggregator": "median"},
                              "coordinate-wise median"),
     "robust_trimmed_mean": Feature({"server.aggregator": "trimmed_mean"},
-                                   True, "coordinate-wise trimmed mean"),
+                                   "coordinate-wise trimmed mean"),
     "robust_krum": Feature({"server.aggregator": "krum",
-                            "server.krum_byzantine": 1}, True,
+                            "server.krum_byzantine": 1},
                            "whole-update krum selection"),
-    "compression_topk": Feature({"server.compression": "topk"}, True,
+    "compression_topk": Feature({"server.compression": "topk"},
                                 "sparse top-k uplink compression"),
-    "compression_qsgd": Feature({"server.compression": "qsgd"}, True,
+    "compression_qsgd": Feature({"server.compression": "qsgd"},
                                 "dense unbiased quantization"),
     "error_feedback": Feature({"server.compression": "qsgd",
-                               "server.error_feedback": True}, True,
+                               "server.error_feedback": True},
                               "EF-SGD residual memory (needs a "
                               "compressor; qsgd is the canonical pick)"),
-    "downlink_qsgd": Feature({"server.downlink_compression": "qsgd"}, True,
+    "downlink_qsgd": Feature({"server.downlink_compression": "qsgd"},
                              "broadcast quantization"),
-    "client_ledger": Feature({"run.obs.client_ledger.enabled": True}, True,
+    "client_ledger": Feature({"run.obs.client_ledger.enabled": True},
                              "per-client forensic ledger"),
     "paged_ledger": Feature({"run.obs.client_ledger.enabled": True,
                              "run.obs.client_ledger.hot_capacity": 8},
-                            False, "hot/cold paged ledger store "
+                            "hot/cold paged ledger store "
                             "(paging is driver-level, not engine-level)"),
     "reputation": Feature({"run.obs.client_ledger.enabled": True,
-                           "server.reputation.enabled": True}, True,
+                           "server.reputation.enabled": True},
                           "ledger-driven trust weighting"),
-    "sampling_weighted": Feature({"server.sampling": "weighted"}, False,
+    "sampling_weighted": Feature({"server.sampling": "weighted"},
                                  "size-proportional cohort draw"),
-    "sampling_poisson": Feature({"server.sampling": "poisson"}, False,
+    "sampling_poisson": Feature({"server.sampling": "poisson"},
                                 "Poisson subsampling (exact DP q)"),
     "sampling_adaptive": Feature({"server.sampling": "adaptive",
                                   "run.obs.client_ledger.enabled": True,
                                   "run.obs.client_ledger.log_every": 1},
-                                 False, "Oort-style utility-aware draw "
+                                 "Oort-style utility-aware draw "
                                  "(needs periodic ledger snapshots)"),
     "sampling_streaming_ledger": Feature(
         {"server.sampling": "streaming",
          "run.obs.client_ledger.enabled": True,
-         "run.obs.client_ledger.log_every": 1}, False,
+         "run.obs.client_ledger.log_every": 1},
         "million-client streaming draw with ledger-fed sketch"),
-    "fuse_rounds": Feature({"run.fuse_rounds": 2}, False,
+    "fuse_rounds": Feature({"run.fuse_rounds": 2},
                            "multi-round fused scan"),
-    "shape_buckets": Feature({"run.shape_buckets.enabled": True}, False,
+    "shape_buckets": Feature({"run.shape_buckets.enabled": True},
                              "cohort-shaped step ladder"),
-    "megabatch": Feature({"run.cohort_layout": "megabatch"}, True,
+    "megabatch": Feature({"run.cohort_layout": "megabatch"},
                          "cohort axis collapsed into the GEMM batch"),
-    "fused_apply": Feature({"server.fused_apply": True}, True,
+    "fused_apply": Feature({"server.fused_apply": True},
                            "pallas fused server-apply kernel"),
-    "stragglers": Feature({"server.straggler_rate": 0.5}, False,
+    "stragglers": Feature({"server.straggler_rate": 0.5},
                           "partial-work straggler simulation"),
     "churn": Feature({"run.churn.enabled": True,
                       "run.churn.dropout_hazard": 0.1,
-                      "run.churn.crash_rate": 0.1}, False,
+                      "run.churn.crash_rate": 0.1},
                      "seed-pure diurnal availability / dropout hazard / "
                      "crash-mid-round model (driver + sampler level; "
                      "never reaches the engine)"),
-    "batch_shards": Feature({"run.batch_shards": 2}, False,
+    "batch_shards": Feature({"run.batch_shards": 2},
                             "intra-client batch mesh axis"),
-    "stream_placement": Feature({"data.placement": "stream"}, False,
+    "stream_placement": Feature({"data.placement": "stream"},
                                 "O(cohort) host-RAM slab path"),
-    "client_store": Feature({"data.store.dir": "<store>"}, False,
+    "client_store": Feature({"data.store.dir": "<store>"},
                             "on-disk mmap client store (dir is a "
                             "validate-level sentinel; existence is "
                             "checked at construction)"),
     "store_gather_pool": Feature({"data.store.dir": "<store>",
-                                  "data.store.gather_workers": 4}, False,
+                                  "data.store.gather_workers": 4},
                                  "sharded parallel gather pool: rows "
                                  "split by owning shard, per-shard "
                                  "copies on a shared worker pool — "
                                  "bitwise row order at every worker "
                                  "count (data level; the engine never "
                                  "sees it)"),
-    "native_pipeline": Feature({"run.host_pipeline": "native"}, False,
+    "native_pipeline": Feature({"run.host_pipeline": "native"},
                                "C++ threaded host pipeline"),
     "lora": Feature({"model.name": "bert_tiny", "model.num_classes": 0,
                      "model.kwargs": {"vocab_size": 32, "seq_len": 8},
                      "model.lora.enabled": True, "model.lora.rank": 2},
-                    False, "adapter-plane uploads (params ARE the "
+                    "adapter-plane uploads (params ARE the "
                     "adapters; engine-transparent by construction)"),
-    "hierarchy": Feature({"server.hierarchy.num_edges": 2}, True,
+    "hierarchy": Feature({"server.hierarchy.num_edges": 2},
                          "two-tier edge/core federation (the engine "
                          "reused recursively, one tier down)"),
     "multi_version": Feature({"algorithm": "fedbuff",
-                              "server.async_versions": 2}, False,
+                              "server.async_versions": 2},
                              "concurrent model versions, one async "
                              "buffer each (fedbuff scheduler level)"),
     "churn_trace": Feature({"run.churn.enabled": True,
-                            "run.churn.trace": "<trace>"}, False,
+                            "run.churn.trace": "<trace>"},
                            "trace-replay availability (recorded on/off "
                            "bitmap; dir is a validate-level sentinel, "
                            "existence checked at model construction)"),
-    "digest": Feature({"run.obs.digest.enabled": True}, False,
+    "digest": Feature({"run.obs.digest.enabled": True},
                       "determinism flight recorder (driver-level digest "
                       "of fetched state; never reaches the engine)"),
     "control_plane_device": Feature(
-        {"run.control_plane": "device"}, False,
+        {"run.control_plane": "device"},
         "device-resident control plane (server/device_plane.py): "
         "cohort/churn/slab derivation lowered into the round program; "
         "driver-level — the engines run unchanged under the wrapper"),
     "executables": Feature(
-        {"run.obs.executables": True}, False,
+        {"run.obs.executables": True},
         "compiled-program observatory (obs/executables.py): AOT "
         "lower/compile registry harvesting XLA cost/memory analysis, "
         "HBM watermarks and retrace forensics; observational like "
@@ -235,144 +212,84 @@ def _merge(a: Dict[str, Any], b: Dict[str, Any]
     return out
 
 
-def _validate_verdict(overrides: Dict[str, Any]) -> Tuple[str, Optional[str]]:
+def _validate_verdict(overrides: Dict[str, Any]) -> Optional[str]:
+    """None when ``validate()`` accepts the base config under
+    ``overrides``, else the reason it gives."""
     cfg = base_config()
     cfg.apply_overrides(dict(overrides))
     try:
         cfg.validate()
-        return "ok", None
+        return None
     except ValueError as e:
-        return "rejected", str(e.args[0]) if e.args else ""
+        return str(e.args[0]) if e.args else ""
 
 
-def mirror_kwargs(cfg: ExperimentConfig) -> Dict[str, Any]:
-    """Derive the ``_check_engine_compat`` call exactly as the driver's
-    centralized-engine construction does (server/round_driver.py):
-    label_flip never reaches the engine, feddyn rides feddyn_alpha,
-    example-DP is dp_cfg.enabled."""
-    from colearn_federated_learning_tpu.server.attacks import UPLOAD_ATTACKS
-
-    return dict(
-        scaffold=cfg.algorithm == "scaffold",
-        aggregator=cfg.server.aggregator,
-        compression=cfg.server.compression,
-        clip_delta_norm=cfg.server.clip_delta_norm,
-        secagg=cfg.server.secure_aggregation,
-        feddyn=cfg.algorithm == "feddyn",
-        client_dp=cfg.server.dp_client_noise_multiplier,
-        downlink=cfg.server.downlink_compression,
-        secagg_quant_step=cfg.server.secagg_quant_step,
-        error_feedback=cfg.server.error_feedback,
-        attack=cfg.attack.kind if cfg.attack.kind in UPLOAD_ATTACKS else "",
-        client_ledger=cfg.run.obs.client_ledger.enabled,
-        reputation=cfg.server.reputation.enabled,
-        fused_apply=cfg.server.fused_apply,
-        cohort_layout=cfg.run.cohort_layout,
-        example_dp=cfg.dp.enabled,
-        hierarchy=cfg.server.hierarchy.num_edges > 0,
-    )
+def feature_verdicts(name: str) -> Tuple[Optional[str], Dict[str, str]]:
+    """What ``validate()`` says of one feature: its verdict alone (None
+    = accepted, as every catalog feature must be), and ``{partner:
+    reason}`` for every well-posed pairing it refuses."""
+    mine = FEATURES[name].overrides
+    refused: Dict[str, str] = {}
+    for other in sorted(FEATURES):
+        if other == name:
+            continue
+        merged = _merge(mine, FEATURES[other].overrides)
+        if merged is None:
+            continue
+        reason = _validate_verdict(merged)
+        if reason is not None:
+            refused[other] = reason
+    return _validate_verdict(mine), refused
 
 
-def _mirror_verdict(overrides: Dict[str, Any],
-                    mirror_fn: Optional[Callable] = None,
-                    ) -> Tuple[str, Optional[str]]:
-    cfg = base_config()
-    cfg.apply_overrides(dict(overrides))
-    if cfg.algorithm in ("gossip", "fedbuff"):
-        # those engines never route through the centralized factories'
-        # shared mirror — there is nothing to compare against
-        return "n/a", None
-    if mirror_fn is None:
-        from colearn_federated_learning_tpu.parallel.round_engine import (
-            _check_engine_compat,
-        )
-
-        mirror_fn = _check_engine_compat
-    try:
-        mirror_fn(**mirror_kwargs(cfg))
-        return "ok", None
-    except ValueError as e:
-        return "rejected", str(e.args[0]) if e.args else ""
-
-
-def _entry(name_a: str, name_b: Optional[str], overrides: Dict[str, Any],
-           enforceable: bool, mirror_fn: Optional[Callable],
-           ) -> Dict[str, Any]:
-    vres, vreason = _validate_verdict(overrides)
-    mres, mreason = _mirror_verdict(overrides, mirror_fn)
-    drift = False
-    if mres != "n/a":
-        if vres == "ok" and mres == "rejected":
-            drift = True
-        elif vres == "rejected" and mres == "ok" and enforceable:
-            drift = True
-    entry: Dict[str, Any] = {
-        "pair": name_a if name_b is None else f"{name_a}+{name_b}",
-        "validate": vres,
-        "mirror": mres,
-        "drift": drift,
-    }
-    if vreason is not None:
-        entry["reason"] = vreason
-    if mreason is not None:
-        entry["mirror_reason"] = mreason
-    return entry
-
-
-def extract_matrix(mirror_fn: Optional[Callable] = None) -> Dict[str, Any]:
-    """Build the full matrix: every singleton + every non-conflicting
-    pairing, both verdicts, drift flags. ``mirror_fn`` is injectable so
-    the drift detector itself is testable (a permissive stub must light
-    up the enforceable pairings)."""
+def extract_matrix() -> Dict[str, Any]:
+    """Build the matrix: every singleton + every non-conflicting
+    pairing through ``validate()``; the rejected ones with reasons."""
     names = sorted(FEATURES)
-    singletons: List[Dict[str, Any]] = []
-    for name in names:
-        entry = _entry(name, None, FEATURES[name].overrides,
-                       FEATURES[name].mirror_visible, mirror_fn)
-        if entry["validate"] != "ok":
+    rejected: Dict[str, str] = {}
+    for a in names:
+        alone, refused = feature_verdicts(a)
+        if alone is not None:
             raise ValueError(
-                f"capability catalog is broken: singleton {name!r} does "
-                f"not validate: {entry.get('reason')}"
+                f"capability catalog is broken: singleton {a!r} does "
+                f"not validate: {alone}"
             )
-        singletons.append(entry)
-    pairs: List[Dict[str, Any]] = []
-    skipped = 0
-    for i, a in enumerate(names):
-        for b in names[i + 1:]:
-            merged = _merge(FEATURES[a].overrides, FEATURES[b].overrides)
-            if merged is None:
-                skipped += 1
-                continue
-            enforceable = (FEATURES[a].mirror_visible
-                           and FEATURES[b].mirror_visible)
-            pairs.append(_entry(a, b, merged, enforceable, mirror_fn))
-    supported = sum(1 for p in pairs if p["validate"] == "ok")
+        rejected.update(
+            {f"{a}+{b}": reason for b, reason in refused.items() if a < b}
+        )
+    pairs = list(itertools.combinations(names, 2))
+    n_pairs = sum(
+        _merge(FEATURES[a].overrides, FEATURES[b].overrides) is not None
+        for a, b in pairs
+    )
+    skipped = len(pairs) - n_pairs
     return {
         "version": MATRIX_VERSION,
         "base": "16 clients / cohort 8 / 8 rounds / eval_every 2 "
                 "(capability.base_config)",
         "features": {
             n: {"overrides": FEATURES[n].overrides,
-                "mirror_visible": FEATURES[n].mirror_visible,
                 "note": FEATURES[n].note}
             for n in names
         },
-        "reconciliations": RECONCILIATIONS,
         "counts": {
             "features": len(names),
-            "pairs": len(pairs),
-            "supported": supported,
-            "rejected": len(pairs) - supported,
+            "pairs": n_pairs,
+            "supported": n_pairs - len(rejected),
+            "rejected": len(rejected),
             "skipped_conflicts": skipped,
-            "drift": sum(1 for p in pairs if p["drift"]),
         },
-        "singletons": singletons,
-        "pairs": pairs,
+        "rejected": rejected,
     }
 
 
 def matrix_path(root: str) -> str:
     return os.path.join(root, MATRIX_FILENAME)
+
+
+def load_matrix(root: str) -> Dict[str, Any]:
+    with open(matrix_path(root)) as f:
+        return json.load(f)
 
 
 def write_matrix(root: str, matrix: Optional[Dict[str, Any]] = None) -> str:
@@ -384,54 +301,33 @@ def write_matrix(root: str, matrix: Optional[Dict[str, Any]] = None) -> str:
     return path
 
 
-def check_capability(root: str,
-                     mirror_fn: Optional[Callable] = None,
-                     ) -> Dict[str, Any]:
-    """The `colearn check` entry: extract, detect drift + reason-less
+def check_capability(root: str) -> Dict[str, Any]:
+    """The `colearn check` entry: extract, refuse reason-less
     rejections, and diff against the checked-in artifact."""
-    matrix = extract_matrix(mirror_fn)
+    matrix = extract_matrix()
     violations: List[Dict[str, Any]] = []
-    for entry in matrix["singletons"] + matrix["pairs"]:
-        if entry["drift"]:
+    for pair, reason in matrix["rejected"].items():
+        if not reason.strip():
             violations.append({
-                "kind": "mirror_drift", "where": entry["pair"],
-                "message": (
-                    f"validate()={entry['validate']} but engine mirror="
-                    f"{entry['mirror']} for pairing {entry['pair']} "
-                    f"(reason: {entry.get('reason') or entry.get('mirror_reason') or 'n/a'})"
-                ),
-            })
-        if entry["validate"] == "rejected" and not (entry.get("reason")
-                                                    or "").strip():
-            violations.append({
-                "kind": "rejection_without_reason", "where": entry["pair"],
-                "message": f"pairing {entry['pair']} is rejected with an "
+                "kind": "rejection_without_reason", "where": pair,
+                "message": f"pairing {pair} is rejected with an "
                            f"empty reason string",
             })
-        if entry["mirror"] == "rejected" and not (entry.get("mirror_reason")
-                                                  or "").strip():
-            violations.append({
-                "kind": "rejection_without_reason", "where": entry["pair"],
-                "message": f"pairing {entry['pair']} is mirror-rejected "
-                           f"with an empty reason string",
-            })
-    path = matrix_path(root)
-    if not os.path.isfile(path):
+    if not os.path.isfile(matrix_path(root)):
         violations.append({
             "kind": "matrix_missing", "where": MATRIX_FILENAME,
             "message": f"checked-in {MATRIX_FILENAME} is missing — run "
                        f"`colearn check --update-matrix`",
         })
     else:
-        with open(path) as f:
-            committed = json.load(f)
+        committed = load_matrix(root)
         if committed != matrix:
             changed = _diff_pairs(committed, matrix)
             violations.append({
                 "kind": "matrix_drift", "where": MATRIX_FILENAME,
                 "message": (
-                    f"checked-in {MATRIX_FILENAME} disagrees with the "
-                    f"code ({len(changed)} pairing(s) changed: "
+                    f"checked-in {MATRIX_FILENAME} disagrees with "
+                    f"validate() ({len(changed)} pairing(s) changed: "
                     f"{', '.join(changed[:5])}"
                     f"{'...' if len(changed) > 5 else ''}) — run "
                     f"`colearn check --update-matrix` and review the diff"
@@ -445,11 +341,10 @@ def check_capability(root: str,
 
 
 def _diff_pairs(old: Dict[str, Any], new: Dict[str, Any]) -> List[str]:
-    def index(m):
-        return {e["pair"]: e for e in m.get("singletons", []) + m.get("pairs", [])}
-
-    oi, ni = index(old), index(new)
-    changed = sorted(
-        p for p in set(oi) | set(ni) if oi.get(p) != ni.get(p)
-    )
+    """Names of what differs: pairings whose verdict or reason changed,
+    then features whose catalog entry changed."""
+    changed = []
+    for key in ("rejected", "features"):
+        o, n = old.get(key, {}), new.get(key, {})
+        changed += sorted(k for k in set(o) | set(n) if o.get(k) != n.get(k))
     return changed or ["<metadata>"]

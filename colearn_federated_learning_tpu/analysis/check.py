@@ -1,8 +1,8 @@
 """`colearn check` orchestration: run all three static analyzers on the
 repo and fold their findings into one violations report (exit 1 names
-each violation; ``--json`` for tooling). Pure host — validate() and the
-engine-compat mirror are plain function calls; nothing initializes a
-jax backend or builds an engine.
+each violation; ``--json`` for tooling). Pure host — validate() is a
+plain function call; nothing initializes a jax backend or builds an
+engine.
 """
 
 from __future__ import annotations
@@ -12,7 +12,7 @@ from typing import Any, Dict, List, Optional
 
 # bump when an analyzer's rules or the matrix schema change — BENCH_r*
 # extras carry this (+ the clean bit) as provenance
-ANALYZER_VERSION = 1
+ANALYZER_VERSION = 2
 
 
 def detect_root(root: Optional[str] = None) -> str:
@@ -93,8 +93,7 @@ def format_report(report: Dict[str, Any]) -> str:
         f"capability: {report['capability']['features']} features, "
         f"{report['capability']['pairs']} pairings "
         f"({report['capability']['supported']} supported / "
-        f"{report['capability']['rejected']} rejected), "
-        f"{report['capability']['drift']} drift",
+        f"{report['capability']['rejected']} rejected)",
         f"seed purity: {report['seed_purity']['files_scanned']} files, "
         f"{report['seed_purity']['findings']} findings, "
         f"{report['seed_purity']['suppressed']} allowlisted",

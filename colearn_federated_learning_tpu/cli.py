@@ -19,7 +19,7 @@ Entry points with capability parity to the reference's
     colearn bench-report       # BENCH_r*.json trajectory + per-phase
                                # budget gates (exit 1 on regression)
     colearn check              # static invariant analyzer: capability
-                               # matrix + mirror drift, seed-purity
+                               # matrix golden pin, seed-purity
                                # lint, JSONL schema cross-check
                                # (exit 1 naming each violation)
     colearn diff <a> <b>       # determinism bisection: align two runs'
@@ -293,7 +293,7 @@ def build_parser():
     ck = sub.add_parser(
         "check",
         help="static invariant analyzer (analysis/): capability-matrix "
-             "extraction + validate()/engine-mirror drift detection, "
+             "extraction from validate() against the checked-in golden, "
              "seed-purity AST lint against the checked-in allowlist, "
              "and the JSONL record-schema emit/consume cross-check — "
              "exits 1 naming each violation (pure host, no backend "
@@ -467,9 +467,9 @@ def main(argv=None):
         return 0
 
     if args.cmd == "check":
-        # static analysis over the repo itself: validate() and the
-        # engine-compat mirror are called as plain functions — no
-        # backend init, no engine construction
+        # static analysis over the repo itself: validate() is called
+        # as a plain function — no backend init, no engine
+        # construction
         from colearn_federated_learning_tpu.analysis import check as _check
 
         try:

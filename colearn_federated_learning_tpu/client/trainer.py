@@ -251,14 +251,11 @@ def make_local_train_fn(model, client_cfg: ClientConfig, dp_cfg: DPConfig, task:
         # GEMM-reassociation tolerance.
         model = _DecomposedLoRA(model)
     # counters of a model with an auxiliary output (models/keye.py);
-    # () for every other model, whose step is the one it always was
+    # () for every other model, whose step is the one it always was.
+    # Such a model trains in the spatial layout, without DP-SGD and
+    # without a batch mesh axis: config.validate() refuses the rest, by
+    # name.
     aux_names = tuple(getattr(model, "aux_counters", ()))
-    if aux_names and (megabatch or batch_axis is not None or dp_cfg.enabled):
-        # config.validate() says so first, by name
-        raise ValueError(
-            "a model with an auxiliary loss trains in the spatial layout, "
-            "without DP-SGD and without a batch mesh axis"
-        )
     grad_fn = jax.value_and_grad(
         make_loss_fn(model, task, with_counters=bool(aux_names)),
         has_aux=bool(aux_names),
@@ -448,8 +445,8 @@ def make_local_train_fn(model, client_cfg: ClientConfig, dp_cfg: DPConfig, task:
         return local_train
 
     if batch_axis is not None:
-        # config.validate() mirrors this: the flattened [C·batch] rows
-        # ARE the axis a batch-sharded mesh splits
+        # read off the engine's mesh: the flattened [C·batch] rows ARE
+        # the axis a batch-sharded mesh splits
         raise ValueError(
             "megabatch local training is incompatible with a batch mesh "
             "axis (run.batch_shards > 1)"

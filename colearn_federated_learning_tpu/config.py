@@ -2174,8 +2174,8 @@ class ExperimentConfig:
                 raise ValueError(
                     f"attack.eps must be >= 0, got {atk.eps}"
                 )
-            # pairing rejections (the _check_engine_compat mirror — each
-            # combination is unsound, not merely unimplemented):
+            # pairing rejections (each combination is unsound, not
+            # merely unimplemented):
             if self.server.secure_aggregation:
                 raise ValueError(
                     "attack simulation is incompatible with "

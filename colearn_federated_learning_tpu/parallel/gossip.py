@@ -108,9 +108,15 @@ def make_gossip_round_fn(model, client_cfg, dp_cfg, task, mesh,
     after local training and before mixing, each compromised client's
     local update ``x_trained − x_pre`` is transformed by the shared
     per-client attack operator (``sign_flip``/``gauss``/``scale``;
-    ``alie`` is rejected — it sizes itself from cohort statistics a
-    decentralized attacker cannot observe) and its replica rewritten to
-    ``x_pre + Δ_attacked``. Honest neighbours then mix the poison in.
+    ``config.validate`` refuses ``alie`` — it sizes itself from cohort
+    statistics a decentralized attacker cannot observe) and its replica
+    rewritten to ``x_pre + Δ_attacked``. Honest neighbours then mix the
+    poison in.
+
+    No ``lr_scale`` is plumbed into ``local_train`` here:
+    ``config.validate`` refuses ``client.lr_decay`` with gossip, as it
+    refuses every other pairing (a factory takes a validated config's
+    values — ``round_engine.make_sharded_round_fn``).
     """
     if topology not in ("ring", "full"):
         raise ValueError(f"unknown gossip topology {topology!r}")
@@ -121,16 +127,6 @@ def make_gossip_round_fn(model, client_cfg, dp_cfg, task, mesh,
 
         if attack not in UPLOAD_ATTACKS:
             raise ValueError(f"unknown upload attack {attack!r}")
-        if attack == "alie":
-            raise ValueError(
-                "attack='alie' is incompatible with gossip (no cohort "
-                "statistics are observable to a decentralized attacker)"
-            )
-    if client_cfg.lr_decay != 1.0:
-        # mirror config.validate(): no lr_scale is plumbed into
-        # local_train here, so decay would be silently dropped for a
-        # direct engine caller (ADVICE r4 #1)
-        raise ValueError("gossip does not support client.lr_decay")
     if not 0.0 < gamma <= 0.5:
         # γ > 1/2 makes the ring weights non-contractive (negative
         # self-weight); γ ≤ 0 is no mixing at all

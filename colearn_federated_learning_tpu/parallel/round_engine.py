@@ -225,267 +225,6 @@ def _fused_stack_inputs(stacked, n_ex, trust, aggregator: str, agg: str,
     return stacked, w / denom
 
 
-def _check_engine_compat(scaffold, aggregator, compression, clip_delta_norm,
-                         secagg=False, feddyn=False, client_dp=0.0,
-                         downlink="", secagg_quant_step=0.0,
-                         error_feedback=False, attack="",
-                         client_ledger=False, reputation=False,
-                         fused_apply=False, cohort_layout="spatial",
-                         example_dp=False, hierarchy=False):
-    """Engine-level mirror of config.validate()'s pairing rejections,
-    SHARED by both engine factories so a direct ``make_*_round_fn``
-    caller can't build an unsound combination that the config layer
-    would have refused (e.g. a scaffold+median engine whose c_global
-    update silently stays a plain poisonable mean). FedDyn's
-    prox_mu injection (and a belt-and-braces copy of its pairing
-    guard) lives in ``_feddyn_prepare``.
-
-    ``example_dp`` is ``dp_cfg.enabled`` as the factories see it — the
-    ``colearn check`` capability extractor (analysis/capability.py)
-    surfaced that the mirror accepted scaffold/feddyn/attack engines
-    built directly with example-level DP while ``validate()`` rejects
-    all three pairings; the flag closes that drift."""
-    robust = aggregator != "weighted_mean"
-    if feddyn and (robust or compression or clip_delta_norm > 0.0):
-        # params would move by the modified deltas while gᵢ/h track the
-        # raw trajectory. Historically guarded only in _feddyn_prepare;
-        # lifted into the shared mirror so the capability extractor's
-        # validate()↔mirror comparison sees one contract surface
-        # (_feddyn_prepare keeps its own guard for direct callers).
-        raise ValueError(
-            "feddyn is incompatible with robust aggregators, "
-            "compression, or delta clipping (the g/h recursion tracks "
-            "raw deltas)"
-        )
-    if example_dp and (scaffold or feddyn):
-        # mirror config.validate(): DP-SGD noise in the local steps
-        # would leak into the persistent c/h state the control-variate
-        # identities assume is a pure function of the deltas
-        raise ValueError(
-            "example-level DP is incompatible with stateful algorithms "
-            "(DP noise would enter the persistent c/h state)"
-        )
-    if example_dp and attack:
-        # mirror config.validate(): the example-level accountant
-        # assumes every client runs the DP-SGD mechanism, which a
-        # Byzantine client does not — the reported epsilon would lie
-        raise ValueError(
-            "attack simulation is incompatible with example-level DP "
-            "(a Byzantine client does not run the DP-SGD mechanism)"
-        )
-    if scaffold and (robust or compression or clip_delta_norm > 0.0):
-        # the c update (c += Σδc/N) has no robust equivalent and the
-        # modified deltas would desynchronize params from the c
-        # trajectory — same reasoning as config.validate()
-        raise ValueError(
-            "scaffold is incompatible with robust aggregators, "
-            "compression, or delta clipping"
-        )
-    if compression == "topk" and robust:
-        # sparse deltas make coordinate-wise order statistics run over
-        # mostly-zero coordinates — statistically meaningless
-        raise ValueError(
-            "compression='topk' (sparse) breaks robust aggregation"
-        )
-    if secagg:
-        if robust or scaffold or feddyn or compression:
-            # masking needs the plain weighted-mean path (see
-            # ServerConfig.secure_aggregation)
-            raise ValueError(
-                "secure aggregation requires the plain weighted-mean "
-                "path (no robust aggregator, stateful algorithm, or "
-                "compression)"
-            )
-        if clip_delta_norm <= 0.0:
-            # without a clip bound the fixed-point values are unbounded
-            # and quantized uploads can exceed int32 range, silently
-            # corrupting the mod-2^32 aggregate
-            raise ValueError(
-                "secure aggregation requires clip_delta_norm > 0"
-            )
-        if secagg_quant_step > 0 and clip_delta_norm / secagg_quant_step >= 2**24:
-            # f32 integer-exactness floor for the quantizer, checked
-            # here so DIRECT engine callers get it too; this covers the
-            # uniform-weight case exactly — under example weights the
-            # driver's resolved-cap check (round_driver.
-            # _check_secagg_bounds) is the authoritative, tighter bound
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "secagg clip/quant_step = %.3g >= 2^24: f32 rounding in "
-                "the fixed-point quantizer can lose integer exactness "
-                "for clients near the clip bound",
-                clip_delta_norm / secagg_quant_step,
-            )
-    if client_dp > 0.0:
-        # mirror config.validate(): the sensitivity analysis holds for
-        # the clipped uniform mean with a fixed denominator only
-        # (ServerConfig.dp_client_noise_multiplier)
-        if robust or scaffold or feddyn or compression:
-            raise ValueError(
-                "client-level DP requires the plain weighted-mean path"
-            )
-        if clip_delta_norm <= 0.0:
-            raise ValueError("client-level DP requires clip_delta_norm > 0")
-    if downlink and (scaffold or feddyn):
-        # state recursions track exact params (config.validate mirror)
-        raise ValueError(
-            "downlink compression supports fedavg/fedprox only"
-        )
-    if error_feedback:
-        if not compression:
-            # EF's whole job is to accumulate what the compressor
-            # dropped; without a compressor the memory is identically 0
-            raise ValueError("error_feedback requires compression")
-        if scaffold or feddyn:
-            # one per-client state store per run — the control-variate
-            # algorithms already own it, and their validate() rules
-            # reject compression anyway
-            raise ValueError(
-                "error_feedback is incompatible with stateful algorithms"
-            )
-        if robust:
-            # EF uploads are history-dependent (this round's message
-            # includes PAST rounds' residuals), so the cohort's messages
-            # mix different effective timescales — coordinate-wise order
-            # statistics over them have no robustness interpretation,
-            # and a Byzantine client's memory is unbounded hidden state
-            raise ValueError(
-                "error_feedback is incompatible with robust aggregators"
-            )
-        if secagg or client_dp > 0.0:
-            # both rely on a per-round norm bound on the upload
-            # (clip_delta_norm); EF uploads C(delta + e) where the
-            # memory e is NOT norm-bounded across rounds, so the
-            # fixed-point range / DP sensitivity analyses don't hold
-            raise ValueError(
-                "error_feedback breaks the per-round upload norm bound "
-                "secure aggregation / client-level DP require"
-            )
-    if attack:
-        # mirror config.validate()'s attack pairing rejections so a
-        # direct engine caller can't build an unsound adversary
-        # simulation (see AttackConfig)
-        from colearn_federated_learning_tpu.server.attacks import (
-            UPLOAD_ATTACKS,
-        )
-
-        if attack not in UPLOAD_ATTACKS:
-            raise ValueError(
-                f"unknown upload attack {attack!r} "
-                f"(label_flip is host-side and never reaches the engine)"
-            )
-        if secagg:
-            raise ValueError(
-                "attack simulation is incompatible with secure "
-                "aggregation (masking hides the uploads the attack "
-                "transform acts on)"
-            )
-        if client_dp > 0.0:
-            raise ValueError(
-                "attack simulation is incompatible with client-level DP "
-                "(a Byzantine upload voids the sensitivity analysis)"
-            )
-        if scaffold or feddyn:
-            raise ValueError(
-                "attack simulation is incompatible with stateful "
-                "algorithms (poisoned uploads enter the persistent c/h "
-                "state through an undefendable plain mean)"
-            )
-        if error_feedback:
-            raise ValueError(
-                "attack simulation is incompatible with error_feedback "
-                "(a Byzantine residual memory is unbounded hidden state)"
-            )
-    if client_ledger:
-        # mirror config.validate()'s client_ledger pairing rejections
-        # so a direct engine caller can't build a forensic ledger over
-        # uploads the protocol hides (or a DP release it would void)
-        if secagg:
-            raise ValueError(
-                "client_ledger is incompatible with secure aggregation "
-                "(per-client upload statistics are what masking hides)"
-            )
-        if client_dp > 0.0:
-            raise ValueError(
-                "client_ledger is incompatible with client-level DP "
-                "(a per-client statistics channel voids the release)"
-            )
-        if scaffold or feddyn:
-            raise ValueError(
-                "client_ledger is not supported with stateful "
-                "algorithms (they own the per-client state path)"
-            )
-    if fused_apply and (scaffold or feddyn):
-        # mirror config.validate(): the stateful algorithms interleave
-        # their c/h recursions with the apply (feddyn bypasses the
-        # server optimizer entirely) — there is no plain delta-apply
-        # chain for the kernel to replace
-        raise ValueError(
-            "fused_apply is incompatible with stateful algorithms "
-            "(they own the server step)"
-        )
-    if cohort_layout not in ("spatial", "megabatch"):
-        raise ValueError(
-            f"unknown cohort_layout {cohort_layout!r}; "
-            f"allowed: spatial | megabatch"
-        )
-    if cohort_layout == "megabatch" and (scaffold or feddyn):
-        # mirror config.validate(): the stateful per-client correction
-        # trees (c − cᵢ / −gᵢ) ride the spatial per-block vmap; the
-        # megabatch block trains from ONE shared weight replica at step
-        # 0 and has no per-client correction slot
-        raise ValueError(
-            "cohort_layout='megabatch' is incompatible with stateful "
-            "algorithms (their per-client correction trees ride the "
-            "spatial per-block scan)"
-        )
-    if reputation and not client_ledger:
-        # mirror config.validate(): the trust weights are a pure
-        # function of the ledger rows — without the ledger there is no
-        # evidence to weight by (and enabling it brings the ledger's
-        # own pairing exclusions, which are exactly reputation's)
-        raise ValueError(
-            "reputation weighting requires client_ledger (trust is "
-            "computed from the device-resident ledger rows)"
-        )
-    if hierarchy:
-        # mirror config.validate()'s server.hierarchy pairing
-        # rejections: the edge tier re-runs this engine per edge over a
-        # sub-population, so any cross-round per-client state or
-        # protocol that assumes ONE flat cohort per round is unsound
-        # when the cohort is split across E independent invocations
-        if scaffold or feddyn:
-            raise ValueError(
-                "hierarchy is incompatible with stateful algorithms "
-                "(the per-client c/h state assumes one flat cohort; "
-                "per-edge invocations would fork the recursion)"
-            )
-        if secagg:
-            raise ValueError(
-                "hierarchy is incompatible with secure aggregation "
-                "(the masking protocol spans one flat cohort; per-edge "
-                "sums would leave edge deltas in the clear anyway)"
-            )
-        if client_dp > 0.0 or example_dp:
-            raise ValueError(
-                "hierarchy is incompatible with DP (the accountant "
-                "assumes one sampling process over the full population, "
-                "not E independent edge cohorts)"
-            )
-        if client_ledger:
-            raise ValueError(
-                "hierarchy is incompatible with client_ledger (the "
-                "device-resident ledger indexes one flat population; "
-                "edge sub-cohorts would alias its rows)"
-            )
-        if error_feedback:
-            raise ValueError(
-                "hierarchy is incompatible with error_feedback (the "
-                "residual memory is keyed by flat cohort slot)"
-            )
-
-
 # fold constant deriving the secure-aggregation mask key from the round
 # rng — MUST be identical in both engines (mask parity is the parity)
 _SECAGG_FOLD = 0x5ECA66
@@ -681,26 +420,17 @@ def _secagg_pairwise_upload(delta_b, b_w, b_slot, b_part, part_full,
     return jax.vmap(one_client)(b_slot, parti, q)
 
 
-def _feddyn_prepare(client_cfg, scaffold, feddyn_alpha, aggregator,
-                    compression, clip_delta_norm):
-    """FedDyn constraint checks + prox_mu=α injection, SHARED by both
-    engine factories so the guards and the injected objective can't
-    drift between the engine and its parity oracle."""
+def _feddyn_prepare(client_cfg, scaffold, feddyn_alpha):
+    """FedDyn's prox_mu=α injection, SHARED by both engine factories so
+    the injected objective can't drift between the engine and its
+    parity oracle. The one check reads the factory's own arguments:
+    ``scaffold`` and ``feddyn_alpha`` are separate keywords here, where
+    a config has one ``algorithm``."""
     feddyn = feddyn_alpha > 0.0
     if not feddyn:
         return False, client_cfg
     if scaffold:
         raise ValueError("scaffold and feddyn are mutually exclusive")
-    if client_cfg.prox_mu:
-        raise ValueError("feddyn injects prox_mu=alpha; set prox_mu=0")
-    if aggregator != "weighted_mean" or compression or clip_delta_norm > 0:
-        # params would move by the modified deltas while gᵢ/h track the
-        # raw trajectory — guard here too so direct engine callers can't
-        # bypass config.validate()
-        raise ValueError(
-            "feddyn is incompatible with robust aggregators, "
-            "compression, or delta clipping"
-        )
     import dataclasses as _dc
 
     return True, _dc.replace(client_cfg, prox_mu=feddyn_alpha)
@@ -762,9 +492,18 @@ def make_sharded_round_fn(model, client_cfg, dp_cfg, task, mesh, server_update,
                           rep_strength: float = 6.0,
                           rep_z_gain: float = 1.0,
                           fused_apply: bool = False,
-                          cohort_layout: str = "spatial",
-                          hierarchy: bool = False):
+                          cohort_layout: str = "spatial"):
     """Build the jitted one-program round function.
+
+    A factory takes a validated config's values; what may be combined
+    is ``ExperimentConfig.validate()``'s to say. ``Experiment`` calls
+    it before it builds an engine, so the factories of this module
+    (and ``parallel/gossip.py``, ``client/trainer.py``,
+    ``server/aggregation.py``) refuse no pairing of features
+    themselves. What they do check is what ``validate()`` cannot see:
+    the mesh's shape against the sizes they were given, an object
+    handed in (``server_update``), and the range or consistency of
+    their own keyword arguments.
 
     ``cohort_layout`` (``run.cohort_layout``): ``"spatial"`` is the
     classic placement — each lane trains its K/L clients in
@@ -785,8 +524,9 @@ def make_sharded_round_fn(model, client_cfg, dp_cfg, task, mesh, server_update,
     unchanged, and megabatch ≡ spatial is parity-pinned across
     aggregators × attacks × EF × fuse_rounds
     (tests/test_round_engine.py). Incompatible with stateful
-    algorithms (``_check_engine_compat``) and batch-sharded meshes
-    (the flattened rows are the axis the batch mesh splits).
+    algorithms (``config.validate``: their per-client correction trees
+    ride the spatial per-block scan) and batch-sharded meshes (the
+    flattened rows are the axis the batch mesh splits).
 
     Signature of the returned fn::
 
@@ -880,7 +620,7 @@ def make_sharded_round_fn(model, client_cfg, dp_cfg, task, mesh, server_update,
     run in-program exactly like scaffold's (zero host sync,
     multi-host capable). Requires ``compression``; incompatible with
     stateful algorithms (store conflict), robust aggregation, secagg,
-    and client-level DP (see ``_check_engine_compat``).
+    and client-level DP (``config.validate`` refuses each).
 
     ``feddyn_alpha`` > 0 activates FedDyn (Acar et al. 2021) on the
     SAME stateful plumbing as scaffold (mutually exclusive): the
@@ -947,16 +687,6 @@ def make_sharded_round_fn(model, client_cfg, dp_cfg, task, mesh, server_update,
     per-coordinate sorts and take the apply-only fusion. Fused ≡
     unfused at f32-reassociation tolerance (tests/test_fused_apply.py).
     """
-    _check_engine_compat(scaffold, aggregator, compression, clip_delta_norm,
-                         secagg=secagg, feddyn=feddyn_alpha > 0.0,
-                         client_dp=client_dp_noise, downlink=downlink,
-                         secagg_quant_step=secagg_quant_step,
-                         error_feedback=error_feedback, attack=attack,
-                         client_ledger=client_ledger,
-                         reputation=reputation, fused_apply=fused_apply,
-                         cohort_layout=cohort_layout,
-                         example_dp=bool(getattr(dp_cfg, "enabled", False)),
-                         hierarchy=hierarchy)
     if fused_apply and not hasattr(server_update, "fused_reduce"):
         # the stacked-path kernel entry lives on the fused server
         # update (make_server_update_fn with cfg.fused_apply) — a
@@ -972,10 +702,7 @@ def make_sharded_round_fn(model, client_cfg, dp_cfg, task, mesh, server_update,
             "client-level DP requires uniform aggregation weights "
             "(the driver selects them automatically)"
         )
-    feddyn, client_cfg = _feddyn_prepare(
-        client_cfg, scaffold, feddyn_alpha, aggregator, compression,
-        clip_delta_norm,
-    )
+    feddyn, client_cfg = _feddyn_prepare(client_cfg, scaffold, feddyn_alpha)
     batch_sharded = has_batch_axis(mesh)
     if batch_sharded and client_cfg.batch_size % mesh.shape[BATCH_AXIS]:
         raise ValueError(
@@ -984,8 +711,8 @@ def make_sharded_round_fn(model, client_cfg, dp_cfg, task, mesh, server_update,
         )
     megabatch = cohort_layout == "megabatch"
     if megabatch and batch_sharded:
-        # mirror config.validate(): the flattened [K_local·batch] rows
-        # ARE the axis the batch mesh shards
+        # read off the mesh: the flattened [K_local·batch] rows ARE the
+        # axis the batch mesh shards
         raise ValueError(
             "cohort_layout='megabatch' is incompatible with a "
             "batch-sharded mesh (run.batch_shards > 1)"
@@ -1020,20 +747,15 @@ def make_sharded_round_fn(model, client_cfg, dp_cfg, task, mesh, server_update,
     # per-client store (stateful algorithms carry c_global + the dc psum
     # on top of it; error feedback only the store itself)
     use_store = stateful or error_feedback
-    if fuse_rounds > 1 and (stateful or secagg):
-        # scaffold/feddyn's c_global recursion is rejected by
-        # config.validate (algorithm pairing); secagg's pairwise seed
-        # matrices are per-round host PROTOCOL outputs (DH agreement +
-        # Shamir recovery of the realized dropout set) that cannot be
-        # precomputed into a stacked scan input. Robust aggregators,
-        # upload attacks, and error feedback all fuse: the per-client
-        # delta stack stays private to the scan body, byzantine masks
-        # become [fuse, K] scan inputs, and the EF store rides the scan
-        # carry (mirrors config.validate).
-        raise ValueError(
-            "fuse_rounds > 1 is incompatible with stateful algorithms "
-            "and secure aggregation"
-        )
+    # fuse_rounds > 1 arrives without stateful algorithms and without
+    # secure aggregation (config.validate): scaffold/feddyn's c_global
+    # recursion has no fused form, and secagg's pairwise seed matrices
+    # are per-round host PROTOCOL outputs (DH agreement + Shamir
+    # recovery of the realized dropout set) that cannot be precomputed
+    # into a stacked scan input. Robust aggregators, upload attacks,
+    # and error feedback all fuse: the per-client delta stack stays
+    # private to the scan body, byzantine masks become [fuse, K] scan
+    # inputs, and the EF store rides the scan carry.
     if use_store and num_clients <= 0:
         raise ValueError("per-client state requires num_clients")
     if aggregator not in ("weighted_mean", "median", "trimmed_mean", "krum"):
@@ -2061,14 +1783,6 @@ def make_async_round_fn(model, client_cfg, dp_cfg, task, mesh, server_update,
             f"clients per lane"
         )
     use_decay = client_cfg.lr_decay != 1.0
-    if reputation and not client_ledger:
-        # mirror config.validate(): trust weights are a pure function
-        # of the ledger rows — without the ledger there is no evidence
-        raise ValueError(
-            "reputation weighting requires client_ledger (trust is "
-            "computed from the device-resident ledger rows)"
-        )
-
     if client_ledger:
         # Per-insert stats path: the lane emits the buffer's [K, ...]
         # per-client delta stack (client-sharded) instead of the
@@ -2354,8 +2068,7 @@ def make_sequential_round_fn(model, client_cfg, dp_cfg, task, server_update,
                              rep_floor: float = 0.05,
                              rep_strength: float = 6.0,
                              rep_z_gain: float = 1.0,
-                             fused_apply: bool = False,
-                             cohort_layout: str = "spatial"):
+                             fused_apply: bool = False):
     """Reference-semantics engine: python loop over the cohort, jitted
     per-client local training, host-side weighted mean. Used for
     single-device debugging and as the parity oracle the shard_map
@@ -2372,21 +2085,13 @@ def make_sequential_round_fn(model, client_cfg, dp_cfg, task, server_update,
     ``ledger`` + ``ledger_ids`` and returns the updated ledger before
     the metrics, built from the SAME shared stats/update helpers
     (obs/ledger.py) over the same wire-upload stack.
-    ``cohort_layout`` is accepted for signature symmetry and validated
-    through the shared compat mirror, but the oracle itself is
-    layout-free: the python loop IS the reference semantics both
-    layouts must reproduce."""
+    There is no ``cohort_layout``: the oracle is layout-free — the
+    python loop IS the reference semantics both layouts must
+    reproduce. What may be combined is
+    ``ExperimentConfig.validate()``'s to say
+    (:func:`make_sharded_round_fn`)."""
     if agg not in ("examples", "uniform"):
         raise ValueError(f"unknown aggregation mode {agg!r}")
-    _check_engine_compat(scaffold, aggregator, compression, clip_delta_norm,
-                         secagg=secagg, feddyn=feddyn_alpha > 0.0,
-                         client_dp=client_dp_noise, downlink=downlink,
-                         secagg_quant_step=secagg_quant_step,
-                         error_feedback=error_feedback, attack=attack,
-                         client_ledger=client_ledger,
-                         reputation=reputation, fused_apply=fused_apply,
-                         cohort_layout=cohort_layout,
-                         example_dp=bool(getattr(dp_cfg, "enabled", False)))
     if fused_apply and not hasattr(server_update, "fused_reduce"):
         raise ValueError(
             "fused_apply=True requires a server_update built by "
@@ -2397,10 +2102,7 @@ def make_sequential_round_fn(model, client_cfg, dp_cfg, task, server_update,
             "client-level DP requires uniform aggregation weights "
             "(the driver selects them automatically)"
         )
-    feddyn, client_cfg = _feddyn_prepare(
-        client_cfg, scaffold, feddyn_alpha, aggregator, compression,
-        clip_delta_norm,
-    )
+    feddyn, client_cfg = _feddyn_prepare(client_cfg, scaffold, feddyn_alpha)
     stateful = scaffold or feddyn
     if stateful and num_clients <= 0:
         raise ValueError("stateful algorithms require num_clients")

@@ -220,8 +220,9 @@ def make_server_update_fn(cfg: ServerConfig):
     STRUCTURE is kept bit-for-bit (``(TraceState, EmptyState)`` /
     ``(EmptyState, EmptyState)``), so fused and unfused runs checkpoint-
     interoperate; only ``mean`` / ``fedavgm`` are expressible as the
-    kernel's single FMA chain (validate() enforces it; this factory
-    guards direct callers). The returned ``update`` additionally carries
+    kernel's single FMA chain — fedadam/fedyogi carry second-moment
+    state the one-pass kernel does not model (validate() refuses
+    them). The returned ``update`` additionally carries
     a ``fused_reduce(params, opt_state, wire_stack, weights)`` attribute
     — the stacked-path entry the engines use to fuse trust/weight
     scaling → weighted reduction → apply → optimizer into the same
@@ -232,14 +233,6 @@ def make_server_update_fn(cfg: ServerConfig):
     """
     opt = make_server_optimizer(cfg)
     fused = getattr(cfg, "fused_apply", False)
-    if fused and cfg.optimizer not in ("mean", "fedavgm"):
-        # mirror of config.validate() for direct callers: fedadam/
-        # fedyogi carry second-moment state the one-pass kernel does
-        # not model
-        raise ValueError(
-            "server.fused_apply supports optimizer='mean' or 'fedavgm' "
-            f"only, got {cfg.optimizer!r}"
-        )
 
     def init(params) -> Any:
         return {"round": jnp.zeros((), jnp.int32), "opt": opt.init(params)}

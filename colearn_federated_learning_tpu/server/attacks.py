@@ -39,9 +39,8 @@ labels* are flipped ``y → (C−1) − y`` in the host data path before the
 corpus is placed (data poisoning; the upload itself is an honest
 gradient of poisoned data). No engine involvement.
 
-Config-level pairing rules (which combinations are rejected and why)
-live in config.validate(); the engine-level mirror is
-round_engine._check_engine_compat.
+Pairing rules (which combinations are rejected and why) live in
+config.validate(), and nowhere else.
 """
 
 from __future__ import annotations

@@ -547,7 +547,6 @@ class Experiment:
                         rep_strength=cfg.server.reputation.strength,
                         rep_z_gain=cfg.server.reputation.z_gain,
                         fused_apply=cfg.server.fused_apply,
-                        hierarchy=self._hier,
                         # hierarchy re-dispatches the SAME params/opt
                         # buffers once per edge — donation would delete
                         # them after the first edge's call; the device
@@ -576,7 +575,6 @@ class Experiment:
             self.round_fn = make_sequential_round_fn(
                 self.model, cfg.client, cfg.dp, self.task, server_update,
                 dp_fixed_denom=cfg.server.cohort_size,
-                cohort_layout=cfg.run.cohort_layout,
                 local_dtype=self._local_dtype(), agg=agg,
                 scaffold=self.scaffold, num_clients=self.fed.num_clients,
                 aggregator=cfg.server.aggregator,

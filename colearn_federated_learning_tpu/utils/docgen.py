@@ -351,20 +351,19 @@ def _model_kwargs_section(name: str, blurb: str):
 def capability_matrix_appendix() -> str:
     """Auto-generated pairing-matrix appendix, sourced from the
     checked-in ``capability_matrix.json`` (`colearn check` extracts it
-    from validate() + the engine-compat mirror; analysis/capability.py).
-    Only the rejected pairings are tabled — the artifact carries the
-    full space. Empty string when the artifact is absent (fresh
-    checkouts before the first `colearn check --update-matrix`)."""
-    import json
+    from validate(); analysis/capability.py). The artifact lists the
+    rejected pairings; every other pairing validates. Empty string
+    when the artifact is absent (fresh checkouts before the first
+    `colearn check --update-matrix`)."""
     import os
 
-    root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    path = os.path.join(root, "capability_matrix.json")
-    if not os.path.isfile(path):
+    from colearn_federated_learning_tpu.analysis import capability
+    from colearn_federated_learning_tpu.analysis.check import detect_root
+
+    root = detect_root()
+    if not os.path.isfile(capability.matrix_path(root)):
         return ""
-    with open(path) as f:
-        matrix = json.load(f)
+    matrix = capability.load_matrix(root)
     c = matrix["counts"]
     lines = [
         "## Appendix: capability pairing matrix",
@@ -373,18 +372,15 @@ def capability_matrix_appendix() -> str:
         f"{matrix['version']}; regenerate with `colearn check "
         f"--update-matrix`): {c['features']} features x {c['pairs']} "
         f"pairings — {c['supported']} supported, {c['rejected']} "
-        f"rejected with reasons, {c['drift']} validate()/engine-mirror "
-        f"drift. The rejected pairings:",
+        f"rejected by `validate()` with reasons. The rejected pairings:",
         "",
         "| pairing | reason |",
         "|---|---|",
     ]
-    for entry in matrix["pairs"]:
-        if entry["validate"] == "rejected":
-            reason = entry.get("reason", "").replace("|", "\\|")
-            reason = " ".join(reason.split())
-            if len(reason) > 140:
-                reason = reason[:137] + "..."
-            lines.append(f"| `{entry['pair']}` | {reason} |")
+    for pair, reason in matrix["rejected"].items():
+        reason = " ".join(reason.replace("|", "\\|").split())
+        if len(reason) > 140:
+            reason = reason[:137] + "..."
+        lines.append(f"| `{pair}` | {reason} |")
     lines.append("")
     return "\n".join(lines)

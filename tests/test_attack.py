@@ -290,34 +290,6 @@ def test_upload_attacks_compose_with_fused_rounds():
         cfg.validate()
 
 
-def test_engine_rejects_unsound_attack_combinations():
-    model, params, x, y, idx, mask, n_ex = _setup(cohort=8)
-    ccfg = ClientConfig(local_epochs=1, batch_size=8, lr=0.1)
-    _, server_update = make_server_update_fn(ServerConfig(cohort_size=8))
-    with pytest.raises(ValueError, match="secure"):
-        make_sequential_round_fn(
-            model, ccfg, DPConfig(), "classify", server_update,
-            attack="sign_flip", secagg=True, clip_delta_norm=1.0,
-        )
-    with pytest.raises(ValueError, match="label_flip"):
-        make_sequential_round_fn(
-            model, ccfg, DPConfig(), "classify", server_update,
-            attack="label_flip",
-        )
-    with pytest.raises(ValueError, match="stateful"):
-        make_sequential_round_fn(
-            model, dataclass_replace(ccfg, momentum=0.0), DPConfig(),
-            "classify", server_update, attack="gauss", scaffold=True,
-            num_clients=8,
-        )
-
-
-def dataclass_replace(dc, **kw):
-    import dataclasses
-
-    return dataclasses.replace(dc, **kw)
-
-
 def test_cli_style_override_builds_attacked_experiment():
     """`--set attack.kind=sign_flip` reaches the driver: compromised set
     constructed, engines built with the attack wired in."""

@@ -792,18 +792,15 @@ def test_trough_floor_keeps_probability_at_min_availability():
 
 def test_capability_matrix_records_the_fedbuff_flips():
     with open(os.path.join(_ROOT, "capability_matrix.json")) as f:
-        matrix = json.load(f)
-    assert matrix["counts"]["drift"] == 0
-    pairs = {p["pair"]: p for p in matrix["pairs"]}
+        rejected = json.load(f)["rejected"]
     for flipped in ("client_ledger+fedbuff", "fedbuff+reputation",
                     "fedbuff+sampling_streaming_ledger",
                     "fedbuff+stream_placement"):
-        assert pairs[flipped]["validate"] == "ok", pairs[flipped]
+        assert flipped not in rejected, rejected[flipped]
     # the genuinely-unsound neighbours stayed rejected, with reasons
     for still in ("fedbuff+paged_ledger", "churn+gossip",
                   "churn+shape_buckets", "churn+sampling_poisson"):
-        assert pairs[still]["validate"] == "rejected"
-        assert pairs[still].get("reason"), pairs[still]
+        assert rejected[still].strip(), still
 
 
 def test_seed_purity_lint_covers_churn_module():

@@ -413,33 +413,6 @@ def test_gossip_rejects_ledger():
         cfg.validate()
 
 
-def test_engine_compat_mirror_rejects_unsound_ledger():
-    from colearn_federated_learning_tpu.config import (
-        ClientConfig,
-        DPConfig,
-        ServerConfig,
-    )
-    from colearn_federated_learning_tpu.parallel.round_engine import (
-        make_sequential_round_fn,
-    )
-    from colearn_federated_learning_tpu.server.aggregation import (
-        make_server_update_fn,
-    )
-
-    _, update = make_server_update_fn(ServerConfig(cohort_size=4))
-    with pytest.raises(ValueError, match="secure aggregation"):
-        make_sequential_round_fn(
-            None, ClientConfig(), DPConfig(), "classify", update,
-            client_ledger=True, secagg=True, clip_delta_norm=1.0,
-        )
-    with pytest.raises(ValueError, match="client-level DP"):
-        make_sequential_round_fn(
-            None, ClientConfig(momentum=0.0), DPConfig(), "classify",
-            update, client_ledger=True, client_dp_noise=1.0,
-            clip_delta_norm=1.0, agg="uniform",
-        )
-
-
 # ---------------------------------------------------------------------------
 # paged ledger (run.obs.client_ledger.hot_capacity): [hot, 7] device hot
 # set + host mmap cold spill — merged view bitwise-equal to dense

@@ -341,23 +341,3 @@ def test_ef_config_validation():
     cfg3.client.momentum = 0.0
     with pytest.raises(ValueError, match="error_feedback|scaffold"):
         cfg3.validate()
-
-
-def test_ef_engine_compat_direct_callers():
-    """Direct make_*_round_fn callers get the same rejections as the
-    config layer (_check_engine_compat mirror)."""
-    model, _, *_ = _setup(cohort=2, n=64)
-    ccfg = ClientConfig(local_epochs=1, batch_size=8, lr=0.1)
-    scfg = ServerConfig(optimizer="mean", server_lr=1.0, cohort_size=2)
-    _, supd = make_server_update_fn(scfg)
-    with pytest.raises(ValueError, match="requires compression"):
-        make_sequential_round_fn(
-            model, ccfg, DPConfig(), "classify", supd, error_feedback=True,
-        )
-    # scaffold's own compression rejection fires first — either guard
-    # refuses the store conflict
-    with pytest.raises(ValueError, match="stateful|scaffold is incompatible"):
-        make_sequential_round_fn(
-            model, ccfg, DPConfig(), "classify", supd, error_feedback=True,
-            compression="qsgd", scaffold=True, num_clients=4,
-        )

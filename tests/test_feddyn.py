@@ -197,22 +197,3 @@ def test_feddyn_config_validation():
     cfg.run.param_dtype = "bfloat16"
     with pytest.raises(ValueError, match="f32 local"):
         cfg.validate()
-
-
-def test_feddyn_engine_rejects_incompatible_features():
-    model = build_model("lenet5", num_classes=10)
-    _, server_update = make_server_update_fn(
-        ServerConfig(optimizer="mean", server_lr=1.0, cohort_size=4)
-    )
-    with pytest.raises(ValueError, match="incompatible"):
-        make_sharded_round_fn(
-            model, ClientConfig(momentum=0.0), DPConfig(), "classify",
-            build_client_mesh(4), server_update, cohort_size=4, donate=False,
-            num_clients=8, feddyn_alpha=0.1, aggregator="median",
-        )
-    with pytest.raises(ValueError, match="incompatible"):
-        make_sequential_round_fn(
-            model, ClientConfig(momentum=0.0), DPConfig(), "classify",
-            server_update, num_clients=8, feddyn_alpha=0.1,
-            compression="qsgd",
-        )

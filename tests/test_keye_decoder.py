@@ -332,9 +332,6 @@ def test_local_metrics_carry_the_counters_and_only_for_this_model(setup):
         build_model("bert_tiny", 0, **_BERT), ClientConfig(), DPConfig(),
         "lm")
     assert plain.aux_names == ()
-    with pytest.raises(ValueError, match="auxiliary loss"):
-        make_local_train_fn(model, ClientConfig(), DPConfig(), "lm",
-                            megabatch=True)
 
 
 @pytest.mark.parametrize("override,named", [

@@ -25,13 +25,19 @@ from colearn_federated_learning_tpu.server.aggregation import robust_reduce
 _SETTINGS = dict(max_examples=25, deadline=None)
 
 
+def _clients_and_examples(max_clients, min_n, max_n):
+    """(clients, n) with at least one example per client: iid_partition
+    (and silo_partition through it) refuses fewer, by design — an empty
+    shard would only surface rounds later."""
+    return st.integers(1, max_clients).flatmap(
+        lambda c: st.tuples(st.just(c), st.integers(max(min_n, c), max_n)))
+
+
 @settings(**_SETTINGS)
-@given(
-    n=st.integers(8, 400),
-    clients=st.integers(1, 16),
-    seed=st.integers(0, 2**31 - 1),
-)
-def test_iid_partition_is_a_partition(n, clients, seed):
+@given(clients_n=_clients_and_examples(16, 8, 400),
+       seed=st.integers(0, 2**31 - 1))
+def test_iid_partition_is_a_partition(clients_n, seed):
+    clients, n = clients_n
     shards = iid_partition(n, clients, seed)
     allv = np.concatenate(shards)
     assert len(allv) == n
@@ -55,9 +61,10 @@ def test_dirichlet_partition_is_a_partition(clients, classes, alpha, seed):
 
 
 @settings(**_SETTINGS)
-@given(n=st.integers(4, 300), clients=st.integers(1, 8),
+@given(clients_n=_clients_and_examples(8, 4, 300),
        seed=st.integers(0, 2**31 - 1))
-def test_silo_partition_is_balanced_partition(n, clients, seed):
+def test_silo_partition_is_balanced_partition(clients_n, seed):
+    clients, n = clients_n
     shards = silo_partition(n, clients, seed)
     allv = np.concatenate(shards)
     assert len(np.unique(allv)) == len(allv) == n

@@ -104,27 +104,6 @@ def test_reputation_knob_ranges(key, value, match):
         cfg.validate()
 
 
-def test_engine_compat_mirror_rejects_reputation_without_ledger():
-    from colearn_federated_learning_tpu.config import (
-        ClientConfig,
-        DPConfig,
-        ServerConfig,
-    )
-    from colearn_federated_learning_tpu.parallel.round_engine import (
-        make_sequential_round_fn,
-    )
-    from colearn_federated_learning_tpu.server.aggregation import (
-        make_server_update_fn,
-    )
-
-    _, update = make_server_update_fn(ServerConfig(cohort_size=4))
-    with pytest.raises(ValueError, match="reputation.*ledger"):
-        make_sequential_round_fn(
-            None, ClientConfig(), DPConfig(), "classify", update,
-            reputation=True,
-        )
-
-
 # ---------------------------------------------------------------------------
 # driver e2e: off-identity + engine/fusion parity with reputation ON
 # ---------------------------------------------------------------------------

@@ -281,37 +281,6 @@ def test_batch_shards_must_divide_batch():
                               server_update, 8, donate=False)
 
 
-def test_engine_mirrors_config_incompatibility_guards():
-    """A direct make_*_round_fn caller must not be able to build the
-    unsound combinations config.validate() rejects (ADVICE r2): a
-    scaffold+robust engine's c_global update would silently stay a
-    plain poisonable mean, and topk-sparse deltas break coordinate-wise
-    order statistics."""
-    from colearn_federated_learning_tpu.parallel.round_engine import (
-        make_sequential_round_fn,
-        make_sharded_round_fn,
-    )
-
-    mesh = build_client_mesh(8)
-    bad = [
-        dict(scaffold=True, num_clients=4, aggregator="median"),
-        dict(scaffold=True, num_clients=4, compression="topk"),
-        dict(scaffold=True, num_clients=4, clip_delta_norm=1.0),
-        dict(compression="topk", aggregator="median"),
-    ]
-    for kw in bad:
-        with pytest.raises(ValueError):
-            make_sharded_round_fn(
-                None, ClientConfig(), DPConfig(), "classify", mesh,
-                lambda p, s, d: (p, s), cohort_size=8, **kw,
-            )
-        with pytest.raises(ValueError):
-            make_sequential_round_fn(
-                None, ClientConfig(), DPConfig(), "classify",
-                lambda p, s, d: (p, s), **kw,
-            )
-
-
 class TestFusedRounds:
     """run.fuse_rounds=F: F rounds as one XLA program (lax.scan over
     the round body with the unfused loop's EXACT per-round rngs)."""
@@ -738,7 +707,6 @@ class TestCohortLayout:
         _, server_update = make_server_update_fn(scfg)
         seq = make_sequential_round_fn(
             model, ccfg, DPConfig(), "classify", server_update,
-            cohort_layout="megabatch",
         )
         rng = jax.random.PRNGKey(21)
         p_mb, _, m_mb = fns["megabatch"](params, opt_state, *args, rng)
@@ -791,7 +759,7 @@ class TestCohortLayout:
                                 cohort_size=8)
             seq = make_sequential_round_fn(
                 model, ccfg, DPConfig(), "classify",
-                make_server_update_fn(scfg)[1], cohort_layout="megabatch",
+                make_server_update_fn(scfg)[1],
             )
             p_sq, _, _ = seq(params, opt_state, *ins)
             self._assert_layout_parity(p_sq, p_mb)
@@ -836,9 +804,6 @@ class TestCohortLayout:
 
     def test_validation_and_engine_rejections(self):
         from colearn_federated_learning_tpu.config import get_named_config
-        from colearn_federated_learning_tpu.parallel.round_engine import (
-            _check_engine_compat,
-        )
 
         cfg = get_named_config("mnist_fedavg_2")
         cfg.run.cohort_layout = "megablotch"
@@ -868,13 +833,6 @@ class TestCohortLayout:
             cfg.run.cohort_layout = "megabatch"
             cfg.run.client_vmap_width = w
             cfg.validate()
-        # the engine-level mirror guards direct factory callers
-        with pytest.raises(ValueError, match="cohort_layout"):
-            _check_engine_compat(False, "weighted_mean", "", 0.0,
-                                 cohort_layout="megablotch")
-        with pytest.raises(ValueError, match="stateful"):
-            _check_engine_compat(True, "weighted_mean", "", 0.0,
-                                 cohort_layout="megabatch")
         # megabatch × batch-sharded mesh is rejected at construction
         model = build_model("lenet5", num_classes=10)
         ccfg = ClientConfig(local_epochs=1, batch_size=8, lr=0.1)

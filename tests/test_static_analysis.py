@@ -1,7 +1,7 @@
 """Static invariant analyzer (`colearn check`, analysis/): seed-purity
 lint positives/negatives on fixture snippets + the allowlist contract,
-capability-matrix golden pin + seeded mirror/matrix drift (exit 1 names
-the pairing), JSONL schema registry static cross-checks + seeded
+capability-matrix golden pin, feature by feature, + a tampered matrix
+(exit 1 names the pairing), JSONL schema registry static cross-checks + seeded
 emitter/consumer violations (file:line), registry completeness against
 a live tiny-fit run's JSONL, the converted bare-assert pin, and the
 tier-1 `colearn check` CLI smoke (ISSUE 13)."""
@@ -130,86 +130,57 @@ def test_converted_assert_raises_typed_exception():
 
 
 # ---------------------------------------------------------------------------
-# capability matrix: golden pin, drift detection, artifact contract
+# capability matrix: golden pin, artifact contract
 # ---------------------------------------------------------------------------
 
 
-def test_capability_matrix_golden_pin():
-    """The checked-in artifact IS the code's matrix (any validate()/
-    mirror change must land with its regenerated matrix diff)."""
-    with open(os.path.join(_ROOT, capability.MATRIX_FILENAME)) as f:
-        committed = json.load(f)
-    assert capability.extract_matrix() == committed
+@pytest.mark.parametrize("feature", sorted(capability.FEATURES))
+def test_capability_matrix_golden_pin(feature):
+    """The checked-in artifact IS what validate() says, feature by
+    feature: ``feature`` alone validates, and the partners validate()
+    refuses with it — and each reason, to the letter — are the golden's.
+    A changed pairing rule fails the cases of the features it touches
+    (and must land with its regenerated matrix diff)."""
+    golden = capability.load_matrix(_ROOT)
+    spec = capability.FEATURES[feature]
+    assert golden["features"][feature] == {
+        "overrides": spec.overrides, "note": spec.note}
+    alone, refused = capability.feature_verdicts(feature)
+    assert alone is None, f"{feature} alone does not validate: {alone}"
+    expected = {}
+    for pair, reason in golden["rejected"].items():
+        a, b = pair.split("+")
+        if feature in (a, b):
+            expected[b if a == feature else a] = reason
+    assert refused == expected
+    # a rejection has a reason
+    assert all(reason.strip() for reason in refused.values()), refused
 
 
-def test_capability_matrix_no_drift_and_reasons_everywhere():
-    matrix = capability.extract_matrix()
-    assert matrix["counts"]["drift"] == 0
-    for entry in matrix["singletons"] + matrix["pairs"]:
-        assert not entry["drift"], entry
-        if entry["validate"] == "rejected":
-            assert entry.get("reason", "").strip(), entry
-        if entry["mirror"] == "rejected":
-            assert entry.get("mirror_reason", "").strip(), entry
-    # the PR 6-12 clause families are all represented in the matrix
-    rejected = {e["pair"] for e in matrix["pairs"]
-                if e["validate"] == "rejected"}
-    for pair in (
-        "attack_sign_flip+secagg",
-        "attack_sign_flip+client_dp",
-        "attack_label_flip+client_store",
-        "client_store+native_pipeline",
-        "error_feedback+paged_ledger",
-        "sampling_adaptive+shape_buckets",
-        "fuse_rounds+secagg",
-        "megabatch+scaffold",
-        # client_ledger+fedbuff flipped to SUPPORTED in the churn PR
-        # (per-insert stats); the ledger clause family is now
-        # represented by its still-unsound members
-        "client_ledger+gossip",
-        "fedbuff+paged_ledger",
-        "churn+gossip",
-    ):
-        assert pair in rejected, pair
-
-
-def test_capability_reconciled_pairs_now_mirror_rejected():
-    """The mirror-drift satellite: the pairings the extractor surfaced
-    (example-DP × scaffold/feddyn/attack, feddyn × robust) are rejected
-    by BOTH layers now, with reasons."""
-    matrix = capability.extract_matrix()
-    entries = {e["pair"]: e for e in matrix["pairs"]}
-    for pair in ("example_dp+scaffold", "example_dp+feddyn",
-                 "attack_sign_flip+example_dp", "feddyn+robust_krum",
-                 "compression_qsgd+feddyn"):
-        e = entries[pair]
-        assert e["validate"] == "rejected" and e["mirror"] == "rejected", e
-
-
-def test_seeded_mirror_drift_is_detected_naming_the_pairing():
-    """Drift failure mode #1: a permissive mirror (accepts everything)
-    must light up every enforceable rejected pairing by name."""
-    report = capability.check_capability(_ROOT,
-                                         mirror_fn=lambda **kw: None)
-    drift = [v for v in report["violations"] if v["kind"] == "mirror_drift"]
-    assert drift, "permissive mirror produced no drift"
-    named = {v["where"] for v in drift}
-    assert "attack_sign_flip+secagg" in named
-    assert "example_dp+scaffold" in named
-    for v in drift:
-        assert v["where"] in v["message"] or v["message"]
+def test_capability_matrix_names_only_catalog_features():
+    """The other direction of the pin: the golden holds no feature and
+    no pairing the catalog has dropped."""
+    golden = capability.load_matrix(_ROOT)
+    assert sorted(golden["features"]) == sorted(capability.FEATURES)
+    for pair in golden["rejected"]:
+        a, b = pair.split("+")
+        assert a < b and {a, b} <= set(capability.FEATURES), pair
+    counts = golden["counts"]
+    assert counts["rejected"] == len(golden["rejected"])
+    assert counts["supported"] + counts["rejected"] == counts["pairs"]
 
 
 def test_tampered_matrix_fails_naming_the_pairing(tmp_path):
-    """Drift failure mode #2 (artifact drift): a checked-in matrix that
-    disagrees with the code exits 1 through the CLI, naming the changed
-    pairing. The tmp repo root symlinks the real package so all three
-    analyzers run for real."""
-    with open(os.path.join(_ROOT, capability.MATRIX_FILENAME)) as f:
-        matrix = json.load(f)
-    victim = next(p for p in matrix["pairs"]
-                  if p["validate"] == "rejected")
-    victim["validate"] = "ok"
+    """A checked-in matrix that disagrees with validate() — one pairing
+    flipped each way — exits 1 through the CLI, naming both pairings.
+    The tmp repo root symlinks the real package so all three analyzers
+    run for real."""
+    matrix = capability.load_matrix(_ROOT)
+    victim = next(iter(matrix["rejected"]))
+    del matrix["rejected"][victim]  # the golden now says: supported
+    planted = "attack_alie+batch_shards"
+    assert planted not in matrix["rejected"]
+    matrix["rejected"][planted] = "no such rule"
     os.symlink(os.path.join(_ROOT, "colearn_federated_learning_tpu"),
                tmp_path / "colearn_federated_learning_tpu")
     with open(tmp_path / capability.MATRIX_FILENAME, "w") as f:
@@ -218,7 +189,8 @@ def test_tampered_matrix_fails_naming_the_pairing(tmp_path):
     assert not report["clean"]
     drift = [v for v in report["violations"] if v["kind"] == "matrix_drift"]
     assert len(drift) == 1
-    assert victim["pair"] in drift[0]["message"]
+    assert victim in drift[0]["message"]
+    assert planted in drift[0]["message"]
 
     from colearn_federated_learning_tpu import cli
 
@@ -367,7 +339,6 @@ def test_live_tiny_fit_jsonl_is_fully_registered(tmp_path):
 def test_run_check_clean_on_repo():
     report = check_mod.run_check(_ROOT)
     assert report["clean"], report["violations"]
-    assert report["capability"]["drift"] == 0
     assert report["analyzer_version"] == check_mod.ANALYZER_VERSION
     text = check_mod.format_report(report)
     assert "OK — no violations" in text
