@@ -373,6 +373,57 @@ def check_selected_attention() -> None:
                        ATTN_TOL["bfloat16"])
 
 
+def check_index_scores() -> None:
+    """``index_scores``' two kernels, natively, at the Keye decoder's
+    widths (a chunk of 512 queries over 8,192 keys, 16 heads of 64, one
+    key head, bf16): the scores and the three gradients against the
+    dense ``jnp`` form, whose ``[16, 512, 8192]`` float32 per-head
+    scores are an array, on the same bf16 inputs; and the sign of a
+    score whose terms are all ``-0.0``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from colearn_federated_learning_tpu.ops.sparse_attention import (
+        index_scores,
+    )
+
+    tq, tk, heads, hd = 512, 8192, 16, 64
+    ks = jax.random.split(jax.random.PRNGKey(28), 4)
+    q_idx = jax.random.normal(ks[0], (tq, heads, hd), jnp.bfloat16)
+    k_idx = jax.random.normal(ks[1], (tk, hd), jnp.bfloat16)
+    w_idx = jax.random.normal(ks[2], (tq, heads), jnp.float32)
+    ct = jax.random.normal(ks[3], (tq, tk), jnp.float32)
+
+    def dense(q_idx, k_idx, w_idx):
+        dots = jnp.einsum("qjd,kd->jqk", q_idx, k_idx,
+                          preferred_element_type=jnp.float32)
+        w = w_idx.T[:, :, None] * jnp.float32(hd ** -0.5 * heads ** -0.5)
+        return (jax.nn.relu(dots) * w).sum(0) + 0.0
+
+    def both(fn):
+        def loss(q_idx, k_idx, w_idx):
+            scores = fn(q_idx, k_idx, w_idx)
+            return (scores * ct).sum(), scores
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2), has_aux=True))
+
+    (_, scores), grads = both(index_scores)(q_idx, k_idx, w_idx)
+    (_, want), want_g = both(dense)(q_idx, k_idx, w_idx)
+    # every dot negative, every weight negative: each term is -0.0, and
+    # the selection's bit patterns need the sum to be +0.0
+    zeros = np.asarray(jax.jit(index_scores)(
+        -jnp.abs(q_idx), jnp.abs(k_idx), -jnp.abs(w_idx)))
+    if zeros.any() or np.signbit(zeros).any():
+        raise RuntimeError("index_scores: a score of -0.0, or not 0 at all")
+    _require_close("index_scores", scores, want, 1e-5)
+    for name, g, w in zip(("dq_idx", "dk_idx", "dw_idx"), grads, want_g):
+        scale = float(jnp.abs(w.astype(jnp.float32)).max())
+        _require_close(f"index_scores {name} / {scale:.3g}",
+                       g.astype(jnp.float32) / scale,
+                       w.astype(jnp.float32) / scale,
+                       ATTN_TOL["bfloat16"])
+
+
 def main() -> int:
     t_start = time.time()
     # (a) the compile cache, before the first compile
@@ -426,6 +477,7 @@ def main() -> int:
     check_pallas_apply(params, k=16)
     check_flash_attention()
     check_selected_attention()
+    check_index_scores()
 
     say(f"total wall {time.time() - t_start:.1f}s")
     print(json.dumps({"ok": True, "device": {

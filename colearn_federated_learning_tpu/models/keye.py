@@ -35,9 +35,13 @@ masks, attention's outputs, log-sum-exps and head-mean weights are
 kept (``checkpoint_name`` ``attn_select``, ``attn_out``, ``attn_lse``,
 ``attn_weights``) and so is the expert layer's output (``moe_out``), so
 the bisection, attention's forward pass and the experts' run once per
-layer and step; attention's backward kernel recomputes the scores of
-its own chunk, a tile at a time in VMEM (``ops/sparse_attention.
-selected_attention``: no array of per-head scores reaches HBM).
+layer and step. A chunk's index scores are computed once in the forward
+pass (the selection and the value of the indexer's loss read the same
+``[q_chunk, keys]`` float32 array) and once more in the backward pass,
+on the way to the loss's gradient; attention's and the indexer's
+backward kernels recompute the per-head scores of their own chunk, a
+tile at a time in VMEM (``ops/sparse_attention.selected_attention``,
+``index_scores``: no array of per-head scores reaches HBM).
 """
 
 from __future__ import annotations
@@ -144,22 +148,41 @@ def indexer_inputs(p, h, index_angles, d: KeyeDims):
 
 
 def chunk_keep(q_idx, k_idx, w_idx, lo: int, hi: int, topk: int):
-    """Keep mask ``[hi - lo, hi]`` of queries ``lo .. hi`` over the keys
-    ``0 .. hi`` they can see: each query's ``topk`` best index scores."""
+    """Queries ``lo .. hi`` over the keys ``0 .. hi`` they can see: their
+    index scores ``[hi - lo, hi]`` float32 (constants: selection is
+    piecewise constant) and the keep mask of each query's ``topk`` best."""
     causal = jnp.arange(lo, hi)[:, None] >= jnp.arange(hi)[None, :]
     with jax.named_scope("attn_indexer"):
         scores = sparse_attention.index_scores(*jax.lax.stop_gradient(
             (q_idx[lo:hi], k_idx[:hi], w_idx[lo:hi])))
     with jax.named_scope("attn_select"):
-        return sparse_attention.select_topk(scores, causal, topk)
+        return scores, sparse_attention.select_topk(scores, causal, topk)
 
 
-@jax.checkpoint
-def chunk_index_loss(q_idx, k_idx, w_idx, keep, target):
-    """The indexer's KL of one chunk of queries, summed over them; the
-    index scores are recomputed in the backward pass."""
-    return sparse_attention.index_kl(
-        sparse_attention.index_scores(q_idx, k_idx, w_idx), keep, target)
+@jax.custom_vjp
+def chunk_index_loss(q_idx, k_idx, w_idx, scores, keep, target):
+    """The indexer's KL of one chunk of queries, summed over them.
+    ``scores`` are ``index_scores(q_idx, k_idx, w_idx)`` as
+    :func:`chunk_keep` computed them: the value reads them, and the
+    backward pass, which keeps the operands only, computes them again
+    (one fused pass) on its way to the three gradients."""
+    return sparse_attention.index_kl(scores, keep, target)
+
+
+def _chunk_index_loss_fwd(q_idx, k_idx, w_idx, scores, keep, target):
+    return (sparse_attention.index_kl(scores, keep, target),
+            (q_idx, k_idx, w_idx, keep, target))
+
+
+def _chunk_index_loss_bwd(res, d_loss):
+    *operands, keep, target = res
+    _, vjp = jax.vjp(
+        lambda *a: sparse_attention.index_kl(
+            sparse_attention.index_scores(*a), keep, target), *operands)
+    return (*vjp(d_loss), None, None, None)
+
+
+chunk_index_loss.defvjp(_chunk_index_loss_fwd, _chunk_index_loss_bwd)
 
 
 def attention_block(p, x, angles, index_angles, d: KeyeDims):
@@ -182,9 +205,8 @@ def attention_block(p, x, angles, index_angles, d: KeyeDims):
     chunk = min(d.q_chunk, t)
     for lo in range(0, t, chunk):
         hi = min(lo + chunk, t)  # keys 0 .. hi are all the chunk can see
-        keep = checkpoint_name(
-            chunk_keep(q_idx, k_idx, w_idx, lo, hi, d.index_topk),
-            "attn_select")
+        scores, keep = chunk_keep(q_idx, k_idx, w_idx, lo, hi, d.index_topk)
+        keep = checkpoint_name(keep, "attn_select")
         with jax.named_scope("attn_sparse"):
             # its output, log-sum-exp and these weights are kept through
             # the layer's rematerialisation: attention's forward pass
@@ -194,7 +216,8 @@ def attention_block(p, x, angles, index_angles, d: KeyeDims):
             weights = checkpoint_name(weights, "attn_weights")
         with jax.named_scope("attn_indexer"):
             loss += chunk_index_loss(q_idx[lo:hi], k_idx[:hi], w_idx[lo:hi],
-                                     keep, jax.lax.stop_gradient(weights))
+                                     scores, keep,
+                                     jax.lax.stop_gradient(weights))
         selected += keep.sum(dtype=jnp.float32)
         outs.append(out)
     out = _dense(jnp.concatenate(outs, axis=0), p["wo"])

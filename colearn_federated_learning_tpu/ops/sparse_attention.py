@@ -6,13 +6,15 @@ attention runs over that set only.
 Everything works on one chunk of queries at a time against the keys the
 chunk can see (static extents), so no ``[T, T]`` array per head is ever
 held: a chunk's index scores are ``[chunk, keys]`` float32, and its
-attention scores exist one ``[block_q, block_k]`` tile of one head at a
-time, in VMEM. The four pieces are separate functions so that the model
-can put them under the scopes ``attn_indexer``, ``attn_select`` and
-``attn_sparse``:
+per-head scores, the indexer's and attention's, exist one ``[block_k,
+block_q]`` or ``[block_q, block_k]`` tile of one head at a time, in VMEM.
+The four pieces are separate functions so that the model can put them
+under the scopes ``attn_indexer``, ``attn_select`` and ``attn_sparse``:
 
 - :func:`index_scores`: ``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])``
-  times the two scale factors, accumulated in float32.
+  times the two scale factors, accumulated in float32. Two Pallas TPU
+  kernels, the forward pass and (its own differentiation rule) the
+  backward pass, which recomputes a tile's dots.
 - :func:`select_topk`: the exact set of the ``topk`` largest ``I[t, s]``
   over ``s <= t`` (all of them while ``t < topk``), ties to the lower
   ``s``: a k-th-value threshold found by bisection over the bits of the
@@ -43,19 +45,6 @@ _NEG_BIG = -1e30
 _LANES = 128
 _VMEM_LIMIT = 96 * 2 ** 20
 _NT = (((1,), (1,)), ((), ()))  # a [m, d] . b [n, d] -> [m, n]
-
-
-def index_scores(q_idx, k_idx, w_idx):
-    """``q_idx``: ``[Tq, J, d]``; ``k_idx``: ``[Tk, d]`` (one key head);
-    ``w_idx``: ``[Tq, J]`` float32 -> ``[Tq, Tk]`` float32."""
-    j, d = q_idx.shape[1], q_idx.shape[2]
-    dots = jnp.einsum("qjd,kd->jqk", q_idx, k_idx,
-                      preferred_element_type=jnp.float32)
-    scale = jnp.float32(d ** -0.5 * j ** -0.5)
-    w = w_idx.astype(jnp.float32).T[:, :, None] * scale
-    # + 0.0: a sum of negative zeros is -0.0, which would order below
-    # +0.0 in the bit pattern the selection bisects over
-    return (jax.nn.relu(dots) * w).sum(0) + 0.0
 
 
 def _ordered_bits(x):
@@ -105,7 +94,8 @@ def _across(x, n: int):
 
 def _heads(rep: int, hd: int, body, carry=None):
     """``body(r, that head's hd columns of a [rows, rep * hd] block,
-    carry)`` for the ``rep`` query heads of a key-value group. A loop the
+    carry)`` for the ``rep`` query heads of a key-value group (for the
+    slabs of the indexer's heads: :func:`_index_slab`). A loop the
     lowering unrolls: a Python loop has every head traced (the round
     program's warm compile-or-load 16.3 s against 14.5, PERF.md PR 26:
     16 chunks x 3 kernels are traced in every process), a rolled loop
@@ -273,7 +263,8 @@ def _call(name, kernel, ins, outs, **grid):
             kernel, name=name,
             out_shape=[out_struct(*o, ins) for o in outs],
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                dimension_semantics=("parallel",) * (len(grid["grid"]) - 1)
+                + ("arbitrary",),
                 vmem_limit_bytes=_VMEM_LIMIT),
             interpret=_interpret(), **grid)(*ins)
 
@@ -288,6 +279,178 @@ def _call(name, kernel, ins, outs, **grid):
         return jax.lax.psum(jnp.where(slot, x[None], jnp.zeros_like(x)), lanes)
 
     return [o[me] for o in jax.vmap(call)(*map(gather, ins))]
+
+
+def _index_slab(heads: int, hd: int) -> int:
+    """How many of the indexer's heads lie in one ``_LANES``-wide slab of
+    the ``[T, heads * hd]`` rows (two, at the published 64): Mosaic takes
+    a traced lane index only where it can prove a multiple of ``_LANES``,
+    so the kernels slice whole slabs with :func:`_heads`' traced index
+    and the heads inside one statically. One head a slab where the widths
+    do not divide (rehearsal sizes, which only the interpreter runs)."""
+    g = _LANES // hd if hd < _LANES and _LANES % hd == 0 else 1
+    return g if heads % g == 0 else 1
+
+
+def _index_forward_kernel(q_ref, k_ref, w_ref, out_ref, *, heads: int,
+                          hd: int):
+    """One tile of keys (rows here: a head's weights are then a lane
+    vector, ``w_ref`` ``[heads, block_q]`` with the scale on it) against
+    the chunk's queries: each head's float32 dots, ``relu``, its weight,
+    summed over the heads in index order; turned to ``[block_q,
+    block_k]`` once, on the way out."""
+    k = k_ref[...]
+    g = _index_slab(heads, hd)
+
+    def slab(r, cols, total):
+        qs = q_ref[:, cols]
+        for i in range(g):
+            dots = jax.lax.dot_general(
+                k, qs[:, i * hd:(i + 1) * hd], _NT,
+                preferred_element_type=jnp.float32)
+            total = total + jnp.maximum(dots, 0.0) * w_ref[
+                pl.ds(r * g + i, 1), :]
+        return total
+
+    total = _heads(heads // g, g * hd, slab,
+                   jnp.zeros(out_ref.shape[::-1], jnp.float32))
+    # a sum of negative zeros is -0.0, which would order below +0.0 in the
+    # bit pattern the selection bisects over (a select and not `+ 0.0`,
+    # which XLA, compiling the interpreted kernel, folds away)
+    total = total.T
+    out_ref[...] = jnp.where(total == 0.0, 0.0, total)
+
+
+def _index_backward_kernel(q_ref, k_ref, w_ref, di_ref, dq_ref, dk_ref,
+                           dw_ref, dq_acc, dw_acc, *, heads: int, hd: int,
+                           scale: float):
+    """One tile of keys (rows) against the chunk's queries, given the
+    tile of the scores' cotangent: each head's dots again, the ``relu``
+    mask, ``d_dots`` in VMEM; ``dK`` summed over the heads, complete per
+    key tile; ``dQ`` and the weights' gradient accumulated over the key
+    tiles (the innermost grid axis) in float32 scratch."""
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        dw_acc[...] = jnp.zeros_like(dw_acc)
+
+    k = k_ref[...]
+    d_i = di_ref[...].T  # [block_k, block_q]
+    g = _index_slab(heads, hd)
+
+    def slab(r, cols, dk):
+        qs = q_ref[:, cols]
+        dqs = []
+        for i in range(g):
+            q, row = qs[:, i * hd:(i + 1) * hd], pl.ds(r * g + i, 1)
+            dots = jax.lax.dot_general(k, q, _NT,
+                                       preferred_element_type=jnp.float32)
+            live = jnp.where(dots > 0, d_i, 0.0)
+            dw_acc[row, :] += (live * dots).sum(0, keepdims=True)
+            d_dots = live * w_ref[row, :]
+            dqs.append(jnp.dot(d_dots.T.astype(k.dtype), k,
+                               preferred_element_type=jnp.float32))
+            dk = dk + jnp.dot(d_dots.astype(q.dtype), q,
+                              preferred_element_type=jnp.float32)
+        dq_acc[:, cols] += jnp.concatenate(dqs, axis=1)
+        return dk
+
+    dk_ref[...] = _heads(heads // g, g * hd, slab,
+                         jnp.zeros(dk_ref.shape, jnp.float32)
+                         ).astype(dk_ref.dtype)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+        dw_ref[...] = dw_acc[...] * scale
+
+
+def _index_tiled(q_idx, k_idx, w_idx, block_q: int, block_k: int):
+    """What both index kernels start from: the block sizes, ``q_idx`` and
+    ``k_idx`` as padded rows, the float32 head weights times the two
+    scale factors as ``[heads, queries]`` (a sixty-fourth of ``q_idx``'s
+    bytes; the large operands keep the model's layout), the scale, and
+    the specs of the three."""
+    tq, heads, hd = q_idx.shape
+    bq, bk = min(block_q, tq), min(block_k, k_idx.shape[0])
+    scale = hd ** -0.5 * heads ** -0.5
+    w = (w_idx.astype(jnp.float32) * jnp.float32(scale)).T
+    ins = (_rows(q_idx, bq), _rows(k_idx, bk),
+           jnp.pad(w, ((0, 0), (0, -tq % bq))))
+    specs = [pl.BlockSpec((bq, heads * hd), lambda i, j: (i, 0)),
+             pl.BlockSpec((bk, hd), lambda i, j: (j, 0)),
+             pl.BlockSpec((heads, bq), lambda i, j: (0, i))]
+    return bq, bk, scale, ins, specs
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def index_scores(q_idx, k_idx, w_idx, block_q: int = 512,
+                 block_k: int = 512):
+    """``q_idx``: ``[Tq, J, d]``; ``k_idx``: ``[Tk, d]`` (one key head);
+    ``w_idx``: ``[Tq, J]`` -> ``[Tq, Tk]`` float32, ``sum_j w[t, j]
+    relu(q_idx[t, j] . k_idx[s])`` times ``d ** -0.5 * J ** -0.5``: the
+    products accumulate in float32, and so do ``relu``, the weights and
+    the sum over the heads. Never ``-0.0``.
+
+    Two Pallas kernels in which a ``[block_k, block_q]`` tile of one
+    head's dots lives and dies in VMEM, so no ``[J, Tq, Tk]`` array
+    reaches HBM: the forward pass, and the backward pass, which keeps the
+    operands only, recomputes a tile's dots and forms the masked
+    cotangent there (rounded to the operands' dtype once, for its
+    products with ``k_idx`` and ``q_idx``). Extents that are no multiple
+    of their block are padded with zeros, which score 0."""
+    return _index_scores_fwd(q_idx, k_idx, w_idx, block_q, block_k)[0]
+
+
+def _index_scores_fwd(q_idx, k_idx, w_idx, block_q, block_k):
+    tq, heads, hd = q_idx.shape
+    bq, bk, _, ins, specs = _index_tiled(q_idx, k_idx, w_idx, block_q,
+                                         block_k)
+    padded = (ins[0].shape[0], ins[1].shape[0])
+    scores, = _call(
+        "attn_index_forward",
+        functools.partial(_index_forward_kernel, heads=heads, hd=hd),
+        ins, [(padded, jnp.float32)],
+        grid=(padded[0] // bq, padded[1] // bk), in_specs=specs,
+        out_specs=[pl.BlockSpec((bq, bk), lambda i, j: (i, j))])
+    return scores[:tq, :k_idx.shape[0]], (q_idx, k_idx, w_idx)
+
+
+def _index_scores_bwd(block_q, block_k, res, d_scores):
+    q_idx, k_idx, w_idx = res
+    tq, heads, hd = q_idx.shape
+    tk = k_idx.shape[0]
+    bq, bk, scale, ins, specs = _index_tiled(q_idx, k_idx, w_idx, block_q,
+                                             block_k)
+    q2, k2, w2 = ins
+    nq, nk = q2.shape[0] // bq, k2.shape[0] // bk
+    d_scores = jnp.pad(d_scores.astype(jnp.float32),
+                       ((0, -tq % bq), (0, -tk % bk)))
+    # one tile of queries: dK leaves the kernel whole, in k_idx's dtype;
+    # more: float32 partial sums, one per query tile
+    sum_dtype = k_idx.dtype if nq == 1 else jnp.float32
+    dq, dk, dw = _call(
+        "attn_index_backward",
+        functools.partial(_index_backward_kernel, heads=heads, hd=hd,
+                          scale=scale),
+        ins + (d_scores,),
+        [(q2.shape, q_idx.dtype), ((nq,) + k2.shape, sum_dtype),
+         (w2.shape, jnp.float32)],
+        grid=(nq, nk),
+        in_specs=specs + [pl.BlockSpec((bq, bk), lambda i, j: (i, j))],
+        out_specs=[specs[0],
+                   pl.BlockSpec((None, bk, hd), lambda i, j: (i, j, 0)),
+                   specs[2]],
+        scratch_shapes=[pltpu.VMEM((bq, heads * hd), jnp.float32),
+                        pltpu.VMEM((heads, bq), jnp.float32)])
+    return (dq[:tq].reshape(q_idx.shape),
+            dk.sum(0)[:tk].astype(k_idx.dtype),
+            dw[:, :tq].T.astype(w_idx.dtype))
+
+
+index_scores.defvjp(_index_scores_fwd, _index_scores_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))

@@ -3,9 +3,11 @@
 attention equations of the plain reference (tests/reference/
 keye_decoder.py: a float32 softmax over the kept pairs of each head,
 rounded to the compute dtype once for its product with v; the heads'
-mean of those weights), and the compiled text of the gradient for a
-described v5e."""
+mean of those weights); ops/sparse_attention.index_scores: its two
+kernels against the ``jnp`` form they replaced, kept here as the
+reference; and the compiled text of the gradient for a described v5e."""
 
+import collections
 import importlib.util
 import os
 import re
@@ -158,6 +160,127 @@ def test_weights_are_exactly_zero_off_keep_and_rows_sum_to_one(dtype):
     np.testing.assert_allclose(weights.sum(-1), 1.0, atol=1e-5)
 
 
+def reference_index_scores(q_idx, k_idx, w_idx):
+    """``index_scores`` as XLA ops, as the program held it until PR 28:
+    the ``[J, Tq, Tk]`` float32 per-head scores are an array here."""
+    j, d = q_idx.shape[1], q_idx.shape[2]
+    dots = jnp.einsum("qjd,kd->jqk", q_idx, k_idx,
+                      preferred_element_type=jnp.float32)
+    scale = jnp.float32(d ** -0.5 * j ** -0.5)
+    w = w_idx.astype(jnp.float32).T[:, :, None] * scale
+    return (jax.nn.relu(dots) * w).sum(0) + 0.0
+
+
+def index_inputs(tq, tk, heads, hd, dtype, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(ks[0], (tq, heads, hd)).astype(dtype),
+            jax.random.normal(ks[1], (tk, hd)).astype(dtype),
+            jax.random.normal(ks[2], (tq, heads)),
+            jax.random.normal(ks[3], (tq, tk)))
+
+
+def index_both(fn, q_idx, k_idx, w_idx, ct):
+    """(scores, (dq_idx, dk_idx, dw_idx)) for the cotangent ``ct``."""
+    def loss(q_idx, k_idx, w_idx):
+        scores = fn(q_idx, k_idx, w_idx)
+        return (scores * ct).sum(), scores
+    (_, scores), grads = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(
+        q_idx, k_idx, w_idx)
+    return scores, grads
+
+
+def fused_index_scores(q_idx, k_idx, w_idx):
+    return sparse_attention.index_scores(q_idx, k_idx, w_idx, 8, 16)
+
+
+# heads x head_dim: one head a slab, two heads a 128-lane slab (the
+# published shape's case), all heads in one slab
+@pytest.mark.parametrize("heads,hd", [(3, 8), (4, 64), (16, 8)])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 3e-6),
+                                       (jnp.bfloat16, 1e-2)])
+def test_index_scores_and_gradients_match_the_jnp_form(dtype, tol, heads,
+                                                       hd):
+    """Two query tiles and three key tiles; bfloat16 operands are held to
+    the float32 reference of the same inputs (the kernel rounds the
+    masked cotangent to bfloat16 once, for its two products)."""
+    q_idx, k_idx, w_idx, ct = index_inputs(16, 48, heads, hd, dtype)
+    scores, grads = index_both(fused_index_scores, q_idx, k_idx, w_idx, ct)
+    want, want_g = index_both(
+        reference_index_scores, q_idx.astype(jnp.float32),
+        k_idx.astype(jnp.float32), w_idx, ct)
+    assert scores.dtype == jnp.float32 and scores.shape == (16, 48)
+    # the scores see the same roundings in both dtypes: bfloat16 products
+    # are exact in float32
+    close(scores, want, 3e-6)
+    for g, w, like in zip(grads, want_g, (q_idx, k_idx, w_idx)):
+        assert g.dtype == like.dtype and g.shape == like.shape
+        close(g, w, tol)
+
+
+def test_the_selection_reads_the_same_set_from_the_fused_scores():
+    """No near-ties here (random float32 scores, 48 keys): the exact
+    top-8 of the kernel's scores and of the reference's are one set."""
+    q_idx, k_idx, w_idx, _ = index_inputs(16, 48, 4, 64, jnp.float32, seed=5)
+    causal = jnp.arange(32, 48)[:, None] >= jnp.arange(48)[None, :]
+    got = sparse_attention.select_topk(
+        fused_index_scores(q_idx, k_idx, w_idx), causal, 8)
+    want = sparse_attention.select_topk(
+        reference_index_scores(q_idx, k_idx, w_idx), causal, 8)
+    assert np.all(np.asarray(got).sum(-1) == 8)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("tq,tk", [(16, 32), (16, 40), (12, 40), (8, 8),
+                                   (12, 21)])
+def test_index_extents_that_are_and_are_not_a_multiple_of_the_block(tq, tk):
+    q_idx, k_idx, w_idx, ct = index_inputs(tq, tk, 4, 64, jnp.float32,
+                                           seed=tq + tk)
+    scores, grads = index_both(fused_index_scores, q_idx, k_idx, w_idx, ct)
+    want, want_g = index_both(reference_index_scores, q_idx, k_idx, w_idx,
+                              ct)
+    assert scores.shape == (tq, tk)
+    close(scores, want, 3e-6)
+    for g, w in zip(grads, want_g):
+        assert g.shape == w.shape
+        close(g, w, 3e-6)
+
+
+def test_a_row_whose_dots_are_all_negative_scores_plus_zero():
+    """Every ``relu`` of the row is 0 and its negative weights turn the
+    terms into ``-0.0``; the score must be ``+0.0``, whose bit pattern
+    the selection orders above ``-0.0``'s. Its gradients: nothing for
+    ``q_idx`` and ``w_idx`` of that row."""
+    q_idx, k_idx, w_idx, ct = index_inputs(8, 32, 4, 64, jnp.float32, seed=9)
+    k_idx = jnp.abs(k_idx)
+    q_idx = q_idx.at[2].set(-jnp.abs(q_idx[2]))
+    w_idx = w_idx.at[2].set(-jnp.abs(w_idx[2]))
+    scores, (dq, _, dw) = index_both(fused_index_scores, q_idx, k_idx,
+                                     w_idx, ct)
+    scores = np.asarray(scores)
+    assert np.all(scores[2] == 0) and np.any(scores[0] != 0)
+    assert not np.signbit(scores[scores == 0]).any()
+    assert not np.any(np.asarray(dq)[2]) and not np.any(np.asarray(dw)[2])
+
+
+def test_index_scores_through_vmap_and_a_shard_map_over_clients():
+    """As the model calls it (the batch mapped over) and as the round
+    engine does (operands that vary over the ``clients`` lanes)."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    args = [jnp.stack(a) for a in zip(*(
+        index_inputs(8, 24, 4, 64, jnp.float32, seed=s) for s in (1, 2)))]
+    want, want_g = jax.vmap(
+        lambda *a: index_both(reference_index_scores, *a))(*args)
+    mapped = jax.vmap(lambda *a: index_both(fused_index_scores, *a))
+    mesh = Mesh(np.array(jax.devices()[:2]), ("clients",))
+    lanes = jax.jit(jax.shard_map(mapped, mesh=mesh, in_specs=P("clients"),
+                                  out_specs=P("clients")))
+    for got, got_g in (mapped(*args), lanes(*args)):
+        close(got, want, 3e-6)
+        for g, w in zip(got_g, want_g):
+            close(g, w, 3e-6)
+
+
 def _kernel_calls(jaxpr, found):
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
@@ -202,10 +325,11 @@ def _dry_keye():
     sizes = dict(vocab_size=8, seq_len=32, layers=2, hidden=64, heads=4,
                  kv_heads=2, head_dim=16, num_experts=8, experts_held=2,
                  expert_offset=2, experts_per_token=3, expert_width=32,
-                 index_heads=2, index_head_dim=8, index_topk=8,
+                 index_heads=3, index_head_dim=8, index_topk=8,
                  mrope_section=(2, 3, 3), q_chunk=8, moe_tile=4)
-    # 2 index heads: the indexer's [index_heads, q_chunk, keys] scores
-    # must not have the shape of attention's
+    # 3 index heads: the indexer's [index_heads, q_chunk, keys] scores
+    # must not have the shape of attention's, nor (with 2) that of the
+    # interpreted forward kernel's [H/G, block_q, head_dim] accumulator
     return build_model("keye_decoder", 0, **sizes), sizes
 
 
@@ -217,9 +341,11 @@ def _loss_and_grad(model):
 
 
 def test_the_decoders_gradient_holds_no_per_head_score_array():
-    """The regression this kernel exists to prevent, at the rehearsal
-    size: no ``[G, H/G, q_chunk, keys]`` array in the lowered gradient
-    (the parent's holds one per chunk and pass)."""
+    """The regression these kernels exist to prevent, at the rehearsal
+    size: no ``[G, H/G, q_chunk, keys]`` array of attention's scores and
+    no ``[index_heads, q_chunk, keys]`` array of the indexer's in the
+    lowered gradient (PR 25's program held one of each per chunk and
+    pass)."""
     model, s = _dry_keye()
     tokens = jnp.zeros((1, s["seq_len"]), jnp.int32)
     params = jax.eval_shape(
@@ -231,6 +357,28 @@ def test_the_decoders_gradient_holds_no_per_head_score_array():
         rf"tensor<(1x)?{g}x{rep}x{s['q_chunk']}x{keys}xf32>", text)
     assert not re.search(
         rf"tensor<(1x)?{s['heads']}x{s['q_chunk']}x{keys}xf32>", text)
+    assert not re.search(
+        rf"tensor<(1x)?{s['index_heads']}x{s['q_chunk']}x{keys}xf32>", text)
+
+
+def test_a_chunks_index_scores_are_computed_once_a_pass():
+    """The decoder's gradient holds the forward index kernel twice a
+    chunk (the forward pass, where the selection and the value of the
+    indexer's loss read the same scores; the backward pass, on the way to
+    the loss's gradient) and the backward kernel once, beside
+    attention's three; the layers are scanned, so a chunk counts once."""
+    model, s = _dry_keye()
+    tokens = jnp.zeros((1, s["seq_len"]), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), tokens)["params"])
+    calls = collections.Counter(_kernel_calls(
+        jax.make_jaxpr(_loss_and_grad(model))(params, tokens).jaxpr, []))
+    chunks = s["seq_len"] // s["q_chunk"]
+    assert calls == {"attn_index_forward": 2 * chunks,
+                     "attn_index_backward": chunks,
+                     "attn_sparse_forward": chunks,
+                     "attn_sparse_head_mean": chunks,
+                     "attn_sparse_backward": chunks}
 
 
 @pytest.fixture(scope="module")
@@ -254,9 +402,12 @@ def test_compiled_for_a_v5e_the_scores_stay_in_the_kernels(chip_mesh,
     as the round engine runs it, compiled for a described v5e with the
     kernels as Mosaic calls: Mosaic accepts their tiling, the kernels'
     loop carries type-check against operands that vary over the mesh,
-    every chunk has its three kernels (forward and heads' mean kept
-    through the layer's rematerialisation), and no float32 buffer of the
-    program has the shape of a chunk's per-head scores."""
+    every chunk has its six kernels (attention's forward and heads' mean,
+    kept through the layer's rematerialisation, and its backward; the
+    index scores once for the selection and the loss's value, once more
+    and their backward for the loss's gradient), and no float32 buffer of
+    the program has the shape of a chunk's per-head scores, attention's
+    or the indexer's."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     monkeypatch.setattr(sparse_attention, "_interpret", lambda: False)
@@ -280,7 +431,8 @@ def test_compiled_for_a_v5e_the_scores_stay_in_the_kernels(chip_mesh,
     step = jax.jit(jax.shard_map(lane, mesh=chip_mesh,
                                  in_specs=(P(), P("clients")), out_specs=P()))
     text = step.lower(params, tokens).compile().as_text()
-    assert text.count("custom_call_target=\"tpu_custom_call\"") == 3 * 4
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 6 * 4
     keys = "(512|1024|1536|2048)"  # what a chunk sees here
     assert not re.search(rf"f32\[(1,)?4,8,512,{keys}\]", text)
     assert not re.search(rf"f32\[(1,)?32,512,{keys}\]", text)
+    assert not re.search(rf"f32\[(1,)?16,512,{keys}\]", text)
