@@ -424,6 +424,54 @@ def check_index_scores() -> None:
                        ATTN_TOL["bfloat16"])
 
 
+def check_latent_attention() -> None:
+    """``ops/latent_attention.causal_attention``'s three kernels,
+    natively, at A.X-K1's widths (2,048 positions in tiles of 512, 8 of
+    the 64 heads of 128 + 64 / 128, one rope key for all heads, bf16):
+    the output and the five gradients against dense causal scores in XLA
+    on the same bf16 inputs."""
+    import jax
+    import jax.numpy as jnp
+
+    from colearn_federated_learning_tpu.ops.latent_attention import (
+        causal_attention,
+    )
+
+    t, heads, scale = 2048, 8, 192 ** -0.5
+    ks = jax.random.split(jax.random.PRNGKey(29), 6)
+    shapes = ((t, heads, 128), (t, heads, 64), (t, heads, 128), (t, 64),
+              (t, heads, 128))
+    ops = [jax.random.normal(k, s, jnp.bfloat16) for k, s in zip(ks, shapes)]
+    ct = jax.random.normal(ks[5], shapes[4], jnp.float32)
+
+    def dense(q_n, q_r, k_n, k_r, v):
+        s = (jnp.einsum("qhd,khd->hqk", q_n, k_n,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("qhd,kd->hqk", q_r, k_r,
+                          preferred_element_type=jnp.float32)) * scale
+        p = jax.nn.softmax(
+            jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf), -1)
+        return jnp.einsum("hqk,khd->qhd", p.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32).astype(v.dtype)
+
+    def both(fn):
+        def loss(*a):
+            out = fn(*a)
+            return (out.astype(jnp.float32) * ct).sum(), out
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2, 3, 4),
+                                          has_aux=True))
+
+    (_, out), grads = both(lambda *a: causal_attention(*a, scale, 512))(*ops)
+    (_, want), want_g = both(dense)(*ops)
+    _require_close("latent attention out", out, want, ATTN_TOL["bfloat16"])
+    for name, g, w in zip(("dq_n", "dq_r", "dk_n", "dk_r", "dv"), grads,
+                          want_g):
+        size = float(jnp.abs(w.astype(jnp.float32)).max())
+        _require_close(f"latent attention {name} / {size:.3g}",
+                       g.astype(jnp.float32) / size,
+                       w.astype(jnp.float32) / size, ATTN_TOL["bfloat16"])
+
+
 def main() -> int:
     t_start = time.time()
     # (a) the compile cache, before the first compile
@@ -478,6 +526,7 @@ def main() -> int:
     check_flash_attention()
     check_selected_attention()
     check_index_scores()
+    check_latent_attention()
 
     say(f"total wall {time.time() - t_start:.1f}s")
     print(json.dumps({"ok": True, "device": {

@@ -26,6 +26,7 @@ Algorithm hooks:
 from __future__ import annotations
 
 import contextlib
+import functools
 from typing import Any, NamedTuple
 
 import jax
@@ -44,6 +45,19 @@ class LocalMetrics(NamedTuple):
     # with an auxiliary output reports (``model.aux_counters``); empty
     # for every other model
     aux: Any = ()
+
+
+class RoundData(NamedTuple):
+    """What a round program reads in the corpus argument's place when
+    the model has a frozen base (``model.lora.enabled``): the examples
+    and the base, both read-only, replicated over the lanes, never
+    donated. Every engine passes its ``train_x`` through to the trainer
+    untouched, so the base is an argument of every round program
+    without one of them knowing it; :func:`_make_step` takes the two
+    apart. The driver builds it (``Experiment._round_data``)."""
+
+    x: Any
+    frozen: Any
 
 
 def make_client_optimizer(cfg: ClientConfig) -> optax.GradientTransformation:
@@ -77,6 +91,14 @@ def normalize_input(x, dtype=jnp.float32):
     return x
 
 
+def _variables(params, frozen):
+    """``model.apply``'s variables: the trained collection, and a LoRA
+    model's frozen base beside it (``models/lora.LoRAModel.apply``)."""
+    if frozen is None:
+        return {"params": params}
+    return {"params": params, "frozen": frozen}
+
+
 def make_loss_fn(model, task: str, reduction: str = "mean",
                  with_counters: bool = False):
     """Masked loss. classify: y [B] ints; lm: y [B,T] next tokens.
@@ -92,20 +114,26 @@ def make_loss_fn(model, task: str, reduction: str = "mean",
     head's logits are f32 by model design).
 
     A model may return ``(logits, aux)`` instead of logits
-    (``models/keye.py``): ``aux["loss"]`` ``[B]`` is an auxiliary loss
-    per example, added to the example's cross-entropy before the mask
-    (coefficient 1; what it moves is the model's business, through
-    ``stop_gradient``), and ``aux["counters"]`` holds ``[B]`` counters
-    named by ``model.aux_counters``. ``with_counters`` makes the loss
+    (``models/keye.py``, ``models/axk1.py``): ``aux["loss"]`` ``[B]``,
+    where the model has one, is an auxiliary loss per example, added to
+    the example's cross-entropy before the mask (coefficient 1; what it
+    moves is the model's business, through ``stop_gradient``), and
+    ``aux["counters"]`` holds ``[B]`` counters named by
+    ``model.aux_counters``. ``with_counters`` makes the loss
     function return ``(loss, {name: mask-weighted mean})`` for
     ``jax.value_and_grad(..., has_aux=True)``. For a model that returns
     logits alone nothing here differs from before.
+
+    ``frozen``: the frozen base of a LoRA model (the variable collection
+    ``"frozen"`` of ``models/lora.LoRAModel.apply``); it takes no
+    gradient.
     """
     in_dtype = getattr(model, "compute_dtype", jnp.float32)
 
-    def loss_fn(params, x, y, m):
+    def loss_fn(params, x, y, m, frozen=None):
         logits = model.apply(
-            {"params": params}, normalize_input(x, in_dtype), train=True
+            _variables(params, frozen), normalize_input(x, in_dtype),
+            train=True
         )
         aux = None
         if isinstance(logits, tuple):
@@ -117,7 +145,7 @@ def make_loss_fn(model, task: str, reduction: str = "mean",
             with (jax.named_scope("lm_head") if aux is not None
                   else contextlib.nullcontext()):
                 ce = optax.softmax_cross_entropy_with_integer_labels(logits, y).mean(-1)
-        if aux is not None:
+        if aux is not None and "loss" in aux:
             ce = ce + aux["loss"]
         weighted = (ce * m).sum()
         loss = weighted if reduction == "sum" else (
@@ -159,9 +187,9 @@ def shared_weight_phase(params) -> bool:
 class _DecomposedLoRA:
     """Megabatch view of a LoRA model: ``apply`` delegates to
     ``apply_decomposed`` (models/lora.py) so the frozen base is never
-    merged into per-client kernels — its weights stay closure constants
-    and contract the flattened megabatch un-batched in every local
-    step. Exposes only what the loss factory reads."""
+    merged into per-client kernels — its weights stay un-batched and
+    contract the flattened megabatch in every local step. Exposes only
+    what the loss factory reads."""
 
     def __init__(self, inner):
         self._inner = inner
@@ -242,8 +270,8 @@ def make_local_train_fn(model, client_cfg: ClientConfig, dp_cfg: DPConfig, task:
     if megabatch and hasattr(model, "apply_decomposed"):
         # All-steps LoRA megabatch: with the merged apply, the diverged
         # phase's per-client vmap batches EVERY base GEMM (C merged
-        # kernel copies); the decomposed apply keeps the frozen base as
-        # a closure constant — only the tiny A/B factors batch — so
+        # kernel copies); the decomposed apply keeps the frozen base
+        # un-batched — only the tiny A/B factors batch — so
         # the dominant contractions stay [C·batch, ·] × un-batched
         # weight in every local step, not just step 0. Spatial and
         # non-megabatch LoRA keep the merged apply bitwise-unchanged;
@@ -263,8 +291,9 @@ def make_local_train_fn(model, client_cfg: ClientConfig, dp_cfg: DPConfig, task:
     sum_grad_fn = jax.value_and_grad(make_loss_fn(model, task, reduction="sum"))
     mu = client_cfg.prox_mu
     if dp_cfg.enabled:
+        dp_loss_fn = make_loss_fn(model, task)
         dp_grad_fn = dp_lib.make_dp_grad_fn(
-            make_loss_fn(model, task), dp_cfg, batch_axis=batch_axis
+            dp_loss_fn, dp_cfg, batch_axis=batch_axis
         )
 
     def _global_count(m):
@@ -304,6 +333,15 @@ def make_local_train_fn(model, client_cfg: ClientConfig, dp_cfg: DPConfig, task:
         ``transpose(jvp(...))`` beneath it; DP's own scopes nest inside)
         and ``local_opt`` (proximal / weight-decay terms and the
         parameter and optimizer-state update). Metadata only."""
+        frozen_args = ()
+        step_dp_grad_fn = dp_grad_fn if dp_cfg.enabled else None
+        if isinstance(train_x, RoundData):
+            train_x, frozen = train_x
+            frozen_args = (frozen,)
+            if dp_cfg.enabled:
+                step_dp_grad_fn = dp_lib.make_dp_grad_fn(
+                    functools.partial(dp_loss_fn, frozen=frozen), dp_cfg,
+                    batch_axis=batch_axis)
 
         def step(carry, inp):
             params, opt_state = carry
@@ -314,14 +352,17 @@ def make_local_train_fn(model, client_cfg: ClientConfig, dp_cfg: DPConfig, task:
             step_n = _global_count(step_mask)  # identical on all batch shards
             with jax.named_scope("local_grad"):
                 if dp_cfg.enabled:
-                    loss, grads = dp_grad_fn(params, x, y, step_mask, key)
+                    loss, grads = step_dp_grad_fn(params, x, y, step_mask,
+                                                  key)
                 elif aux_names:
-                    (loss, counters), grads = grad_fn(params, x, y, step_mask)
+                    (loss, counters), grads = grad_fn(params, x, y, step_mask,
+                                                      *frozen_args)
                 elif batch_axis is None:
-                    loss, grads = grad_fn(params, x, y, step_mask)
+                    loss, grads = grad_fn(params, x, y, step_mask,
+                                          *frozen_args)
                 else:
                     sum_loss, sum_grads = sum_grad_fn(
-                        _batch_varying(params), x, y, step_mask
+                        _batch_varying(params), x, y, step_mask, *frozen_args
                     )
                     denom = jnp.maximum(step_n, 1.0)
                     loss = jax.lax.psum(sum_loss, batch_axis) / denom
@@ -513,8 +554,9 @@ def make_eval_fn(model, task: str):
     loss_core = make_loss_fn(model, task)
     del loss_core  # eval computes sums, not means; kept for symmetry
 
-    def eval_batch(params, x, y, m):
-        logits = model.apply({"params": params}, normalize_input(x), train=False)
+    def eval_batch(params, x, y, m, frozen=None):
+        logits = model.apply(_variables(params, frozen), normalize_input(x),
+                             train=False)
         if isinstance(logits, tuple):  # (logits, aux): see make_loss_fn
             logits = logits[0]
         if task == "classify":

@@ -39,8 +39,12 @@ class LoRAConfig:
     init rng) — re-derived on resume, never checkpointed or shipped
     (the one-time base broadcast is out of the per-round wire model,
     like any deployed-base LoRA system). Supported model families:
-    ``bert_tiny``, ``vit_b16`` (the transformer-block injection map);
-    other zoo members are rejected with a clear error. With
+    ``bert_tiny``, ``vit_b16`` (the transformer-block injection map),
+    ``axk1_decoder`` (the projections of its latent attention, stacked
+    over the layers); other zoo members are rejected with a clear
+    error. The base lives on the device as data, in
+    ``run.local_param_dtype`` (else ``run.param_dtype``), an argument
+    of every round program; ``run.hbm_gb``'s pre-flight counts it. With
     ``enabled=false`` no wrapper is constructed anywhere and runs are
     bitwise-identical to pre-LoRA builds (test-pinned)."""
 
@@ -2103,15 +2107,19 @@ class ExperimentConfig:
                 f"unknown dp.clipping {self.dp.clipping!r}"
             )
         from colearn_federated_learning_tpu.models import returns_aux_loss
+        from colearn_federated_learning_tpu.models.lora import LORA_SUPPORTED
 
         if returns_aux_loss(self.model.name):
-            # the model returns (logits, aux) and its auxiliary loss
-            # reaches the gradient through the plain per-client step only
+            # the model returns (logits, aux): its auxiliary loss and its
+            # counters travel through the plain per-client step only
             # (client/trainer.make_loss_fn); name the pairing here
-            # instead of failing inside flax
+            # instead of failing inside flax. Adapters are the model's
+            # own matter: one with an injection map (models/lora.py)
+            # takes them.
             unsupported = [
                 what for what, on in (
-                    ("model.lora.enabled", self.model.lora.enabled),
+                    ("model.lora.enabled", self.model.lora.enabled
+                     and self.model.name not in LORA_SUPPORTED),
                     ("run.cohort_layout='megabatch'",
                      self.run.cohort_layout == "megabatch"),
                     ("dp.enabled", self.dp.enabled),
@@ -2126,7 +2134,6 @@ class ExperimentConfig:
         lora = self.model.lora
         if lora.enabled:
             from colearn_federated_learning_tpu.models.lora import (
-                LORA_SUPPORTED,
                 LORA_TARGETS,
             )
 
@@ -3059,6 +3066,41 @@ def _keye_silo_lm() -> ExperimentConfig:
     )
 
 
+def _axk1_silo_lora() -> ExperimentConfig:
+    """Cross-silo FedAvg of rank-16 adapters on A.X-K1 as one chip of a
+    16-way expert-parallel deployment holds it (models/axk1.py: latent
+    attention, the leading dense layer and 4 of the 60 expert layers, 12
+    of 192 routed experts beside the shared one, an eighth of the
+    vocabulary; every width as published): 8 silos adapt the frozen
+    bfloat16 base (3.49 B parameters, 7 GB, an argument of the round
+    program) to private text and exchange only the adapters of the five
+    latent-attention projections (5.0 M parameters), 2 local AdamW steps
+    of one 4,096-token sequence per round. Spatial layout, no DP
+    (validate() names what this model does not support)."""
+    return ExperimentConfig(
+        name="axk1_silo_lora",
+        algorithm="fedavg",
+        model=ModelConfig(
+            name="axk1_decoder",
+            num_classes=0,
+            kwargs={"vocab_size": 20480, "seq_len": 4096, "layers": 5,
+                    "experts_held": 12},
+            lora=LoRAConfig(enabled=True, rank=16, alpha=32.0,
+                            target="attention"),
+        ),
+        data=DataConfig(
+            name="synthetic_text",
+            num_clients=8,
+            partition="silo",
+            max_examples_per_client=2,
+        ),
+        client=ClientConfig(local_epochs=1, batch_size=1, lr=1e-4,
+                            optimizer="adamw", weight_decay=0.01),
+        server=ServerConfig(num_rounds=100, cohort_size=8, eval_every=0),
+        run=RunConfig(compute_dtype="bfloat16", local_param_dtype="bfloat16"),
+    )
+
+
 _NAMED = {
     "mnist_fedavg_2": _mnist_fedavg_2,
     "cifar10_fedavg_100": _cifar10_fedavg_100,
@@ -3071,6 +3113,7 @@ _NAMED = {
     "bert_lora_federated": _bert_lora_federated,
     "vit_lora_dp": _vit_lora_dp,
     "keye_silo_lm": _keye_silo_lm,
+    "axk1_silo_lora": _axk1_silo_lora,
 }
 
 

@@ -34,7 +34,7 @@ instead made every tile's products five times slower. PERF.md, PR 25.)
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -51,7 +51,9 @@ class Dispatch(NamedTuple):
     tile]`` local expert of each tile; ``n_tiles``: tiles in use;
     ``counts``: ``[experts_held]`` assignments per held expert;
     ``held_share``: share of all assignments that fall on held experts;
-    ``experts``: ``[T, top_k]`` global ids of every token's experts.
+    ``experts``: ``[T, top_k]`` global ids of every token's experts;
+    ``groups``: ``[T, topk_group]`` the groups kept for every token
+    (group-limited routing only).
     """
 
     row_token: jnp.ndarray
@@ -61,19 +63,52 @@ class Dispatch(NamedTuple):
     counts: jnp.ndarray
     held_share: jnp.ndarray
     experts: jnp.ndarray
+    groups: Any = None
+
+
+def group_limited_top_k(scores, top_k: int, n_group: int, topk_group: int):
+    """Group-limited selection: the experts are ``n_group`` groups of
+    consecutive ids, a group's score is the sum of its two largest
+    ``scores``, the ``topk_group`` best groups are kept (ties: the lower
+    group), and the ``top_k`` largest scores among their experts win
+    (ties: the lower expert id). ``scores``: ``[T, num_experts]``, all
+    positive. Returns (scores of the chosen, their ids, the kept
+    groups ``[T, topk_group]``)."""
+    t, n = scores.shape
+    grouped = scores.reshape(t, n_group, n // n_group)
+    group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)
+    _, groups = jax.lax.top_k(group_score, topk_group)
+    kept = jnp.zeros((t, n_group), bool).at[
+        jnp.arange(t)[:, None], groups].set(True)
+    masked = jnp.where(kept[:, :, None], grouped, -1.0).reshape(t, n)
+    _, top_e = jax.lax.top_k(masked, top_k)
+    return jnp.take_along_axis(scores, top_e, axis=-1), top_e, groups
 
 
 def route(h, w_router, *, top_k: int, experts_held: int, expert_offset: int,
-          tile: int) -> Dispatch:
-    """Router softmax over all experts (float32), top-k with gates
+          tile: int, scoring: str = "softmax", n_group: int = 1,
+          topk_group: int = 1, gate_scale: float = 1.0) -> Dispatch:
+    """Router scores over all experts (float32), top-k with gates
     renormalised over the k, and the dispatch tables for the held ones.
-    ``h``: ``[T, D]``; ``w_router``: ``[D, num_experts]``."""
+    ``h``: ``[T, D]``; ``w_router``: ``[D, num_experts]``. ``scoring``
+    ``"softmax"`` takes the k largest probabilities; ``"sigmoid"``
+    scores every expert alone, selects within the ``topk_group`` best of
+    ``n_group`` groups (:func:`group_limited_top_k`) and multiplies the
+    renormalised gates by ``gate_scale``."""
     t = h.shape[0]
     logits = jnp.dot(h, w_router.astype(h.dtype),
                      preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, top_k)  # ties: the lower expert id
-    gates = top_p / top_p.sum(-1, keepdims=True)
+    groups = None
+    if scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_e = jax.lax.top_k(probs, top_k)  # ties: the lower expert id
+        gates = top_p / top_p.sum(-1, keepdims=True)
+    elif scoring == "sigmoid":
+        top_p, top_e, groups = group_limited_top_k(
+            jax.nn.sigmoid(logits), top_k, n_group, topk_group)
+        gates = gate_scale * top_p / top_p.sum(-1, keepdims=True)
+    else:
+        raise ValueError(f"unknown router scoring {scoring!r}")
     if experts_held < w_router.shape[1]:
         # A share of the layer has a share of the gates' gradient: the
         # deployment sums it over the chips, with the combine's exchange,
@@ -116,7 +151,7 @@ def route(h, w_router, *, top_k: int, experts_held: int, expert_offset: int,
     ).astype(jnp.int32)
     return Dispatch(row_token, row_gate, tile_expert,
                     padded_end[-1] // tile, counts[:experts_held],
-                    held.mean(dtype=jnp.float32), top_e)
+                    held.mean(dtype=jnp.float32), top_e, groups)
 
 
 def _tile_inputs(i, tile, h, w1, w3, row_token, row_gate, tile_expert):
@@ -146,6 +181,31 @@ def _experts_forward(h, w1, w3, w2, row_token, row_gate, tile_expert,
     return y.astype(h.dtype)
 
 
+def _tile_cotangents(i, tile, h, w1, w3, w2, row_token, row_gate, tile_expert,
+                     dy):
+    """One tile of the backward pass, up to the cotangents of its two
+    first products: (expert, rows' tokens, gates, rows, ``silu(a) b``,
+    rows of ``dy``, ``da``, ``db``, the gates' cotangent)."""
+    e, tok, gate, x, a, b = _tile_inputs(i, tile, h, w1, w3, row_token,
+                                         row_gate, tile_expert)
+    sig = jax.nn.sigmoid(a)
+    silu = a * sig
+    mid = silu * b
+    dout = jnp.take(dy, tok, axis=0)
+    # cotangent of the ungated tile output, back through w2
+    dmid_pre = jnp.dot(dout, w2[e].T, preferred_element_type=jnp.float32)
+    dg = (mid * dmid_pre).sum(-1)
+    dmid = dmid_pre * gate[:, None]
+    da = (dmid * b * (sig * (1.0 + a * (1.0 - sig)))).astype(h.dtype)
+    db = (dmid * silu).astype(h.dtype)
+    return e, tok, gate, x, mid, dout, da, db, dg
+
+
+def _rows_cotangent(e, da, db, w1, w3):
+    return (jnp.dot(da, w1[e].T, preferred_element_type=jnp.float32)
+            + jnp.dot(db, w3[e].T, preferred_element_type=jnp.float32))
+
+
 @sequential_vmap
 def _experts_backward(h, w1, w3, w2, row_token, row_gate, tile_expert,
                       n_tiles, dy):
@@ -154,18 +214,8 @@ def _experts_backward(h, w1, w3, w2, row_token, row_gate, tile_expert,
 
     def body(i, carry):
         dh, dw1, dw3, dw2, dgate = carry
-        e, tok, gate, x, a, b = _tile_inputs(i, tile, h, w1, w3, row_token,
-                                             row_gate, tile_expert)
-        sig = jax.nn.sigmoid(a)
-        silu = a * sig
-        mid = silu * b
-        dout = jnp.take(dy, tok, axis=0)
-        # cotangent of the ungated tile output, back through w2
-        dmid_pre = jnp.dot(dout, w2[e].T, preferred_element_type=jnp.float32)
-        dg = (mid * dmid_pre).sum(-1)
-        dmid = dmid_pre * gate[:, None]
-        da = (dmid * b * (sig * (1.0 + a * (1.0 - sig)))).astype(cd)
-        db = (dmid * silu).astype(cd)
+        e, tok, gate, x, mid, dout, da, db, dg = _tile_cotangents(
+            i, tile, h, w1, w3, w2, row_token, row_gate, tile_expert, dy)
         dout_g = (dout.astype(jnp.float32) * gate[:, None]).astype(cd)
         dw2 = dw2.at[e].add(jnp.dot(mid.astype(cd).T, dout_g,
                                     preferred_element_type=jnp.float32))
@@ -173,9 +223,7 @@ def _experts_backward(h, w1, w3, w2, row_token, row_gate, tile_expert,
                                     preferred_element_type=jnp.float32))
         dw3 = dw3.at[e].add(jnp.dot(x.T, db,
                                     preferred_element_type=jnp.float32))
-        dx = (jnp.dot(da, w1[e].T, preferred_element_type=jnp.float32)
-              + jnp.dot(db, w3[e].T, preferred_element_type=jnp.float32))
-        dh = dh.at[tok].add(dx)
+        dh = dh.at[tok].add(_rows_cotangent(e, da, db, w1, w3))
         dgate = jax.lax.dynamic_update_slice(dgate, dg, (i * tile,))
         return dh, dw1, dw3, dw2, dgate
 
@@ -186,6 +234,31 @@ def _experts_backward(h, w1, w3, w2, row_token, row_gate, tile_expert,
     )
     return (dh.astype(h.dtype), dw1.astype(w1.dtype), dw3.astype(w3.dtype),
             dw2.astype(w2.dtype), dgate)
+
+
+@sequential_vmap
+def _experts_backward_rows(h, w1, w3, w2, row_token, row_gate, tile_expert,
+                           n_tiles, dy):
+    """:func:`_experts_backward` against weights that take no gradient:
+    the rows' and the gates' cotangents only, five products a tile
+    (two of them the forward's, computed again) where the trained form
+    has eight. (XLA cannot drop the three weight
+    accumulators itself: they are carried by a loop whose trip count is
+    data.)"""
+    tile = row_token.shape[0] // tile_expert.shape[0]
+
+    def body(i, carry):
+        dh, dgate = carry
+        e, tok, _, _, _, _, da, db, dg = _tile_cotangents(
+            i, tile, h, w1, w3, w2, row_token, row_gate, tile_expert, dy)
+        dh = dh.at[tok].add(_rows_cotangent(e, da, db, w1, w3))
+        dgate = jax.lax.dynamic_update_slice(dgate, dg, (i * tile,))
+        return dh, dgate
+
+    zeros = lambda a: zeros_varying_like(h, a.shape, jnp.float32)  # noqa: E731
+    dh, dgate = jax.lax.fori_loop(0, n_tiles, body,
+                                  (zeros(h), zeros(row_gate)))
+    return dh.astype(h.dtype), dgate
 
 
 @jax.custom_vjp
@@ -211,6 +284,24 @@ def _expert_ffn_bwd(res, dy):
 
 
 expert_ffn.defvjp(_expert_ffn_fwd, _expert_ffn_bwd)
+
+
+@jax.custom_vjp
+def expert_ffn_frozen(h, w1, w3, w2, row_token, row_gate, tile_expert,
+                      n_tiles):
+    """:func:`expert_ffn` against frozen experts: the same result, and a
+    backward pass that computes the rows' and the gates' cotangents
+    only (the weights' are ``None``, which JAX reads as zero)."""
+    return _experts_forward(h, w1, w3, w2, row_token, row_gate, tile_expert,
+                            n_tiles)
+
+
+def _expert_ffn_frozen_bwd(res, dy):
+    dh, dgate = _experts_backward_rows(*res, dy)
+    return dh, None, None, None, None, dgate, None, None
+
+
+expert_ffn_frozen.defvjp(_expert_ffn_fwd, _expert_ffn_frozen_bwd)
 
 
 def expert_share(h, w_router, w1, w3, w2, *, top_k: int, expert_offset: int,

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from colearn_federated_learning_tpu.client.trainer import (
+    RoundData,
     make_eval_fn,
     make_local_train_fn,
     shared_weight_phase,
@@ -120,22 +121,31 @@ class Experiment:
         if cfg.run.sanitize:
             jax.config.update("jax_debug_nans", True)
         compute_dtype = _DTYPES[cfg.run.compute_dtype]
+        # LoRA adapter plane (model.lora, models/lora.py): wrap the
+        # transformer so the params pytree every downstream subsystem
+        # sees IS the adapter set — the [K,·] wire stack carries adapter
+        # deltas, and aggregation/compression/attacks/ledger/reputation
+        # all run in adapter space with zero engine involvement. The
+        # frozen base is data (``frozen_base``, drawn and placed once by
+        # init_state, an argument of every program that applies the
+        # model), stored in the dtype local training holds weights in
+        # (what _cast_params would make of it every round, done once):
+        # the base module is built with that param_dtype, the adapters
+        # keep run.param_dtype. lora-off constructs no wrapper at all
+        # (the bitwise-identity contract).
+        self._lora = cfg.model.lora.enabled
+        param_dtype = _DTYPES[cfg.run.param_dtype]
         self.model = build_model(
             cfg.model.name, cfg.model.num_classes,
             compute_dtype=compute_dtype,
-            param_dtype=_DTYPES[cfg.run.param_dtype],
+            param_dtype=(self._local_dtype() or param_dtype) if self._lora
+            else param_dtype,
             **cfg.model.kwargs,
         )
-        # LoRA adapter plane (model.lora, models/lora.py): wrap the
-        # transformer so the params pytree every downstream subsystem
-        # sees IS the adapter set — the base stays frozen inside the
-        # wrapper's apply, the [K,·] wire stack carries adapter deltas,
-        # and aggregation/compression/attacks/ledger/reputation all run
-        # in adapter space with zero engine involvement. lora-off
-        # constructs no wrapper at all (the bitwise-identity contract).
-        self._lora = cfg.model.lora.enabled
         self._full_param_stats_cache = None
         self._wire_reduction_cache = None
+        self.frozen_base = None
+        self._frozen_base_seed = None
         if self._lora:
             from colearn_federated_learning_tpu.models.lora import (
                 build_lora_model,
@@ -144,7 +154,7 @@ class Experiment:
             self.model = build_lora_model(
                 self.model, cfg.model.name,
                 rank=cfg.model.lora.rank, alpha=cfg.model.lora.alpha,
-                target=cfg.model.lora.target,
+                target=cfg.model.lora.target, adapter_dtype=param_dtype,
             )
         self.fed = build_federated_data(cfg.data, seed=cfg.run.seed, **cfg.model.kwargs)
         self.task = self.fed.task
@@ -747,10 +757,10 @@ class Experiment:
         # outer over clients, inner over each client's padded batch stack
         # — instead of one jitted call per client per batch (up to
         # clients × batches dispatches; same fix as _eval_all).
-        def _fed_eval_all(params, xs, ys, ms):
+        def _fed_eval_all(params, xs, ys, ms, frozen=None):
             def per_client(_, client_b):
                 def body(acc, b):
-                    _, c, n = eval_fn(params, *b)
+                    _, c, n = eval_fn(params, *b, frozen)
                     return (acc[0] + c, acc[1] + n), None
 
                 sums, _ = jax.lax.scan(
@@ -770,9 +780,9 @@ class Experiment:
         # scale (50k test / batch 64 ≈ 780 batches) the per-batch loop is
         # host-dispatch-bound. Parity with the per-batch
         # loop is pinned by tests/test_e2e_mnist.py::test_eval_scan_parity.
-        def _eval_all(params, xb, yb, mb):
+        def _eval_all(params, xb, yb, mb, frozen=None):
             def body(acc, b):
-                l, c, n = eval_fn(params, *b)
+                l, c, n = eval_fn(params, *b, frozen)
                 return (acc[0] + l, acc[1] + c, acc[2] + n), None
 
             acc, _ = jax.lax.scan(
@@ -1052,12 +1062,25 @@ class Experiment:
                 dummy,
             )
             leaves = jax.tree.leaves(shapes)
+            coords = sum(int(np.prod(l.shape)) for l in leaves)
+            # the full-delta twin ships run.param_dtype; the base itself
+            # is stored in the local dtype (frozen_base_bytes)
             self._full_param_stats_cache = (
-                sum(int(np.prod(l.shape)) for l in leaves),
-                sum(int(np.prod(l.shape)) * l.dtype.itemsize
-                    for l in leaves),
+                coords,
+                coords * jnp.dtype(
+                    _DTYPES[self.cfg.run.param_dtype]).itemsize,
             )
+            self._frozen_base_bytes_cache = sum(
+                int(np.prod(l.shape)) * l.dtype.itemsize for l in leaves)
         return self._full_param_stats_cache
+
+    def frozen_base_bytes(self) -> int:
+        """Bytes of a LoRA model's frozen base as every chip holds it
+        (0 without one): what the HBM pre-flight counts."""
+        if not self._lora:
+            return 0
+        self._full_param_stats()
+        return self._frozen_base_bytes_cache
 
     def wire_reduction_vs_full(self) -> float:
         """Analytic per-client upload-byte ratio full-delta ÷ trained
@@ -1231,6 +1254,9 @@ class Experiment:
             self.cfg.server.optimizer
         ]
         parts["params + server opt"] = p_bytes * (1 + opt_factor) / gib
+        if self._lora:
+            parts["frozen base (replicated)"] = (
+                self.frozen_base_bytes() / gib)
         state_itemsize = (
             2 if self.cfg.server.client_state_dtype == "bfloat16" else 4
         )
@@ -1331,6 +1357,33 @@ class Experiment:
     def _put_data(self, arr):
         return self._put(arr, self._data_sharding)
 
+    def _init_frozen_base(self, init_rng, dummy, seed: int) -> None:
+        """Draw a LoRA model's frozen base for ``seed`` and place it,
+        replicated over the lanes: one jitted program whose outputs are
+        born on the device in their stored dtype, leaf by leaf (a base
+        of gigabytes never exists on the host, nor whole in float32).
+        A pure function of the seed: never checkpointed, re-derived on
+        resume. Drawn once per seed; the round programs, eval and
+        export read ``frozen_base``."""
+        with self.tracer.span("init.frozen_base"):
+            self.frozen_base = None  # the old seed's, before the new
+            draw = jax.jit(self.model.init_frozen,
+                           out_shardings=self._data_sharding)
+            self.frozen_base = jax.block_until_ready(draw(init_rng, dummy))
+            self._frozen_base_seed = seed
+
+    def _round_data(self, train_x):
+        """The corpus argument of a round program: the examples, with
+        the frozen base beside them where the model has one
+        (client/trainer.RoundData)."""
+        if not self._lora:
+            return train_x
+        if self.frozen_base is None:
+            raise RuntimeError(
+                "model.lora: the frozen base is drawn by init_state(); "
+                "call it before running a round")
+        return RoundData(train_x, self.frozen_base)
+
     def init_state(self, seed: Optional[int] = None) -> Dict[str, Any]:
         seed = self.cfg.run.seed if seed is None else seed
         rng = jax.random.PRNGKey(seed)
@@ -1340,6 +1393,8 @@ class Experiment:
         dummy = normalize_input(jnp.asarray(self.fed.train_x[:1]))
         variables = self.model.init(init_rng, dummy, train=False)
         params = variables["params"]
+        if self._lora and self._frozen_base_seed != seed:
+            self._init_frozen_base(init_rng, dummy, seed)
         state = {
             "params": params,
             "server_opt_state": self.server_opt_init(params),
@@ -1958,10 +2013,11 @@ class Experiment:
         compute of the PREVIOUS round)."""
         if slab is not None:
             idx, slab_x, slab_y = slab
-            train_x = self._put_data(jnp.asarray(slab_x))
+            train_x = self._round_data(
+                self._put_data(jnp.asarray(slab_x)))
             train_y = self._put_data(jnp.asarray(slab_y))
         else:
-            train_x, train_y = self.train_x, self.train_y
+            train_x, train_y = self._round_data(self.train_x), self.train_y
         if self._cohort_sharding is not None:
             idx = self._put(idx, self._cohort_sharding)
             # the [K, 2] spec has no batch dim — cohort-sharded only
@@ -2215,7 +2271,8 @@ class Experiment:
                 )
         if not place:
             # fuse>1 requires hbm placement (validate), so slab is None
-            return cohort, idx, mask, n_ex, self.train_x, self.train_y, n_host
+            return (cohort, idx, mask, n_ex,
+                    self._round_data(self.train_x), self.train_y, n_host)
         with self.tracer.span("round.placement"):
             if entry is not None and entry["placed"] is not None:
                 # double-buffered: the worker already placed this
@@ -2619,10 +2676,11 @@ class Experiment:
                 self._population.observe_slab(
                     int(idx.size), int(len(np.unique(idx)))
                 )
-            train_x = self._put_data(jnp.asarray(slab_x))
+            train_x = self._round_data(
+                self._put_data(jnp.asarray(slab_x)))
             train_y = self._put_data(jnp.asarray(slab_y))
         else:
-            train_x, train_y = self.train_x, self.train_y
+            train_x, train_y = self._round_data(self.train_x), self.train_y
 
         put_c = lambda a: self._put(jnp.asarray(a), self._client_sharding)  # noqa: E731
         rng = jax.random.fold_in(state["rng_key"], round_idx)
@@ -2870,8 +2928,8 @@ class Experiment:
         else:
             round_fn = self._device_unfused_round_fn()
         args = (state["params"], state["server_opt_state"],
-                self.train_x, self.train_y, self._device_arrays,
-                jnp.int32(round_idx), state["rng_key"])
+                self._round_data(self.train_x), self.train_y,
+                self._device_arrays, jnp.int32(round_idx), state["rng_key"])
         with self.tracer.span("round.dispatch"):
             if self._ledger_on:
                 params, opt_state, ledger, metrics, sched = round_fn(
@@ -2916,8 +2974,8 @@ class Experiment:
         with self.tracer.span("round.dispatch"):
             out = self.round_fn(
                 state["params"], state["server_opt_state"],
-                self.train_x, self.train_y, sched["idx"], sched["spec"],
-                sched["n_ex"], rng, **kw,
+                self._round_data(self.train_x), self.train_y,
+                sched["idx"], sched["spec"], sched["n_ex"], rng, **kw,
             )
         if self._ledger_on:
             params, opt_state, ledger, metrics = out
@@ -3492,7 +3550,8 @@ class Experiment:
                         slab_x[: len(uniq)] = self.fed.train_x[uniq]
                         slab_y[: len(uniq)] = self.fed.train_y[uniq]
                     idx_stack = inv.reshape(idx_stack.shape).astype(np.int32)
-                train_x = self._put_data(jnp.asarray(slab_x))
+                train_x = self._round_data(
+                    self._put_data(jnp.asarray(slab_x)))
                 train_y = self._put_data(jnp.asarray(slab_y))
             idx_f = self._put(idx_stack, self._fused_cohort_sharding)
             # mask SPECS [F, K, 2] have no batch dim: fuse replicated,
@@ -4218,6 +4277,10 @@ class Experiment:
                 "fused_apply": bool(cfg.server.fused_apply),
                 "double_buffer": bool(self._double_buffer),
                 "control_plane": cfg.run.control_plane,
+                **({"frozen_base_bytes": self.frozen_base_bytes(),
+                    "frozen_base_dtype": (cfg.run.local_param_dtype
+                                          or cfg.run.param_dtype)}
+                   if self._lora else {}),
             })
         if start_round == 0 and self._phase_cost_on:
             # the static half of the cost model (obs/roofline.py): the
@@ -5036,7 +5099,8 @@ class Experiment:
     def evaluate(self, params) -> Dict[str, float]:
         with self.tracer.span("round.eval"):
             xb, yb, mb = self._eval_data
-            loss, acc, n = jax.device_get(self._eval_all(params, xb, yb, mb))
+            loss, acc, n = jax.device_get(
+                self._eval_all(params, xb, yb, mb, self.frozen_base))
             return {"eval_loss": float(loss / n), "eval_acc": float(acc / n)}
 
     def evaluate_federated(self, params, max_clients: int = 64,
@@ -5120,7 +5184,8 @@ class Experiment:
                 np.stack([pad(t[i]) for t in part]) for i in range(3)
             )
             c, n = jax.device_get(self._fed_eval_all(
-                params, jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(ms)
+                params, jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(ms),
+                self.frozen_base,
             ))
             cs.append(np.asarray(c))
             ns.append(np.asarray(n))
@@ -5228,7 +5293,8 @@ class Experiment:
             if self.cfg.client.lr_decay != 1.0:
                 extra = (jnp.float32(self.cfg.client.lr_decay ** round_idx),)
             p_i, _ = self._personal_train(
-                params, jnp.asarray(slab_x), jnp.asarray(slab_y),
+                params, self._round_data(jnp.asarray(slab_x)),
+                jnp.asarray(slab_y),
                 jnp.asarray(idx.reshape(steps, batch)),
                 jnp.asarray(mask.reshape(steps, batch)),
                 jax.random.fold_in(jax.random.PRNGKey(seed), cid),
@@ -5241,7 +5307,7 @@ class Experiment:
                 for b in range(xb.shape[0]):
                     _, c, m = self._eval_fn(
                         p, jnp.asarray(xb[b]), jnp.asarray(yb[b]),
-                        jnp.asarray(mb[b]),
+                        jnp.asarray(mb[b]), self.frozen_base,
                     )
                     c_sum += float(c)
                     n_sum += float(m)
@@ -5277,7 +5343,7 @@ class Experiment:
             # the deployment artifact is the MERGED model (W +
             # (alpha/r)·A·B over the seed-derived frozen base) — a
             # consumer of the export never needs the adapter structure
-            params = self.model.merged_params(params)
+            params = self.model.merged_params(params, self.frozen_base)
         out_path = export_params(params, path)
         n_params = sum(
             int(np.prod(p.shape)) for p in jax.tree.leaves(params)

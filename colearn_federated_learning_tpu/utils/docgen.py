@@ -27,11 +27,20 @@ _SECTIONS = [
      "construction; eval and `colearn export` use the merged model. "
      "Cuts per-client upload bytes ~d/(2r) per target (the shipped "
      "bert_lora_federated geometry logs wire_reduction_vs_full = "
-     "136x); supported families: bert_tiny, vit_b16. The frozen base "
-     "is a pure function of run.seed — re-derived on resume, never "
-     "checkpointed or shipped. lora off builds the exact pre-LoRA "
-     "program (bitwise, test-pinned). See docs/DESIGN.md \"LoRA "
-     "adapter plane\"."),
+     "136x); supported families: bert_tiny, vit_b16, axk1_decoder (the "
+     "five projections of its latent attention, stacked over the "
+     "layers, applied as side products). The frozen base is DATA: "
+     "drawn on the device by init_state (span init.frozen_base), leaf "
+     "by leaf in run.local_param_dtype (else run.param_dtype), "
+     "replicated over the lanes, handed to every round program as an "
+     "argument beside the corpus (client/trainer.RoundData) and to "
+     "eval and export as Experiment.frozen_base; never donated, "
+     "aggregated, compressed, attacked, ledgered, checkpointed or "
+     "shipped (a pure function of run.seed, re-derived on resume); "
+     "run.hbm_gb's pre-flight counts its bytes. The adapters keep "
+     "run.param_dtype. lora off builds the exact pre-LoRA program "
+     "(bitwise, test-pinned). See docs/DESIGN.md \"LoRA adapter "
+     "plane\"."),
     ("data", config_mod.DataConfig, "Dataset, federation partition, placement."),
     ("data.store", config_mod.StoreConfig,
      "On-disk memory-mapped client store (data/store.py) — the "
@@ -299,6 +308,7 @@ def config_reference_markdown() -> str:
         if section == "attack":
             lines += [_THREAT_MODEL]
     lines += _model_kwargs_section("keye_decoder", _KEYE_KWARGS_BLURB)
+    lines += _model_kwargs_section("axk1_decoder", _AXK1_KWARGS_BLURB)
     names = config_mod.list_named_configs()
     named = ", ".join(f"`{n}`" for n in names)
     lines += [
@@ -329,6 +339,27 @@ _KEYE_KWARGS_BLURB = (
     "The model does not support "
     "`model.lora.enabled`, `run.cohort_layout=megabatch`, `dp.enabled` "
     "or `run.batch_shards > 1` (validate() names them)."
+)
+
+
+_AXK1_KWARGS_BLURB = (
+    "The decoder of A.X-K1 as one chip of an expert-parallel deployment "
+    "holds it (models/axk1.py; named config `axk1_silo_lora`, which "
+    "trains rank-16 adapters on its latent attention over a frozen "
+    "bfloat16 base). The defaults are the published widths; `layers` "
+    "(the leading dense layer and `layers - 1` expert layers), "
+    "`experts_held` (with `expert_offset`) and `vocab_size` are the "
+    "chip's share. Latent attention: `q_rank` / `kv_rank` latents, "
+    "`heads` of `qk_nope + qk_rope` query-key dims and `v_dim` value "
+    "dims, one rope key per position; the `rope_*` kwargs are YaRN's. "
+    "Routing: sigmoid scores, the `topk_group` best of `n_group` groups, "
+    "`experts_per_token` among them, gates scaled by `gate_scale`; a "
+    "shared expert beside the held ones. `q_chunk` and `moe_tile` are "
+    "tilings that change no value. The model reports counters and "
+    "does not support `run.cohort_layout=megabatch`, `dp.enabled` or "
+    "`run.batch_shards > 1` (validate() names them); with "
+    "`model.lora.enabled` everything but the adapters is frozen, "
+    "without it the whole decoder trains."
 )
 
 

@@ -28,6 +28,22 @@ _SHRINK = {
         "model.kwargs.mrope_section": [1, 1, 2], "model.kwargs.q_chunk": 16,
         "model.kwargs.moe_tile": 4, "run.local_param_dtype": "",
     },
+    # A.X-K1 at a toy size: latent attention at two query chunks, the
+    # dense layer and one expert layer, 4 of 16 experts held in 4 groups
+    # of which 2 are kept, rank-16 adapters cut to rank 4
+    "axk1_silo_lora": {
+        "model.kwargs.vocab_size": 8, "model.kwargs.seq_len": 32,
+        "model.kwargs.layers": 2, "model.kwargs.hidden": 32,
+        "model.kwargs.heads": 4, "model.kwargs.q_rank": 12,
+        "model.kwargs.kv_rank": 8, "model.kwargs.qk_nope": 8,
+        "model.kwargs.qk_rope": 4, "model.kwargs.v_dim": 8,
+        "model.kwargs.dense_width": 48, "model.kwargs.num_experts": 16,
+        "model.kwargs.experts_held": 4, "model.kwargs.experts_per_token": 4,
+        "model.kwargs.expert_width": 16, "model.kwargs.n_group": 4,
+        "model.kwargs.topk_group": 2, "model.kwargs.q_chunk": 16,
+        "model.kwargs.moe_tile": 4, "model.lora.rank": 4,
+        "run.local_param_dtype": "",
+    },
     "cifar10_fedavg_100": {"data.num_clients": 16, "model.kwargs.width": 16},
     # the north-star config keeps its FULL 1000-client federation — the
     # point is sampling/partitioning/index-tensor behavior at that scale;

@@ -25,6 +25,7 @@ def test_named_configs_exist():
         "bert_lora_federated",
         "vit_lora_dp",
         "keye_silo_lm",  # PR 25: the sparse-expert language decoder
+        "axk1_silo_lora",  # PR 29: adapters on a frozen latent-attention base
     ])
     for name in list_named_configs():
         cfg = get_named_config(name)

@@ -123,6 +123,12 @@ def test_rank_must_be_low_rank_for_every_target():
         init_lora_params(base, 32, "attention", jax.random.PRNGKey(0))
 
 
+def _frozen(model, shape=(1, 16), seed=0):
+    """The base the driver would draw for ``seed`` (init_params' rng)."""
+    return model.init_frozen(jax.random.PRNGKey(seed),
+                             jnp.zeros(shape, jnp.int32))
+
+
 def test_wrapper_params_are_adapters_and_apply_merges():
     model = build_lora_model(_tiny_bert(), "bert_tiny", rank=2,
                              alpha=8.0, target="attention")
@@ -133,24 +139,25 @@ def test_wrapper_params_are_adapters_and_apply_merges():
     }
     assert names == {"lora_a", "lora_b"}
     x = jnp.zeros((2, 16), jnp.int32)
-    out = model.apply({"params": params}, x, train=False)
+    base_params = _frozen(model)
+    out = model.apply({"params": params, "frozen": base_params}, x,
+                      train=False)
     assert out.shape == (2, 16, 32)
     # B = 0 at init => the merged model IS the base model
-    base_params = model._base_params
     out_base = model.base.apply({"params": base_params}, x, train=False)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(out_base))
     # merged_params exports the full-model tree
-    merged = model.merged_params(params)
+    merged = model.merged_params(params, base_params)
     assert set(merged.keys()) == set(base_params.keys())
 
 
-def test_apply_before_concrete_init_raises():
+def test_apply_without_the_frozen_base_raises():
     model = LoRAModel(_tiny_bert(), rank=2, alpha=8.0, target="attention")
-    with pytest.raises(RuntimeError, match="concrete init"):
+    with pytest.raises(ValueError, match="frozen"):
         model.apply({"params": {}}, jnp.zeros((1, 16), jnp.int32))
 
 
-def test_eval_shape_init_counts_adapters_without_binding():
+def test_eval_shape_init_counts_adapters_and_holds_no_base():
     model = LoRAModel(_tiny_bert(), rank=2, alpha=8.0, target="attention")
     shapes = jax.eval_shape(
         lambda d: model.init(jax.random.PRNGKey(0), d, train=False)[
@@ -162,7 +169,11 @@ def test_eval_shape_init_counts_adapters_without_binding():
     # 4 attention kernels at hidden 32: qkv (32x2 + 2x96) x2 blocks,
     # attn-out (32x2 + 2x32) x2 blocks
     assert n == 2 * ((32 * 2 + 2 * 96) + (32 * 2 + 2 * 32))
-    assert model._base_params is None  # abstract init must not bind
+    # the facade holds no array, before or after a concrete init: the
+    # base is data (init_frozen), never a constant of apply
+    model.init(jax.random.PRNGKey(0), jnp.zeros((1, 16), jnp.int32))
+    assert not any(isinstance(v, jax.Array) or isinstance(v, dict)
+                   for v in vars(model).values())
 
 
 def test_build_lora_model_rejects_unsupported_family():
@@ -299,8 +310,9 @@ def test_apply_decomposed_matches_merged_apply():
         lambda p, l: l + 0.02 if p[-1].key == "lora_b" else l, params
     )
     x = jax.random.randint(jax.random.PRNGKey(3), (4, 16), 0, 32)
-    merged = model.apply({"params": params}, x, train=False)
-    dec = model.apply_decomposed({"params": params}, x, train=False)
+    variables = {"params": params, "frozen": _frozen(model)}
+    merged = model.apply(variables, x, train=False)
+    dec = model.apply_decomposed(variables, x, train=False)
     np.testing.assert_allclose(
         np.asarray(dec), np.asarray(merged), atol=1e-6, rtol=2e-5
     )

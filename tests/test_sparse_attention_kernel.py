@@ -436,3 +436,58 @@ def test_compiled_for_a_v5e_the_scores_stay_in_the_kernels(chip_mesh,
     assert not re.search(rf"f32\[(1,)?4,8,512,{keys}\]", text)
     assert not re.search(rf"f32\[(1,)?32,512,{keys}\]", text)
     assert not re.search(rf"f32\[(1,)?16,512,{keys}\]", text)
+
+
+def test_compiled_for_a_v5e_latent_attentions_scores_stay_in_the_kernels(
+        chip_mesh, monkeypatch):
+    """A.X-K1's leading layer and one expert layer at the published
+    widths (2,048 tokens: four tiles of 512), bfloat16, adapters on a
+    frozen base, inside a manual ``clients`` region as the round engine
+    runs it, compiled for a described v5e: Mosaic accepts the three
+    latent-attention kernels' tiling (rope parts 64 wide, one rope key
+    for all heads, the statistics as lane vectors), each layer has its
+    forward kernel once (kept through the rematerialisation) and its two
+    backward kernels, and no float32 buffer of the program has the shape
+    of a tile row's scores over the heads."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from colearn_federated_learning_tpu.models.lora import build_lora_model
+
+    monkeypatch.setattr(sparse_attention, "_interpret", lambda: False)
+    model = build_lora_model(
+        build_model("axk1_decoder", 0, seq_len=2048, layers=2,
+                    vocab_size=1024, experts_held=2,
+                    compute_dtype=jnp.bfloat16, param_dtype=jnp.bfloat16),
+        "axk1_decoder", rank=16, alpha=32.0, target="attention",
+        adapter_dtype=jnp.bfloat16)
+    everywhere = NamedSharding(chip_mesh, P())
+    tokens = jax.ShapeDtypeStruct(
+        (1, 2048), jnp.int32, sharding=NamedSharding(chip_mesh, P("clients")))
+    dummy = jnp.zeros((1, 2048), jnp.int32)
+    abstract = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=everywhere),
+        tree)
+    frozen = abstract(jax.eval_shape(
+        lambda: model.init_frozen(jax.random.PRNGKey(0), dummy)))
+    adapters = abstract(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), dummy)["params"]))
+
+    def lane(adapters, frozen, tokens):
+        adapters = jax.lax.pcast(adapters, ("clients",), to="varying")
+
+        def loss(adapters):
+            logits, _ = model.apply({"params": adapters, "frozen": frozen},
+                                    tokens, train=True)
+            return logits.mean()
+
+        return jax.lax.psum(jax.value_and_grad(loss)(adapters), "clients")
+
+    step = jax.jit(jax.shard_map(
+        lane, mesh=chip_mesh, in_specs=(P(), P(), P("clients")),
+        out_specs=P()))
+    text = step.lower(adapters, frozen, tokens).compile().as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 3 * 2
+    assert not re.search(r"f32\[(1,)?64,512,(512|1024|1536|2048)\]", text)
+    # the base is a parameter of the program, not a constant of it
+    assert not re.search(r"= bf16\[7168,18432\]\S* constant\(", text)
+    assert re.search(r"= bf16\[7168,18432\]\S* parameter\(", text)
