@@ -424,6 +424,75 @@ def check_index_scores() -> None:
                        ATTN_TOL["bfloat16"])
 
 
+def check_expert_ffn() -> None:
+    """``ops/moe.expert_ffn``'s kernels, natively, at the Keye decoder's
+    widths (2,048 tokens, 16 held of 64 experts of ``[2048, 768]``, 8 a
+    token, tiles of 256 rows, bf16): the result and the four gradients,
+    and the frozen form's rows' gradient, against every held expert
+    computed densely over every token and weighted by a ``[tokens,
+    held]`` matrix of gates, in XLA on the same bf16 inputs; once as the
+    decoders run it and once with the tiles in use split over several
+    kernel calls, as they are when the held experts draw many times
+    their share."""
+    import jax
+    import jax.numpy as jnp
+
+    from colearn_federated_learning_tpu.ops import moe
+
+    t, d, f, held, tile = 2048, 2048, 768, 16, 256
+    ks = jax.random.split(jax.random.PRNGKey(30), 6)
+    h = jax.random.normal(ks[0], (t, d), jnp.bfloat16)
+    router = jax.random.normal(ks[1], (d, 64), jnp.bfloat16) * 0.02
+    w = [jax.random.normal(k, s, jnp.bfloat16) * 0.02 for k, s in
+         zip(ks[2:5], ((held, d, f), (held, d, f), (held, f, d)))]
+    ct = jax.random.normal(ks[5], (t, d), jnp.float32)
+    disp = moe.route(h, router, top_k=8, experts_held=held, expert_offset=8,
+                     tile=tile)
+    tables = (disp.row_token, disp.row_gate, disp.tile_expert, disp.n_tiles)
+    gates = jnp.zeros((t, held), jnp.float32).at[
+        disp.row_token, jnp.repeat(disp.tile_expert, tile)].add(disp.row_gate)
+
+    def dense(h, w1, w3, w2):
+        def one(y, e):
+            mid = (jax.nn.silu(jnp.dot(h, w1[e],
+                                       preferred_element_type=jnp.float32))
+                   * jnp.dot(h, w3[e], preferred_element_type=jnp.float32))
+            out = jnp.dot(mid.astype(h.dtype), w2[e],
+                          preferred_element_type=jnp.float32)
+            return y + out * gates[:, e, None], None
+        y, _ = jax.lax.scan(one, jnp.zeros((t, d), jnp.float32),
+                            jnp.arange(held))
+        return y.astype(h.dtype)
+
+    def both(fn):
+        def loss(*a):
+            out = fn(*a)
+            return (out.astype(jnp.float32) * ct).sum(), out
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2, 3), has_aux=True))
+
+    (_, want), want_g = both(dense)(h, *w)
+    # in one kernel call a pass (the tiles in use fit it), and in calls of
+    # four tiles, where an expert's sums pass from call to call
+    for rows_bytes, how in ((moe._ROWS_BYTES, "one call"),
+                            (4 * tile * d * 2, "calls of 4 tiles")):
+        moe._ROWS_BYTES, kept = rows_bytes, moe._ROWS_BYTES
+        try:
+            (_, out), grads = both(
+                lambda *a: moe.expert_ffn(*a, *tables))(h, *w)
+            (_, _), frozen = both(
+                lambda *a: moe.expert_ffn_frozen(*a, *tables))(h, *w)
+        finally:
+            moe._ROWS_BYTES = kept
+        for name, g, wg in zip(
+                ("out", "dh", "dw1", "dw3", "dw2", "frozen dh"),
+                (out, *grads, frozen[0]), (want, *want_g, want_g[0])):
+            size = float(jnp.abs(wg.astype(jnp.float32)).max())
+            _require_close(f"expert_ffn, {how}: {name} / {size:.3g}",
+                           g.astype(jnp.float32) / size,
+                           wg.astype(jnp.float32) / size,
+                           ATTN_TOL["bfloat16"])
+
+
 def check_latent_attention() -> None:
     """``ops/latent_attention.causal_attention``'s three kernels,
     natively, at A.X-K1's widths (2,048 positions in tiles of 512, 8 of
@@ -527,6 +596,7 @@ def main() -> int:
     check_selected_attention()
     check_index_scores()
     check_latent_attention()
+    check_expert_ffn()
 
     say(f"total wall {time.time() - t_start:.1f}s")
     print(json.dumps({"ok": True, "device": {

@@ -81,9 +81,11 @@ REGISTRY: Dict[str, RecordSpec] = {
             "hier_edge_excluded", "hier_core_upload_bytes",
             "byzantine_count", "consensus_dist", "rounds_per_sec",
             "client_updates_per_sec_per_chip", "eval_loss", "eval_acc",
-            # RoundMetrics.aux: the Keye decoder's counters (models/keye.py)
+            # RoundMetrics.aux: the decoders' counters (models/keye.py,
+            # models/axk1.py)
             "indexer_loss", "held_assignment_share",
             "expert_load_max_over_mean", "selected_key_share",
+            "held_group_hit_share", "expert_tile_fill",
         ),
         doc="per-round metrics (driver flush windows)",
     ),

@@ -64,12 +64,13 @@ from colearn_federated_learning_tpu.models import _INPUT_SPECS, model_registry
 from colearn_federated_learning_tpu.models.keye import (
     _dense,
     apply_rope,
+    expert_stack,
     rms_norm,
 )
 from colearn_federated_learning_tpu.ops import latent_attention, moe
 
 AUX_COUNTERS = ("held_assignment_share", "expert_load_max_over_mean",
-                "held_group_hit_share")
+                "held_group_hit_share", "expert_tile_fill")
 
 
 class AXK1Dims(NamedTuple):
@@ -171,7 +172,7 @@ def attention_block(p, ad, x, angles, d: AXK1Dims, lora_scale: float):
         return proj(out.reshape(t, d.heads * d.v_dim), "wo")
 
 
-def expert_block(p, h, d: AXK1Dims, frozen: bool):
+def expert_block(p, h, d: AXK1Dims, frozen: bool, stack=None):
     """The shared expert, whole, plus this chip's share of the routed
     layer, for one normed sequence ``h``; and the layer's counters."""
     with jax.named_scope("moe_shared"):
@@ -187,7 +188,7 @@ def expert_block(p, h, d: AXK1Dims, frozen: bool):
         ffn = moe.expert_ffn_frozen if frozen else moe.expert_ffn
         y = ffn(h, p["w1"].astype(cd), p["w3"].astype(cd),
                 p["w2"].astype(cd), disp.row_token, disp.row_gate,
-                disp.tile_expert, disp.n_tiles)
+                disp.tile_expert, disp.n_tiles, stack)
         y = checkpoint_name(y, "moe_out")
     counts = disp.counts.astype(jnp.float32)
     per_group = d.num_experts // d.n_group
@@ -196,7 +197,7 @@ def expert_block(p, h, d: AXK1Dims, frozen: bool):
     hit = ((disp.groups >= lo) & (disp.groups <= hi)).any(-1)
     return shared + y, jnp.stack([
         disp.held_share, counts.max() / jnp.maximum(counts.mean(), 1.0),
-        hit.mean(dtype=jnp.float32)])
+        hit.mean(dtype=jnp.float32), moe.tile_fill(disp, d.moe_tile)])
 
 
 def dense_layer(p, ad, x, angles, d: AXK1Dims, lora_scale: float):
@@ -206,12 +207,12 @@ def dense_layer(p, ad, x, angles, d: AXK1Dims, lora_scale: float):
         return x + _swiglu(h, p["w1"], p["w3"], p["w2"])
 
 
-def expert_layer(p, ad, x, angles, d: AXK1Dims, lora_scale: float,
+def expert_layer(p, ad, stack, x, angles, d: AXK1Dims, lora_scale: float,
                  frozen: bool):
     """One expert layer on one sequence: (x, its ``AUX_COUNTERS``)."""
     x = x + attention_block(p, ad, x, angles, d, lora_scale)
     y, stats = expert_block(p, rms_norm(x, p["mlp_norm"], d.rms_eps), d,
-                            frozen)
+                            frozen, stack)
     return x + y, stats
 
 
@@ -309,9 +310,12 @@ class AXK1DecoderLM(nn.Module):
                 partial(expert_layer, d=d, lora_scale=lora_scale,
                         frozen=frozen), policy=keep)
             x, stats = jax.lax.scan(
-                lambda x, pa: jax.vmap(layer, in_axes=(None, None, 0, None))(
-                    *pa, x, angles),
-                x, (stacked, ad_stacked))  # stats: [layers, B, counters]
+                lambda x, pal: jax.vmap(
+                    layer, in_axes=(None, None, None, 0, None))(
+                        *pal[:2], expert_stack(stacked, pal[2], x.dtype), x,
+                        angles),
+                x, (stacked, ad_stacked, jnp.arange(n_moe)))
+            # stats: [layers, B, counters]
         final_norm = self.param("final_norm", nn.initializers.ones,
                                 (d.hidden,), self.param_dtype)
         head = self.param("head", _normal(0.02), (d.hidden, self.vocab_size),

@@ -59,7 +59,8 @@ from colearn_federated_learning_tpu.models import _INPUT_SPECS, model_registry
 from colearn_federated_learning_tpu.ops import moe, sparse_attention
 
 AUX_COUNTERS = ("indexer_loss", "held_assignment_share",
-                "expert_load_max_over_mean", "selected_key_share")
+                "expert_load_max_over_mean", "selected_key_share",
+                "expert_tile_fill")
 
 
 class KeyeDims(NamedTuple):
@@ -224,7 +225,19 @@ def attention_block(p, x, angles, index_angles, d: KeyeDims):
     return out, loss / t, selected / (t * (t + 1) // 2)
 
 
-def expert_block(p, x, d: KeyeDims):
+def expert_stack(stacked, layer, dtype):
+    """``ops/moe.expert_ffn``'s ``stack`` for layer ``layer`` of a scan
+    over the leaves ``stacked``: the three expert weights of all layers,
+    as constants of the scan, where the kernels can read them as they
+    are stored (``None`` where they compute in another dtype: the cast
+    is then a copy in any case)."""
+    if stacked["w1"].dtype != dtype:
+        return None
+    return (*(jax.lax.stop_gradient(stacked[n]) for n in ("w1", "w3", "w2")),
+            layer)
+
+
+def expert_block(p, x, d: KeyeDims, stack=None):
     """This chip's share of the sparse-expert layer for one sequence."""
     h = rms_norm(x, p["mlp_norm"], d.rms_eps)
     with jax.named_scope("moe_route"):
@@ -235,18 +248,20 @@ def expert_block(p, x, d: KeyeDims):
         cd = h.dtype
         y = moe.expert_ffn(h, p["w1"].astype(cd), p["w3"].astype(cd),
                            p["w2"].astype(cd), disp.row_token, disp.row_gate,
-                           disp.tile_expert, disp.n_tiles)
+                           disp.tile_expert, disp.n_tiles, stack)
         y = checkpoint_name(y, "moe_out")
     counts = disp.counts.astype(jnp.float32)
-    return y, disp.held_share, counts.max() / jnp.maximum(counts.mean(), 1.0)
+    return (y, disp.held_share,
+            counts.max() / jnp.maximum(counts.mean(), 1.0),
+            moe.tile_fill(disp, d.moe_tile))
 
 
-def decoder_layer(p, x, angles, index_angles, d: KeyeDims):
+def decoder_layer(p, stack, x, angles, index_angles, d: KeyeDims):
     """One layer on one sequence: (x, the layer's ``AUX_COUNTERS``)."""
     att, loss, selected_share = attention_block(p, x, angles, index_angles, d)
     x = x + att
-    y, held_share, load = expert_block(p, x, d)
-    return x + y, jnp.stack([loss, held_share, load, selected_share])
+    y, held_share, load, fill = expert_block(p, x, d, stack)
+    return x + y, jnp.stack([loss, held_share, load, selected_share, fill])
 
 
 class KeyeDecoderLM(nn.Module):
@@ -327,9 +342,11 @@ class KeyeDecoderLM(nn.Module):
             stats = jnp.ones((self.layers, b, len(AUX_COUNTERS)), jnp.float32)
         else:
             x, stats = jax.lax.scan(
-                lambda x, p: jax.vmap(layer, in_axes=(None, 0, 0, 0))(
-                    p, x, angles, index_angles),
-                x, stacked)  # stats: [layers, B, counters]
+                lambda x, pl: jax.vmap(layer, in_axes=(None, None, 0, 0, 0))(
+                    pl[0], expert_stack(stacked, pl[1], x.dtype), x, angles,
+                    index_angles),
+                x, (stacked, jnp.arange(self.layers)))
+            # stats: [layers, B, counters]
         final_norm = self.param("final_norm", nn.initializers.ones,
                                 (d.hidden,), self.param_dtype)
         head = self.param("head", normal, (d.hidden, self.vocab_size),

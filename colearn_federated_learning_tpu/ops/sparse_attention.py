@@ -249,22 +249,24 @@ def _tiled(q, k, v, keep, block_q: int, block_k: int):
             keep8.shape[0] // bq, keep8.shape[1] // bk)
 
 
-def _call(name, kernel, ins, outs, **grid):
+def _call(name, kernel, ins, outs, semantics=None, **grid):
     """``pl.pallas_call`` of ``kernel`` (``name`` is what a device trace
     calls it) on ``ins`` with outputs ``outs`` (``(shape, dtype)`` each);
     the tiles a kernel accumulates over, keys or groups, are its
-    innermost grid axis. Inside a manual mesh
-    region (the round engine's client lanes) the interpreter cannot type
-    the kernel's constants against operands that vary over the mesh, so
-    off the chip every lane runs the call on all lanes' operands, which
-    do not vary, and keeps its own result."""
+    innermost grid axis unless ``semantics`` says otherwise. Inside a
+    manual mesh region (the round engine's client lanes) the interpreter
+    cannot type the kernel's constants against operands that vary over
+    the mesh, so off the chip every lane runs the call on all lanes'
+    operands, which do not vary, and keeps its own result."""
+    if semantics is None:
+        semantics = ("parallel",) * (len(grid["grid"]) - 1) + ("arbitrary",)
+
     def call(*ins):
         return pl.pallas_call(
             kernel, name=name,
             out_shape=[out_struct(*o, ins) for o in outs],
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel",) * (len(grid["grid"]) - 1)
-                + ("arbitrary",),
+                dimension_semantics=semantics,
                 vmem_limit_bytes=_VMEM_LIMIT),
             interpret=_interpret(), **grid)(*ins)
 

@@ -369,9 +369,12 @@ def _model_kwargs_section(name: str, blurb: str):
 
     from colearn_federated_learning_tpu.models import model_registry
 
+    factory = model_registry.get(name)
+    counters = ", ".join(f"`{c}`" for c in factory.aux_counters)
     lines = [f"## `model.kwargs` of `{name}`", "", blurb, "",
+             f"Counters in the round's metrics, per round: {counters}.", "",
              "| kwarg | default |", "|---|---|"]
-    for p in inspect.signature(model_registry.get(name)).parameters.values():
+    for p in inspect.signature(factory).parameters.values():
         if p.kind is p.VAR_KEYWORD or p.name in (
                 "num_classes", "compute_dtype", "param_dtype"):
             continue

@@ -6,6 +6,7 @@ client/trainer.RoundData, the driver) against the plain reference
 which 2 are kept and 4 held, top 4, one dense and two expert layers,
 T 64, adapters of rank 4."""
 
+import functools
 import importlib.util
 import os
 import re
@@ -286,12 +287,13 @@ def test_frozen_experts_give_the_trained_forms_row_gradients_and_no_more():
     np.testing.assert_array_equal(frozen[4], trained[4])  # gates
     for got, had in zip(frozen[1:4], trained[1:4]):
         assert not np.any(np.asarray(got)) and np.any(np.asarray(had))
-    # and its loop runs five products a tile (two of them the forward's,
-    # again) where the trained form runs eight
+    # and its kernel runs five products a tile (two of them the forward's,
+    # again) where the trained form's runs eight
     dy = jnp.ones_like(h)
-    for fn, products in ((moe._experts_backward_rows, 5),
-                         (moe._experts_backward, 8)):
-        text = str(jax.make_jaxpr(fn)(h, *w, *tables, dy))
+    for call, products in ((moe._backward_rows, 5),
+                           (moe._backward_trained, 8)):
+        text = str(jax.make_jaxpr(functools.partial(call, 4))(
+            h, *w, *tables[:2], tables[2], tables[2], tables[3], dy))
         assert text.count("dot_general") == products
 
 

@@ -328,6 +328,9 @@ def test_local_metrics_carry_the_counters_and_only_for_this_model(setup):
     assert set(metrics.aux) == set(model.aux_counters)
     lm, li = _model_losses(model, params, tokens[:1], targets[:1])
     assert float(metrics.aux["indexer_loss"]) > 0
+    assert model.aux_counters[0] == "indexer_loss"  # the trainer's index
+    assert model.aux_counters[-1] == "expert_tile_fill"
+    assert 0.0 < float(metrics.aux["expert_tile_fill"]) <= 1.0
     plain = make_local_train_fn(
         build_model("bert_tiny", 0, **_BERT), ClientConfig(), DPConfig(),
         "lm")

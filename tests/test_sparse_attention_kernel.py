@@ -378,7 +378,11 @@ def test_a_chunks_index_scores_are_computed_once_a_pass():
                      "attn_index_backward": chunks,
                      "attn_sparse_forward": chunks,
                      "attn_sparse_head_mean": chunks,
-                     "attn_sparse_backward": chunks}
+                     "attn_sparse_backward": chunks,
+                     # the held experts, a row kernel and the combining
+                     # kernel a pass (ops/moe.py)
+                     "moe_experts_forward": 1, "moe_experts_backward": 1,
+                     "moe_experts_combine": 2}
 
 
 @pytest.fixture(scope="module")
@@ -393,6 +397,19 @@ def chip_mesh():
     except Exception as e:  # no TPU compiler in this installation
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     return jax.sharding.Mesh(np.array(topo.devices[:1]), ("clients",))
+
+
+def _experts_meet_their_weights_in_the_kernels(text, held, d, f):
+    """Under ``moe_experts`` nothing but the kernels touches the stacked
+    weights: no copy, slice or relayout of ``[held, d, f]`` / ``[held,
+    f, d]`` (``vmap``'s per-element slice, a loop's ``w[e]``), and no
+    one expert's ``[d, f]`` cut out of them (a bitcast moves nothing)."""
+    stacked = rf"{held},(?:{d},{f}|{f},{d})"
+    assert re.search(rf"= bf16\[(1,)*{stacked}\]\S* parameter\(", text)
+    made = re.findall(
+        rf"= bf16\[(?:1,)*(?:{stacked}|{d},{f})\]\S* ([a-z\-]+)\(.*"
+        r"op_name=\"[^\"]*moe_experts", text)
+    assert set(made) <= {"broadcast", "get-tuple-element", "bitcast"}, made
 
 
 def test_compiled_for_a_v5e_the_scores_stay_in_the_kernels(chip_mesh,
@@ -431,7 +448,10 @@ def test_compiled_for_a_v5e_the_scores_stay_in_the_kernels(chip_mesh,
     step = jax.jit(jax.shard_map(lane, mesh=chip_mesh,
                                  in_specs=(P(), P("clients")), out_specs=P()))
     text = step.lower(params, tokens).compile().as_text()
-    assert text.count("custom_call_target=\"tpu_custom_call\"") == 6 * 4
+    # and the held experts' forward (kept through the rematerialisation)
+    # and backward, a row kernel and the combining kernel each
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 6 * 4 + 4
+    _experts_meet_their_weights_in_the_kernels(text, 16, 2048, 768)
     keys = "(512|1024|1536|2048)"  # what a chunk sees here
     assert not re.search(rf"f32\[(1,)?4,8,512,{keys}\]", text)
     assert not re.search(rf"f32\[(1,)?32,512,{keys}\]", text)
@@ -456,8 +476,8 @@ def test_compiled_for_a_v5e_latent_attentions_scores_stay_in_the_kernels(
     monkeypatch.setattr(sparse_attention, "_interpret", lambda: False)
     model = build_lora_model(
         build_model("axk1_decoder", 0, seq_len=2048, layers=2,
-                    vocab_size=1024, experts_held=2,
-                    compute_dtype=jnp.bfloat16, param_dtype=jnp.bfloat16),
+                    vocab_size=1024, compute_dtype=jnp.bfloat16,
+                    param_dtype=jnp.bfloat16),
         "axk1_decoder", rank=16, alpha=32.0, target="attention",
         adapter_dtype=jnp.bfloat16)
     everywhere = NamedSharding(chip_mesh, P())
@@ -486,7 +506,10 @@ def test_compiled_for_a_v5e_latent_attentions_scores_stay_in_the_kernels(
         lane, mesh=chip_mesh, in_specs=(P(), P(), P("clients")),
         out_specs=P()))
     text = step.lower(adapters, frozen, tokens).compile().as_text()
-    assert text.count("custom_call_target=\"tpu_custom_call\"") == 3 * 2
+    # and the frozen held experts' forward and backward, a row kernel and
+    # the combining kernel each
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 3 * 2 + 4
+    _experts_meet_their_weights_in_the_kernels(text, 12, 7168, 2048)
     assert not re.search(r"f32\[(1,)?64,512,(512|1024|1536|2048)\]", text)
     # the base is a parameter of the program, not a constant of it
     assert not re.search(r"= bf16\[7168,18432\]\S* constant\(", text)
