@@ -425,72 +425,155 @@ def check_index_scores() -> None:
 
 
 def check_expert_ffn() -> None:
-    """``ops/moe.expert_ffn``'s kernels, natively, at the Keye decoder's
-    widths (2,048 tokens, 16 held of 64 experts of ``[2048, 768]``, 8 a
-    token, tiles of 256 rows, bf16): the result and the four gradients,
-    and the frozen form's rows' gradient, against every held expert
-    computed densely over every token and weighted by a ``[tokens,
-    held]`` matrix of gates, in XLA on the same bf16 inputs; once as the
+    """``ops/moe.expert_ffn``'s kernels, natively, bf16, tiles of 256
+    rows: the result and the four gradients, and the frozen form's rows'
+    gradient, against every held expert computed densely over every
+    token and weighted by a ``[tokens, held]`` matrix of gates, in XLA on
+    the same bf16 inputs. At the Keye decoder's widths (2,048 tokens, 16
+    held of 64 experts of ``[2048, 768]``, 8 a token) once as the
     decoders run it and once with the tiles in use split over several
     kernel calls, as they are when the held experts draw many times
-    their share."""
+    their share; and at Mellum2's (16,384 tokens, 8 held of 64 experts
+    of ``[2304, 896]``: widths of 18 and 7 lanes, the float32 result in
+    two blocks of 1,152 columns, and 64 to 72 tiles in use where a call
+    has 56 slots, so every pass takes a second call as it is)."""
     import jax
     import jax.numpy as jnp
 
     from colearn_federated_learning_tpu.ops import moe
 
-    t, d, f, held, tile = 2048, 2048, 768, 16, 256
-    ks = jax.random.split(jax.random.PRNGKey(30), 6)
-    h = jax.random.normal(ks[0], (t, d), jnp.bfloat16)
-    router = jax.random.normal(ks[1], (d, 64), jnp.bfloat16) * 0.02
-    w = [jax.random.normal(k, s, jnp.bfloat16) * 0.02 for k, s in
-         zip(ks[2:5], ((held, d, f), (held, d, f), (held, f, d)))]
-    ct = jax.random.normal(ks[5], (t, d), jnp.float32)
-    disp = moe.route(h, router, top_k=8, experts_held=held, expert_offset=8,
-                     tile=tile)
-    tables = (disp.row_token, disp.row_gate, disp.tile_expert, disp.n_tiles)
-    gates = jnp.zeros((t, held), jnp.float32).at[
-        disp.row_token, jnp.repeat(disp.tile_expert, tile)].add(disp.row_gate)
+    tile = 256
+    for t, d, f, held, offset, split in ((2048, 2048, 768, 16, 8, True),
+                                         (16384, 2304, 896, 8, 0, False)):
+        ks = jax.random.split(jax.random.PRNGKey(30), 6)
+        h = jax.random.normal(ks[0], (t, d), jnp.bfloat16)
+        router = jax.random.normal(ks[1], (d, 64), jnp.bfloat16) * 0.02
+        w = [jax.random.normal(k, s, jnp.bfloat16) * 0.02 for k, s in
+             zip(ks[2:5], ((held, d, f), (held, d, f), (held, f, d)))]
+        ct = jax.random.normal(ks[5], (t, d), jnp.float32)
+        disp = moe.route(h, router, top_k=8, experts_held=held,
+                         expert_offset=offset, tile=tile)
+        tables = (disp.row_token, disp.row_gate, disp.tile_expert,
+                  disp.n_tiles)
+        gates = jnp.zeros((t, held), jnp.float32).at[
+            disp.row_token, jnp.repeat(disp.tile_expert, tile)].add(
+                disp.row_gate)
+        slots = moe._calls(h, *tables[:2], disp.tile_expert,
+                           disp.tile_expert)[1]
+        say(f"expert_ffn [{d}, {f}] x {held}, {t} tokens: "
+            f"{int(disp.n_tiles)} tiles in use, {slots} slots a call")
+        if not split and int(disp.n_tiles) <= slots:
+            raise RuntimeError("the tiles in use fit one call: this case "
+                               "is there for the second call")
 
-    def dense(h, w1, w3, w2):
-        def one(y, e):
-            mid = (jax.nn.silu(jnp.dot(h, w1[e],
-                                       preferred_element_type=jnp.float32))
-                   * jnp.dot(h, w3[e], preferred_element_type=jnp.float32))
-            out = jnp.dot(mid.astype(h.dtype), w2[e],
-                          preferred_element_type=jnp.float32)
-            return y + out * gates[:, e, None], None
-        y, _ = jax.lax.scan(one, jnp.zeros((t, d), jnp.float32),
-                            jnp.arange(held))
-        return y.astype(h.dtype)
+        def dense(h, w1, w3, w2):
+            def one(y, e):
+                mid = (jax.nn.silu(jnp.dot(
+                    h, w1[e], preferred_element_type=jnp.float32))
+                    * jnp.dot(h, w3[e], preferred_element_type=jnp.float32))
+                out = jnp.dot(mid.astype(h.dtype), w2[e],
+                              preferred_element_type=jnp.float32)
+                return y + out * gates[:, e, None], None
+            y, _ = jax.lax.scan(one, jnp.zeros((t, d), jnp.float32),
+                                jnp.arange(held))
+            return y.astype(h.dtype)
+
+        def both(fn):
+            def loss(*a):
+                out = fn(*a)
+                return (out.astype(jnp.float32) * ct).sum(), out
+            return jax.jit(jax.value_and_grad(loss, (0, 1, 2, 3),
+                                              has_aux=True))
+
+        (_, want), want_g = both(dense)(h, *w)
+        # in one kernel call a pass (the tiles in use fit it), and in
+        # calls of four tiles, where an expert's sums pass from call to
+        # call; at Mellum2's sizes the calls as they come
+        cases = [(moe._ROWS_BYTES, "as it is")]
+        if split:
+            cases = [(moe._ROWS_BYTES, "one call"),
+                     (4 * tile * d * 2, "calls of 4 tiles")]
+        for rows_bytes, how in cases:
+            moe._ROWS_BYTES, kept = rows_bytes, moe._ROWS_BYTES
+            try:
+                (_, out), grads = both(
+                    lambda *a: moe.expert_ffn(*a, *tables))(h, *w)
+                (_, _), frozen = both(
+                    lambda *a: moe.expert_ffn_frozen(*a, *tables))(h, *w)
+            finally:
+                moe._ROWS_BYTES = kept
+            for name, g, wg in zip(
+                    ("out", "dh", "dw1", "dw3", "dw2", "frozen dh"),
+                    (out, *grads, frozen[0]), (want, *want_g, want_g[0])):
+                size = float(jnp.abs(wg.astype(jnp.float32)).max())
+                _require_close(
+                    f"expert_ffn [{d}, {f}], {how}: {name} / {size:.3g}",
+                    g.astype(jnp.float32) / size,
+                    wg.astype(jnp.float32) / size, ATTN_TOL["bfloat16"])
+
+
+def check_band_attention() -> None:
+    """``ops/band_attention.band_attention``'s three kernels, natively,
+    at Mellum2's widths (16,384 positions in tiles of 512, 32 query heads
+    over 4 key-value heads of 128, bf16), under the sliding layers' band
+    of 1,024 and over the whole triangle: the output and the three
+    gradients against a softmax over float32 scores under a mask that is
+    an array, a block of 1,024 queries at a time, in XLA on the same
+    bf16 inputs."""
+    import jax
+    import jax.numpy as jnp
+
+    from colearn_federated_learning_tpu.ops.band_attention import (
+        band_attention,
+    )
+
+    t, heads, kv, hd, rows = 16384, 32, 4, 128, 1024
+    ks = jax.random.split(jax.random.PRNGKey(31), 4)
+    q = jax.random.normal(ks[0], (t, heads, hd), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (t, kv, hd), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (t, kv, hd), jnp.bfloat16)
+    ct = jax.random.normal(ks[3], (t, heads, hd), jnp.float32)
+
+    def blockwise(window):
+        def dense(q, k, v):
+            kr, vr = (jnp.repeat(a, heads // kv, axis=1) for a in (k, v))
+
+            @jax.checkpoint
+            def block(q_b, lo):
+                s = jnp.einsum("qhd,khd->hqk", q_b, kr,
+                               preferred_element_type=jnp.float32) * hd ** -0.5
+                ahead = (lo + jnp.arange(rows))[:, None] - jnp.arange(t)
+                keep = ahead >= 0
+                if window is not None:
+                    keep &= ahead < window
+                p = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), -1)
+                return jnp.einsum("hqk,khd->qhd", p.astype(v.dtype), vr,
+                                  preferred_element_type=jnp.float32
+                                  ).astype(v.dtype)
+
+            out = jax.lax.map(lambda a: block(*a), (
+                q.reshape(t // rows, rows, heads, hd),
+                jnp.arange(0, t, rows)))
+            return out.reshape(t, heads, hd)
+        return dense
 
     def both(fn):
         def loss(*a):
             out = fn(*a)
             return (out.astype(jnp.float32) * ct).sum(), out
-        return jax.jit(jax.value_and_grad(loss, (0, 1, 2, 3), has_aux=True))
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2), has_aux=True))
 
-    (_, want), want_g = both(dense)(h, *w)
-    # in one kernel call a pass (the tiles in use fit it), and in calls of
-    # four tiles, where an expert's sums pass from call to call
-    for rows_bytes, how in ((moe._ROWS_BYTES, "one call"),
-                            (4 * tile * d * 2, "calls of 4 tiles")):
-        moe._ROWS_BYTES, kept = rows_bytes, moe._ROWS_BYTES
-        try:
-            (_, out), grads = both(
-                lambda *a: moe.expert_ffn(*a, *tables))(h, *w)
-            (_, _), frozen = both(
-                lambda *a: moe.expert_ffn_frozen(*a, *tables))(h, *w)
-        finally:
-            moe._ROWS_BYTES = kept
-        for name, g, wg in zip(
-                ("out", "dh", "dw1", "dw3", "dw2", "frozen dh"),
-                (out, *grads, frozen[0]), (want, *want_g, want_g[0])):
-            size = float(jnp.abs(wg.astype(jnp.float32)).max())
-            _require_close(f"expert_ffn, {how}: {name} / {size:.3g}",
+    for window in (1024, None):
+        (_, out), grads = both(
+            lambda *a: band_attention(*a, window, hd ** -0.5, 512))(q, k, v)
+        (_, want), want_g = both(blockwise(window))(q, k, v)
+        label = f"band attention, window {window}"
+        _require_close(f"{label}: out", out, want, ATTN_TOL["bfloat16"])
+        for name, g, w in zip(("dq", "dk", "dv"), grads, want_g):
+            size = float(jnp.abs(w.astype(jnp.float32)).max())
+            _require_close(f"{label}: {name} / {size:.3g}",
                            g.astype(jnp.float32) / size,
-                           wg.astype(jnp.float32) / size,
-                           ATTN_TOL["bfloat16"])
+                           w.astype(jnp.float32) / size, ATTN_TOL["bfloat16"])
 
 
 def check_latent_attention() -> None:
@@ -596,6 +679,7 @@ def main() -> int:
     check_selected_attention()
     check_index_scores()
     check_latent_attention()
+    check_band_attention()
     check_expert_ffn()
 
     say(f"total wall {time.time() - t_start:.1f}s")

@@ -82,10 +82,10 @@ REGISTRY: Dict[str, RecordSpec] = {
             "byzantine_count", "consensus_dist", "rounds_per_sec",
             "client_updates_per_sec_per_chip", "eval_loss", "eval_acc",
             # RoundMetrics.aux: the decoders' counters (models/keye.py,
-            # models/axk1.py)
+            # models/axk1.py, models/mellum2.py)
             "indexer_loss", "held_assignment_share",
             "expert_load_max_over_mean", "selected_key_share",
-            "held_group_hit_share", "expert_tile_fill",
+            "held_group_hit_share", "expert_tile_fill", "band_pair_share",
         ),
         doc="per-round metrics (driver flush windows)",
     ),

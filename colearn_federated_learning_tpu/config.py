@@ -3101,6 +3101,37 @@ def _axk1_silo_lora() -> ExperimentConfig:
     )
 
 
+def _mellum2_silo_lm() -> ExperimentConfig:
+    """Cross-silo FedAvg on the decoder of Mellum2-12B-A2.5B as one chip
+    of an 8-way expert-parallel deployment holds it (models/mellum2.py:
+    one whole period of its layers, three sliding-window layers and one
+    full layer with YaRN's rope, 8 of 64 experts, an eighth of the
+    vocabulary; every width as published), trained in full: 8 silos
+    continue training on private repositories, 2 local AdamW steps of
+    one 16,384-token sequence per round. Spatial layout, no DP, no LoRA
+    (validate() names what this model does not support)."""
+    return ExperimentConfig(
+        name="mellum2_silo_lm",
+        algorithm="fedavg",
+        model=ModelConfig(
+            name="mellum2_decoder",
+            num_classes=0,
+            kwargs={"vocab_size": 12288, "seq_len": 16384, "layers": 4,
+                    "experts_held": 8},
+        ),
+        data=DataConfig(
+            name="synthetic_text",
+            num_clients=8,
+            partition="silo",
+            max_examples_per_client=2,
+        ),
+        client=ClientConfig(local_epochs=1, batch_size=1, lr=1e-4,
+                            optimizer="adamw", weight_decay=0.01),
+        server=ServerConfig(num_rounds=100, cohort_size=8, eval_every=0),
+        run=RunConfig(compute_dtype="bfloat16", local_param_dtype="bfloat16"),
+    )
+
+
 _NAMED = {
     "mnist_fedavg_2": _mnist_fedavg_2,
     "cifar10_fedavg_100": _cifar10_fedavg_100,
@@ -3114,6 +3145,7 @@ _NAMED = {
     "vit_lora_dp": _vit_lora_dp,
     "keye_silo_lm": _keye_silo_lm,
     "axk1_silo_lora": _axk1_silo_lora,
+    "mellum2_silo_lm": _mellum2_silo_lm,
 }
 
 

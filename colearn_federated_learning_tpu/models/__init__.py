@@ -110,3 +110,4 @@ from colearn_federated_learning_tpu.models import vit  # noqa: E402,F401
 from colearn_federated_learning_tpu.models import lstm  # noqa: E402,F401
 from colearn_federated_learning_tpu.models import keye  # noqa: E402,F401
 from colearn_federated_learning_tpu.models import axk1  # noqa: E402,F401
+from colearn_federated_learning_tpu.models import mellum2  # noqa: E402,F401

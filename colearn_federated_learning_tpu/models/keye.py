@@ -118,10 +118,14 @@ def mrope_angles(positions, dim: int, theta: float, sections):
     return jnp.take_along_axis(ang, stream[None, None, :], axis=0)[0]
 
 
-def apply_rope(x, angles):
-    """Rotate-half on the last axis of ``x`` ``[T, ..., dim]``."""
+def apply_rope(x, angles, factor: float = 1.0):
+    """Rotate-half on the last axis of ``x`` ``[T, ..., dim]``; cosine
+    and sine are each multiplied by ``factor`` (YaRN's attention factor,
+    where a model has one) before they meet ``x``."""
     shape = (angles.shape[0],) + (1,) * (x.ndim - 2) + (angles.shape[1],)
     cos, sin = jnp.cos(angles).reshape(shape), jnp.sin(angles).reshape(shape)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     x32 = x.astype(jnp.float32)
     x1, x2 = jnp.split(x32, 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],

@@ -309,6 +309,7 @@ def config_reference_markdown() -> str:
             lines += [_THREAT_MODEL]
     lines += _model_kwargs_section("keye_decoder", _KEYE_KWARGS_BLURB)
     lines += _model_kwargs_section("axk1_decoder", _AXK1_KWARGS_BLURB)
+    lines += _model_kwargs_section("mellum2_decoder", _MELLUM2_KWARGS_BLURB)
     names = config_mod.list_named_configs()
     named = ", ".join(f"`{n}`" for n in names)
     lines += [
@@ -360,6 +361,29 @@ _AXK1_KWARGS_BLURB = (
     "`run.batch_shards > 1` (validate() names them); with "
     "`model.lora.enabled` everything but the adapters is frozen, "
     "without it the whole decoder trains."
+)
+
+
+_MELLUM2_KWARGS_BLURB = (
+    "The decoder of Mellum2-12B-A2.5B as one chip of an expert-parallel "
+    "deployment holds it (models/mellum2.py; named config "
+    "`mellum2_silo_lm`, trained in full). The defaults are the published "
+    "widths; `layers` (whole periods of `period`), `experts_held` (with "
+    "`expert_offset`) and `vocab_size` are the chip's share. `period` "
+    "lists the kinds of a period's layers: a `sliding` layer's query "
+    "reads itself and the `sliding_window - 1` positions before it and "
+    "turns by plain RoPE, a `full` layer's reads the whole causal "
+    "triangle and turns by YaRN's frequencies (`rope_factor`, "
+    "`rope_original`, `rope_beta_fast`, `rope_beta_slow`) with cosine "
+    "and sine times `rope_attention_factor`; both kinds run "
+    "ops/band_attention.py, which visits the band's tiles only. "
+    "`q_chunk` (the attention kernels' tile, both kinds) and `moe_tile` "
+    "are tilings that change no value. Where "
+    "`experts_held` is less than `num_experts` the gates are constants "
+    "of the backward pass (ops/moe.route). The model reports counters "
+    "and does not support `model.lora.enabled`, "
+    "`run.cohort_layout=megabatch`, `dp.enabled` or "
+    "`run.batch_shards > 1` (validate() names them)."
 )
 
 

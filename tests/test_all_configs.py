@@ -44,6 +44,23 @@ _SHRINK = {
         "model.kwargs.moe_tile": 4, "model.lora.rank": 4,
         "run.local_param_dtype": "",
     },
+    # Mellum2 at a toy size: two periods of (sliding, full), so the scan
+    # over periods is a loop; 40 positions under a window of 12 in tiles
+    # of 8, so the band leaves whole tiles out; 4 of 8 experts held: 24
+    # expert tiles at most, which one kernel call's slots hold (off the
+    # chip a kernel call gathers the lanes' operands with a psum, so the
+    # lanes may not differ in how many calls their tiles take)
+    "mellum2_silo_lm": {
+        "model.kwargs.vocab_size": 8, "model.kwargs.seq_len": 40,
+        "model.kwargs.layers": 4, "model.kwargs.period": ["sliding", "full"],
+        "model.kwargs.hidden": 32, "model.kwargs.heads": 4,
+        "model.kwargs.kv_heads": 2, "model.kwargs.head_dim": 8,
+        "model.kwargs.num_experts": 8, "model.kwargs.experts_held": 4,
+        "model.kwargs.experts_per_token": 2, "model.kwargs.expert_width": 16,
+        "model.kwargs.sliding_window": 12, "model.kwargs.rope_original": 16,
+        "model.kwargs.q_chunk": 8, "model.kwargs.moe_tile": 4,
+        "run.local_param_dtype": "",
+    },
     "cifar10_fedavg_100": {"data.num_clients": 16, "model.kwargs.width": 16},
     # the north-star config keeps its FULL 1000-client federation — the
     # point is sampling/partitioning/index-tensor behavior at that scale;

@@ -26,6 +26,7 @@ def test_named_configs_exist():
         "vit_lora_dp",
         "keye_silo_lm",  # PR 25: the sparse-expert language decoder
         "axk1_silo_lora",  # PR 29: adapters on a frozen latent-attention base
+        "mellum2_silo_lm",  # PR 31: layers in periods, banded attention
     ])
     for name in list_named_configs():
         cfg = get_named_config(name)

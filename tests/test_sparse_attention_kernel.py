@@ -514,3 +514,49 @@ def test_compiled_for_a_v5e_latent_attentions_scores_stay_in_the_kernels(
     # the base is a parameter of the program, not a constant of it
     assert not re.search(r"= bf16\[7168,18432\]\S* constant\(", text)
     assert re.search(r"= bf16\[7168,18432\]\S* parameter\(", text)
+
+
+def test_compiled_for_a_v5e_banded_attentions_scores_stay_in_the_kernels(
+        chip_mesh, monkeypatch):
+    """One period of Mellum2's layers (sliding, full) at the published
+    widths (2,048 tokens: four tiles of 512 under a window of 1,024),
+    bfloat16, inside a manual ``clients`` region as the round engine
+    runs it, compiled for a described v5e: Mosaic accepts the three
+    banded-attention kernels' tiling in both forms (a group's eight
+    query heads of 128 over one key-value head, the statistics as lane
+    vectors, a grid of three band steps and of four), each layer has its
+    forward kernel once (kept through the rematerialisation) and its two
+    backward kernels beside the held experts' four calls, experts of
+    [2304, 896] (18 and 7 lanes) fit the expert kernels' VMEM, and no
+    float32 buffer of the program has the shape of a tile row's scores
+    over the heads."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    monkeypatch.setattr(sparse_attention, "_interpret", lambda: False)
+    model = build_model("mellum2_decoder", 0, seq_len=2048, layers=2,
+                        period=("sliding", "full"), vocab_size=1024,
+                        compute_dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    everywhere = NamedSharding(chip_mesh, P())
+    tokens = jax.ShapeDtypeStruct(
+        (1, 2048), jnp.int32, sharding=NamedSharding(chip_mesh, P("clients")))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=everywhere),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 2048), jnp.int32))["params"]))
+
+    def lane(params, tokens):
+        params = jax.lax.pcast(params, ("clients",), to="varying")
+
+        def loss(params):
+            logits, _ = model.apply({"params": params}, tokens, train=True)
+            return logits.mean()
+
+        return jax.lax.psum(jax.value_and_grad(loss)(params), "clients")
+
+    step = jax.jit(jax.shard_map(lane, mesh=chip_mesh,
+                                 in_specs=(P(), P("clients")), out_specs=P()))
+    text = step.lower(params, tokens).compile().as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == (3 + 4) * 2
+    _experts_meet_their_weights_in_the_kernels(text, 8, 2304, 896)
+    assert not re.search(r"f32\[(1,)?(32|4,8),(512|2048),(512|1024|2048)\]",
+                         text)
