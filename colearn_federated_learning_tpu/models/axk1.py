@@ -41,9 +41,10 @@ Layout as ``models/keye.py``: activations ``[T, hidden]`` per sequence,
 the batch mapped over; layer 0 stands alone (``dense_<leaf>``), the
 identical expert layers are scanned over leaves stacked on a leading
 axis (``layers_<leaf>``); one layer is rematerialised at a time, and
-attention's output and log-sum-exp and the routed experts' output are
-kept (``attn_out``, ``attn_lse``, ``moe_out``) so that both run once per
-layer and step. Shared with ``keye.py``: ``rms_norm``, ``apply_rope``,
+attention's output and log-sum-exp and the routed experts' dispatch
+tables and output are kept (``attn_out``, ``attn_lse``,
+``moe_dispatch``, ``moe_out``) so that all three run once per layer and
+step. Shared with ``keye.py``: ``rms_norm``, ``apply_rope``,
 ``_dense``; its own: the projections, YaRN's frequencies, the two kinds
 of layer.
 """
@@ -296,7 +297,7 @@ class AXK1DecoderLM(nn.Module):
         ad_stacked = {k[len("layers_"):]: v for k, v in adapters.items()
                       if k.startswith("layers_")}
         keep = jax.checkpoint_policies.save_only_these_names(
-            "attn_out", "attn_lse", "moe_out")
+            "attn_out", "attn_lse", "moe_dispatch", "moe_out")
         if self.is_initializing():
             # shapes only: init need not run 4,096-token attention
             stats = jnp.ones((n_moe, b, len(AUX_COUNTERS)), jnp.float32)

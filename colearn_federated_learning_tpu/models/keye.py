@@ -33,10 +33,11 @@ over), attention in chunks of ``q_chunk`` queries against the keys the
 chunk can see. One layer is rematerialised at a time; the selection
 masks, attention's outputs, log-sum-exps and head-mean weights are
 kept (``checkpoint_name`` ``attn_select``, ``attn_out``, ``attn_lse``,
-``attn_weights``) and so is the expert layer's output (``moe_out``), so
-the bisection, attention's forward pass and the experts' run once per
-layer and step. A chunk's index scores are computed once in the forward
-pass (the selection and the value of the indexer's loss read the same
+``attn_weights``) and so are the expert layer's dispatch tables and
+output (``moe_dispatch``, ``moe_out``), so the bisection, attention's
+forward pass, the router with its top-k and sort, and the experts' run
+once per layer and step. A chunk's index scores are computed once in the
+forward pass (the selection and the value of the indexer's loss read the same
 ``[q_chunk, keys]`` float32 array) and once more in the backward pass,
 on the way to the loss's gradient; attention's and the indexer's
 backward kernels recompute the per-head scores of their own chunk, a
@@ -338,7 +339,7 @@ class KeyeDecoderLM(nn.Module):
             partial(decoder_layer, d=d),
             policy=jax.checkpoint_policies.save_only_these_names(
                 "attn_select", "attn_out", "attn_lse", "attn_weights",
-                "moe_out"),
+                "moe_dispatch", "moe_out"),
         )
         stacked = self._layer_params()
         if self.is_initializing():

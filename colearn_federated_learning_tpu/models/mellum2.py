@@ -31,12 +31,13 @@ the batch mapped over; every layer's leaves stacked on a leading
 ``layers`` axis (``layers_<leaf>``), scanned a *period* at a time: the
 scan's body holds the period's layers one after the other, each with its
 kind static and each rematerialised on its own, with attention's output
-and log-sum-exp and the experts' output kept (``attn_out``,
-``attn_lse``, ``moe_out``). The two angle tables of a sequence are built
-once, outside the scan. Shared with ``keye.py``: ``rms_norm``,
-``rope_angles``, ``apply_rope``, ``_dense``, ``expert_block``,
-``expert_stack``; with ``axk1.py``: ``yarn_inv_freq``; its own: the
-attention block, the period, the two tables.
+and log-sum-exp and the experts' dispatch tables and output kept
+(``attn_out``, ``attn_lse``, ``moe_dispatch``, ``moe_out``). The two
+angle tables of a sequence are built once, outside the scan. Shared
+with ``keye.py``: ``rms_norm``, ``rope_angles``, ``apply_rope``,
+``_dense``, ``expert_block``, ``expert_stack``; with ``axk1.py``:
+``yarn_inv_freq``; its own: the attention block, the period, the two
+tables.
 """
 
 from __future__ import annotations
@@ -165,7 +166,7 @@ class Mellum2DecoderLM(nn.Module):
                 self.full_inv_freq, jnp.float32),
         }
         keep = jax.checkpoint_policies.save_only_these_names(
-            "attn_out", "attn_lse", "moe_out")
+            "attn_out", "attn_lse", "moe_dispatch", "moe_out")
         layer = {kind: jax.checkpoint(
             partial(decoder_layer, d=d, kind=kind), policy=keep)
             for kind in KINDS}
