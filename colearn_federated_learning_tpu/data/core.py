@@ -25,6 +25,7 @@ import numpy as np
 
 from colearn_federated_learning_tpu.config import DataConfig
 from colearn_federated_learning_tpu.data import partition as partition_lib
+from colearn_federated_learning_tpu.obs.spans import _NULL_SPAN
 from colearn_federated_learning_tpu.utils.registry import Registry
 
 dataset_registry = Registry("dataset")
@@ -359,8 +360,18 @@ def _load_synthetic_text(cfg: DataConfig, vocab_size: int = 90,
 # ---------------------------------------------------------------------------
 
 
-def build_federated_data(cfg: DataConfig, seed: int = 0, **model_kwargs) -> FederatedData:
+def _no_span(name: str, **args):
+    return _NULL_SPAN
+
+
+def build_federated_data(cfg: DataConfig, seed: int = 0, *, span=_no_span,
+                         **model_kwargs) -> FederatedData:
     """Load a dataset and partition it into ``cfg.num_clients`` shards.
+
+    ``span`` is the caller's tracer's ``span`` (``Experiment`` passes
+    its own): the load and the partition are bracketed as
+    ``setup.data.load`` and ``setup.data.partition``. Without one
+    nothing is timed.
 
     With ``cfg.store.dir`` set the corpus comes from an on-disk client
     store instead (data/store.py): example bytes stay memory-mapped, the
@@ -371,26 +382,33 @@ def build_federated_data(cfg: DataConfig, seed: int = 0, **model_kwargs) -> Fede
     if cfg.store.dir:
         from colearn_federated_learning_tpu.data.store import open_store
 
-        return open_store(
-            cfg.store.dir, gather_workers=cfg.store.gather_workers
-        ).as_federated_data(
-            expected_clients=cfg.num_clients,
-            materialize=cfg.store.materialize,
-        )
+        with span("setup.data.load", dataset="store") as load:
+            fed = open_store(
+                cfg.store.dir, gather_workers=cfg.store.gather_workers
+            ).as_federated_data(
+                expected_clients=cfg.num_clients,
+                materialize=cfg.store.materialize,
+            )
+            load.note(examples=int(len(fed.train_x)) + int(len(fed.test_x)))
+        return fed
     loader = dataset_registry.get(cfg.name)
-    tx, ty, ex, ey, meta, num_classes, task = loader(cfg, **model_kwargs)
+    with span("setup.data.load", dataset=cfg.name) as load:
+        tx, ty, ex, ey, meta, num_classes, task = loader(cfg, **model_kwargs)
+        load.note(examples=int(len(tx)) + int(len(ex)))
     labels_for_partition = ty if task == "classify" else ty[:, 0]
     part_info: dict = {}
-    client_indices = partition_lib.partition(
-        cfg.partition,
-        labels=labels_for_partition,
-        num_clients=cfg.num_clients,
-        num_classes=num_classes if task == "classify" else int(labels_for_partition.max()) + 1,
-        alpha=cfg.dirichlet_alpha,
-        seed=seed,
-        natural_groups=meta.get("natural_groups"),
-        info=part_info,
-    )
+    with span("setup.data.partition", partition=cfg.partition,
+              clients=cfg.num_clients):
+        client_indices = partition_lib.partition(
+            cfg.partition,
+            labels=labels_for_partition,
+            num_clients=cfg.num_clients,
+            num_classes=num_classes if task == "classify" else int(labels_for_partition.max()) + 1,
+            alpha=cfg.dirichlet_alpha,
+            seed=seed,
+            natural_groups=meta.get("natural_groups"),
+            info=part_info,
+        )
     meta = dict(meta, partition=cfg.partition, **part_info)
     if part_info.get("repair_used"):
         # the deterministic extreme-α repair changed the effective

@@ -52,7 +52,6 @@ pre-fit/over-budget abort (not retried by ``run.max_retries``).
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import hashlib
 import inspect
@@ -61,6 +60,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
+
+from colearn_federated_learning_tpu.obs.spans import _NULL_SPAN
 
 __all__ = [
     "ExecutableRegistry",
@@ -324,13 +325,13 @@ class ExecutableRegistry:
         self.total_compile_ms = 0.0
 
     # -- spans ----------------------------------------------------------
-    def _span(self, label: str):
+    def _span(self, label: str, **args):
         if self.tracer is None:
-            return contextlib.nullcontext()
+            return _NULL_SPAN
         try:
-            return self.tracer.span(label)
+            return self.tracer.span(label, **args)
         except Exception:
-            return contextlib.nullcontext()
+            return _NULL_SPAN
 
     # -- the wrapped-call entry point -----------------------------------
     def call(self, name: str, fn: Callable, args: tuple, kwargs: dict,
@@ -379,8 +380,14 @@ class ExecutableRegistry:
             fingerprint = _fingerprint_hex(name, key)
             t0 = time.perf_counter()
             try:
-                lowered = _on_roomy_stack(fn.lower, *args, **kwargs)
-                compiled = lowered.compile()
+                # trace + lower, then compile or load from the persistent
+                # cache: two spans of the start-up record (obs/spans.py),
+                # which inherit the dispatch's ``round``
+                with self._span("compile.lower", program=name):
+                    lowered = _on_roomy_stack(fn.lower, *args, **kwargs)
+                with self._span("compile.backend", program=name) as backend:
+                    compiled = lowered.compile()
+                    backend.note(cache=backend.cache)
             except Exception as e:
                 compile_ms = (time.perf_counter() - t0) * 1e3
                 self._emit_compiled(name, fingerprint, None, compile_ms,

@@ -114,10 +114,17 @@ _NON_HOST_EXPOSED_SPANS = ("round", "round.run", "round.prefetch",
 # to the total.
 _SUBSPAN_PREFIXES = ("round.host_inputs.",)
 
+# Set-up (`setup.*`, `init.*`: Experiment.__init__, init_state,
+# _place_state, before the first round's wall time begins) and the
+# registry's `compile.lower` / `compile.backend` inside
+# `obs.executables`: the first `spans` record of a fit carries them,
+# and none of it is a round's host time.
+_SETUP_PREFIXES = ("setup.", "init.", "compile.")
+
 
 def _is_host_exposed(name: str) -> bool:
     return (name not in _NON_HOST_EXPOSED_SPANS
-            and not name.startswith(_SUBSPAN_PREFIXES))
+            and not name.startswith(_SUBSPAN_PREFIXES + _SETUP_PREFIXES))
 
 
 # Byte-model pass counts (documented constants, not magic numbers):

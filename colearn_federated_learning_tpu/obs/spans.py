@@ -33,6 +33,24 @@ Retrace attribution: ``jax.monitoring`` fires a
 module-level listener forwards those into every live tracer, so an
 unexpected mid-run retrace shows up as a ``compile`` pseudo-phase in
 the same window it stalled (and as a timeline block in the trace).
+The persistent cache's ``/jax/compilation_cache/cache_hits`` and
+``cache_misses`` events are counted beside it (``compile`` carries
+``cache_hits`` / ``cache_misses``), and every span that is open on the
+compiling thread counts the compiles that ran inside it.
+
+What is kept past ``drain()``: the start-up record. A process pays its
+set-up once, before the first flush window means anything, and a reader
+that drains at its window's start (the benchmark does) would throw it
+away; a compile in the middle of a run is as rare and has to say which
+program and which round it belonged to. So every span whose name starts
+with ``setup.``, ``compile.`` or ``init.``, or is ``obs.executables``,
+is, besides the aggregate, appended whole to a list that lives as long
+as the tracer (:meth:`Tracer.startup_record`: name, start, end, self
+time, arguments, parent, lane, and the compiles counted inside it), and
+each ``drain()`` that saw compiles appends its ``compile`` pseudo-phase
+there too. The list is bounded by construction: tens of entries for a
+set-up, two per program the executable registry compiles later. Hot
+spans (``round.*``) are never kept.
 """
 
 from __future__ import annotations
@@ -62,6 +80,11 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def note(self, **args):
+        pass
+
+    cache = None
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -71,11 +94,40 @@ _ACTIVE: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
 _LISTENER_INSTALLED = False
 
 
+# the persistent compilation cache's two outcomes (jax 0.9.0:
+# _src/compiler.py fires the hit, _src/compilation_cache.py the miss
+# where it writes the new entry); a program compiled with the cache off
+# fires neither
+# (slots of a span's ``_compiles``: compiles, seconds, hits, misses)
+_HIT, _MISS = 2, 3
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": _HIT,
+                 "/jax/compilation_cache/cache_misses": _MISS}
+
+# names the start-up record keeps (module docstring)
+_KEPT_PREFIXES = ("setup.", "compile.", "init.")
+_KEPT_NAMES = ("obs.executables",)
+
+
 def _on_event_duration(event, duration, **kw):
     if "backend_compile" not in event:
         return
     for tracer in list(_ACTIVE):
         tracer._note_compile(float(duration))
+
+
+def _on_event(event, **kw):
+    slot = _CACHE_EVENTS.get(event)
+    if slot is None:
+        return
+    for tracer in list(_ACTIVE):
+        tracer._note_cache(slot)
+
+
+def live_tracers() -> List["Tracer"]:
+    """The enabled tracers alive in this process: how a reader that was
+    not handed the ``Experiment`` (the benchmark's per-layer readers)
+    finds the start-up record."""
+    return list(_ACTIVE)
 
 
 def _install_listener() -> None:
@@ -87,6 +139,7 @@ def _install_listener() -> None:
         from jax import monitoring
 
         monitoring.register_event_duration_secs_listener(_on_event_duration)
+        monitoring.register_event_listener(_on_event)
     except Exception:
         pass  # no jax / no monitoring API: spans still work, no retrace attribution
 
@@ -94,6 +147,18 @@ def _install_listener() -> None:
 # span arguments that pass from a span to the spans opened inside it on
 # the same thread: the identifier that spans of one dispatch share
 _INHERITED_ARGS = ("round",)
+
+
+def _startup_entry(name, start, end, self_s, args, parent, lane,
+                   compiles) -> Dict[str, Any]:
+    entry = {"name": name, "start": start, "end": end, "self_s": self_s,
+             "args": dict(args) if args else {}, "parent": parent,
+             "lane": lane}
+    if compiles is not None:
+        entry.update(compiles=int(compiles[0]), compile_s=float(compiles[1]),
+                     cache_hits=int(compiles[2]),
+                     cache_misses=int(compiles[3]))
+    return entry
 
 
 class _Lane:
@@ -105,10 +170,20 @@ class _Lane:
         self.index = index
         self.stack: List["_Span"] = []
 
+    def count(self, slot: int, amount=1) -> None:
+        """Books a compile event of this thread on its innermost open
+        span (its ``_compiles``; the span passes it outwards as it
+        closes)."""
+        if self.stack:
+            inside = self.stack[-1]
+            if inside._compiles is None:
+                inside._compiles = [0, 0.0, 0, 0]
+            inside._compiles[slot] += amount
+
 
 class _Span:
     __slots__ = ("_tracer", "_name", "_args", "_start", "_lane",
-                 "_children", "_annotation")
+                 "_children", "_annotation", "_compiles")
 
     def __init__(self, tracer: "Tracer", name: str, args=None):
         self._tracer = tracer
@@ -125,10 +200,30 @@ class _Span:
                 self._args = {**passed, **(self._args or {})}
         lane.stack.append(self)
         self._children = 0.0
+        # [compiles, seconds, cache hits, cache misses] of the programs
+        # jax compiled on this thread while the span was open; None
+        # until the first one
+        self._compiles = None
         self._annotation = tracer._annotate(self._name, **(self._args or {}))
         self._annotation.__enter__()
         self._start = tracer._clock()
         return self
+
+    def note(self, **args) -> None:
+        """Arguments known only once the span's work is done. They
+        reach the aggregate's record and the Chrome event; the
+        profiler's annotation was written at entry and lacks them."""
+        self._args = {**(self._args or {}), **args}
+
+    @property
+    def cache(self) -> str:
+        """What the persistent compilation cache said of the programs
+        compiled inside this span so far: ``miss`` if any was written
+        anew, else ``hit`` if any was loaded, else ``off``."""
+        c = self._compiles
+        if c is None or not (c[_HIT] or c[_MISS]):
+            return "off"
+        return "miss" if c[_MISS] else "hit"
 
     def __exit__(self, *exc):
         end = self._tracer._clock()
@@ -136,11 +231,18 @@ class _Span:
         stack = self._lane.stack
         stack.pop()
         dur = end - self._start
+        compiles = self._compiles
+        parent = None
         if stack:
-            stack[-1]._children += dur
+            above = stack[-1]
+            parent = above._name
+            above._children += dur
+            if compiles is not None:
+                held = above._compiles or (0, 0.0, 0, 0)
+                above._compiles = [a + b for a, b in zip(held, compiles)]
         self._tracer._record(self._name, self._start, dur,
                              dur - self._children, self._lane.index,
-                             self._args)
+                             self._args, parent, compiles)
         return False
 
 
@@ -183,6 +285,11 @@ class Tracer:
         self._compiles = 0
         self._compile_secs = 0.0
         self._compile_max = 0.0
+        self._cache_hits = 0
+        self._cache_misses = 0
+        # the start-up record (module docstring): never cleared
+        self._startup: List[Dict[str, Any]] = []
+        self._compile_kept_to = self._t0
         if enabled:
             from jax.profiler import TraceAnnotation
 
@@ -202,6 +309,15 @@ class Tracer:
             return _NULL_SPAN
         return _Span(self, name, args or None)
 
+    def note_past(self, name: str, start: float, end: float, **args) -> None:
+        """A phase that was over before this tracer existed, timed by
+        whoever saw it on this tracer's clock (the driver module's own
+        import, ``setup.import``): it enters the aggregates and the
+        start-up record as a top-level span of the calling thread."""
+        if self.enabled:
+            self._record(name, start, end - start, end - start,
+                         self._lane().index, args or None)
+
     def _lane(self) -> _Lane:
         lane = getattr(self._local, "lane", None)
         if lane is None:
@@ -211,8 +327,15 @@ class Tracer:
         return lane
 
     def _record(self, name: str, start: float, dur: float, self_s: float,
-                lane: int, args=None) -> None:
+                lane: int, args=None, parent: Optional[str] = None,
+                compiles: Optional[List[float]] = None) -> None:
+        kept = None
+        if name.startswith(_KEPT_PREFIXES) or name in _KEPT_NAMES:
+            kept = _startup_entry(name, start, start + dur, self_s, args,
+                                  parent, lane, compiles)
         with self._lock:
+            if kept is not None:
+                self._startup.append(kept)
             agg = self._agg.get(name)
             if agg is None:
                 self._agg[name] = [1, dur, dur, self_s]
@@ -236,7 +359,10 @@ class Tracer:
                 self._append_event(event)
 
     def _note_compile(self, duration: float) -> None:
-        lane = self._lane().index  # the compiling thread's
+        lane = self._lane()  # the compiling thread's
+        lane.count(0)
+        lane.count(1, duration)
+        lane = lane.index
         with self._lock:
             self._compiles += 1
             self._compile_secs += duration
@@ -254,6 +380,17 @@ class Tracer:
                     "ts": max(0.0, (now - self._t0 - duration)) * 1e6,
                     "dur": duration * 1e6,
                 })
+
+    def _note_cache(self, slot: int) -> None:
+        """One program's outcome at the persistent compilation cache
+        (``slot`` ``_HIT``: loaded, ``_MISS``: compiled and written),
+        on the thread that asked for it."""
+        self._lane().count(slot)
+        with self._lock:
+            if slot == _HIT:
+                self._cache_hits += 1
+            else:
+                self._cache_misses += 1
 
     def _append_event(self, event: Dict[str, Any]) -> None:
         """Append one Chrome-trace event under the event cap (caller
@@ -282,16 +419,38 @@ class Tracer:
         with self._lock:
             return self._compiles, self._compile_secs
 
+    def startup_record(self) -> List[Dict[str, Any]]:
+        """The start-up record (module docstring), oldest first: one
+        entry per kept span, ``{name, start, end, self_s, args, parent,
+        lane}`` on this tracer's clock (``time.perf_counter`` unless one
+        was given), with ``compiles``, ``compile_s``, ``cache_hits`` and
+        ``cache_misses`` where jax compiled programs inside it; and one
+        entry named ``compile`` per :meth:`drain` that saw compiles,
+        from the previous such entry's end to that drain. ``drain()``
+        does not clear it."""
+        with self._lock:
+            return list(self._startup)
+
     def drain(self) -> Dict[str, Dict[str, float]]:
         """Return and reset the per-phase aggregates since the last
         drain: ``{phase: {count, total_ms, max_ms, self_ms}}``, with
         compiles (retraces included) reported as the ``compile``
-        pseudo-phase (which nothing nests in: all of it is self time)."""
+        pseudo-phase (which nothing nests in: all of it is self time;
+        it also counts the persistent cache's ``cache_hits`` and
+        ``cache_misses``)."""
         with self._lock:
             agg, self._agg = self._agg, {}
             compiles, self._compiles = self._compiles, 0
             csecs, self._compile_secs = self._compile_secs, 0.0
             cmax, self._compile_max = self._compile_max, 0.0
+            hits, self._cache_hits = self._cache_hits, 0
+            misses, self._cache_misses = self._cache_misses, 0
+            if compiles:
+                now = self._clock()
+                self._startup.append(_startup_entry(
+                    "compile", self._compile_kept_to, now, csecs, None,
+                    None, None, (compiles, csecs, hits, misses)))
+                self._compile_kept_to = now
         out = {
             name: {
                 "count": int(c),
@@ -307,6 +466,8 @@ class Tracer:
                 "total_ms": round(csecs * 1000.0, 3),
                 "max_ms": round(cmax * 1000.0, 3),
                 "self_ms": round(csecs * 1000.0, 3),
+                "cache_hits": hits,
+                "cache_misses": misses,
             }
         return out
 

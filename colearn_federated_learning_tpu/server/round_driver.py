@@ -14,6 +14,10 @@ import os
 import time
 from typing import Any, Dict, Optional
 
+# first of the imports that cost anything, on purpose: it reads the
+# clock before orbax, flax and the models' Pallas kernels load
+from colearn_federated_learning_tpu.server import import_clock  # isort: skip
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -81,6 +85,11 @@ from colearn_federated_learning_tpu.server.sampler import CohortSampler
 from colearn_federated_learning_tpu.utils.checkpoint import CheckpointStore
 from colearn_federated_learning_tpu.utils.metrics import MetricsLogger
 
+# (start, end) of this module's imports on the perf_counter clock: seconds
+# of every process's set-up, which the first Experiment puts into its
+# tracer's start-up record as ``setup.import`` (None once claimed)
+_IMPORT_SPAN: Optional[tuple] = (import_clock.STARTED, time.perf_counter())
+
 _DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16, "float16": jnp.float16}
 
 # warn-once latch for bf16-on-a-backend-without-native-bf16-matmuls:
@@ -118,6 +127,33 @@ class Experiment:
     def __init__(self, cfg: ExperimentConfig, echo: bool = True):
         cfg.validate()
         self.cfg = cfg
+        # Round-lifecycle telemetry (run.obs, obs/): the tracer times
+        # host phases (and attributes retraces via compile hooks). It
+        # comes first so that its ``setup.*`` spans bracket everything
+        # a process pays before its first dispatch (obs/spans.py keeps
+        # them past drain()). Under multi-process EVERY process traces
+        # into its own lane (pid = process_index): non-primaries export
+        # per-host `trace.p<i>.json` fragments and the primary merges
+        # them into the final trace.json — the merged timeline replaces
+        # the old process-0-only export. The JSONL stays single-writer.
+        obs = cfg.run.obs
+        self._process_index = jax.process_index()
+        self.tracer = Tracer(
+            enabled=obs.spans, trace=obs.trace,
+            max_events=obs.trace_max_events,
+            process_index=self._process_index,
+        )
+        global _IMPORT_SPAN
+        if _IMPORT_SPAN is not None:
+            self.tracer.note_past("setup.import", *_IMPORT_SPAN)
+            _IMPORT_SPAN = None
+        with self.tracer.span("setup.experiment"):
+            self._build(cfg, echo)
+
+    def _build(self, cfg: ExperimentConfig, echo: bool) -> None:
+        """Everything ``__init__`` constructs, inside its
+        ``setup.experiment`` span."""
+        span = self.tracer.span
         if cfg.run.sanitize:
             jax.config.update("jax_debug_nans", True)
         compute_dtype = _DTYPES[cfg.run.compute_dtype]
@@ -135,28 +171,30 @@ class Experiment:
         # (the bitwise-identity contract).
         self._lora = cfg.model.lora.enabled
         param_dtype = _DTYPES[cfg.run.param_dtype]
-        self.model = build_model(
-            cfg.model.name, cfg.model.num_classes,
-            compute_dtype=compute_dtype,
-            param_dtype=(self._local_dtype() or param_dtype) if self._lora
-            else param_dtype,
-            **cfg.model.kwargs,
-        )
         self._full_param_stats_cache = None
         self._wire_reduction_cache = None
         self.frozen_base = None
         self._frozen_base_seed = None
-        if self._lora:
-            from colearn_federated_learning_tpu.models.lora import (
-                build_lora_model,
+        with span("setup.model"):
+            self.model = build_model(
+                cfg.model.name, cfg.model.num_classes,
+                compute_dtype=compute_dtype,
+                param_dtype=(self._local_dtype() or param_dtype)
+                if self._lora else param_dtype,
+                **cfg.model.kwargs,
             )
+            if self._lora:
+                from colearn_federated_learning_tpu.models.lora import (
+                    build_lora_model,
+                )
 
-            self.model = build_lora_model(
-                self.model, cfg.model.name,
-                rank=cfg.model.lora.rank, alpha=cfg.model.lora.alpha,
-                target=cfg.model.lora.target, adapter_dtype=param_dtype,
-            )
-        self.fed = build_federated_data(cfg.data, seed=cfg.run.seed, **cfg.model.kwargs)
+                self.model = build_lora_model(
+                    self.model, cfg.model.name,
+                    rank=cfg.model.lora.rank, alpha=cfg.model.lora.alpha,
+                    target=cfg.model.lora.target, adapter_dtype=param_dtype,
+                )
+        self.fed = build_federated_data(
+            cfg.data, seed=cfg.run.seed, span=span, **cfg.model.kwargs)
         self.task = self.fed.task
         self.shape = compute_round_shape(self.fed, cfg.client, cfg.data)
         # On-device masks (r7): the synchronous cohort paths ship the
@@ -381,8 +419,8 @@ class Experiment:
         self._staleness_warned = False
         # observability (run.obs, obs/): per-round comm-byte and
         # failure-count stats keyed by round (host-side, popped at
-        # flush); the tracer + health monitor are built after the
-        # logger below. _param_stats_cache backs both the HBM
+        # flush); the health monitor is built after the logger
+        # below. _param_stats_cache backs both the HBM
         # pre-flight and the comm-byte model.
         self._param_stats_cache = None
         self._comm_stats: Dict[int, Dict[str, int]] = {}
@@ -450,105 +488,60 @@ class Experiment:
         # construction so the poisson cap is already lane-rounded —
         # the bound must cover the padded worst case)
 
-        if cfg.run.engine == "sharded":
-            batch_shards = max(1, cfg.run.batch_shards)
-            if cfg.client.batch_size % batch_shards:
-                raise ValueError(
-                    f"run.batch_shards={batch_shards} must divide "
-                    f"client.batch_size={cfg.client.batch_size}"
-                )
-            avail = len(jax.devices()) // batch_shards
-            if avail < 1:
-                raise ValueError(
-                    f"run.batch_shards={batch_shards} > visible devices "
-                    f"{len(jax.devices())}"
-                )
-            if cfg.run.num_lanes:
-                lanes = cfg.run.num_lanes
-                if not self._poisson and cfg.server.cohort_size % lanes != 0:
+        with span("setup.engine"):
+            if cfg.run.engine == "sharded":
+                batch_shards = max(1, cfg.run.batch_shards)
+                if cfg.client.batch_size % batch_shards:
                     raise ValueError(
-                        f"run.num_lanes={lanes} must divide cohort_size="
-                        f"{cfg.server.cohort_size} (set num_lanes=0 to auto-pick)"
+                        f"run.batch_shards={batch_shards} must divide "
+                        f"client.batch_size={cfg.client.batch_size}"
                     )
-            else:
-                lanes = mesh_lib.largest_lane_count(cfg.server.cohort_size, avail)
-            if self._poisson:
-                # static rows must divide the lanes; pad rows are free
-                self._poisson_cap = -(-self._poisson_cap // lanes) * lanes
-            self.mesh = mesh_lib.build_client_mesh(lanes, batch_shards=batch_shards)
-            if self.gossip:
-                from colearn_federated_learning_tpu.parallel.gossip import (
-                    make_gossip_round_fn,
-                )
+                avail = len(jax.devices()) // batch_shards
+                if avail < 1:
+                    raise ValueError(
+                        f"run.batch_shards={batch_shards} > visible devices "
+                        f"{len(jax.devices())}"
+                    )
+                if cfg.run.num_lanes:
+                    lanes = cfg.run.num_lanes
+                    if not self._poisson and cfg.server.cohort_size % lanes != 0:
+                        raise ValueError(
+                            f"run.num_lanes={lanes} must divide cohort_size="
+                            f"{cfg.server.cohort_size} (set num_lanes=0 to auto-pick)"
+                        )
+                else:
+                    lanes = mesh_lib.largest_lane_count(cfg.server.cohort_size, avail)
+                if self._poisson:
+                    # static rows must divide the lanes; pad rows are free
+                    self._poisson_cap = -(-self._poisson_cap // lanes) * lanes
+                self.mesh = mesh_lib.build_client_mesh(lanes, batch_shards=batch_shards)
+                if self.gossip:
+                    from colearn_federated_learning_tpu.parallel.gossip import (
+                        make_gossip_round_fn,
+                    )
 
-                self.round_fn = make_gossip_round_fn(
-                    self.model, cfg.client, cfg.dp, self.task, self.mesh,
-                    num_clients=self.fed.num_clients,
-                    gamma=cfg.server.gossip_gamma,
-                    mixing_steps=cfg.server.gossip_mixing_steps,
-                    topology=cfg.server.gossip_topology,
-                    local_dtype=self._local_dtype(),
-                    scan_unroll=cfg.run.scan_unroll,
-                    cohort_size=cfg.server.cohort_size,
-                    attack=self.attack_kind if self._attack_upload else "",
-                    attack_scale=cfg.attack.scale,
-                    attack_eps=cfg.attack.eps,
-                )
-            elif self.fedbuff:
-                self.round_fn = make_async_round_fn(
-                    self.model, cfg.client, cfg.dp, self.task, self.mesh,
-                    server_update, buffer_size=cfg.server.cohort_size,
-                    window=2 * cfg.server.async_max_staleness + 1,
-                    client_vmap_width=cfg.run.client_vmap_width,
-                    local_dtype=self._local_dtype(),
-                    clip_delta_norm=cfg.server.clip_delta_norm,
-                    scan_unroll=cfg.run.scan_unroll,
-                    client_ledger=self._ledger_on,
-                    ledger_ema=lcfg.ema,
-                    ledger_zmax=lcfg.zmax,
-                    reputation=cfg.server.reputation.enabled,
-                    rep_floor=cfg.server.reputation.floor,
-                    rep_strength=cfg.server.reputation.strength,
-                    rep_z_gain=cfg.server.reputation.z_gain,
-                )
-            else:
-                def _make_engine(fuse, donate=True):
-                    return make_sharded_round_fn(
+                    self.round_fn = make_gossip_round_fn(
                         self.model, cfg.client, cfg.dp, self.task, self.mesh,
-                        server_update,
-                        self._poisson_cap or cfg.server.cohort_size,
-                        dp_fixed_denom=cfg.server.cohort_size,
-                        client_vmap_width=cfg.run.client_vmap_width,
-                        cohort_layout=cfg.run.cohort_layout,
-                        local_dtype=self._local_dtype(), agg=agg,
-                        scaffold=self.scaffold,
                         num_clients=self.fed.num_clients,
-                        aggregator=cfg.server.aggregator,
-                        trim_ratio=cfg.server.trim_ratio,
-                        compression=cfg.server.compression,
-                        topk_ratio=cfg.server.compression_topk_ratio,
-                        qsgd_levels=cfg.server.compression_qsgd_levels,
-                        topk_exact=cfg.server.compression_topk_exact,
-                        clip_delta_norm=cfg.server.clip_delta_norm,
-                        feddyn_alpha=(
-                            cfg.server.feddyn_alpha if self.feddyn else 0.0
-                        ),
-                        byzantine_f=cfg.server.krum_byzantine,
+                        gamma=cfg.server.gossip_gamma,
+                        mixing_steps=cfg.server.gossip_mixing_steps,
+                        topology=cfg.server.gossip_topology,
+                        local_dtype=self._local_dtype(),
                         scan_unroll=cfg.run.scan_unroll,
-                        secagg=self.secagg,
-                        secagg_quant_step=cfg.server.secagg_quant_step,
-                        secagg_mode=cfg.server.secagg_mode,
-                        client_dp_noise=cfg.server.dp_client_noise_multiplier,
-                        downlink=cfg.server.downlink_compression,
-                        downlink_levels=cfg.server.downlink_qsgd_levels,
-                        error_feedback=self.ef,
-                        fuse_rounds=fuse,
-                        attack=(
-                            self.attack_kind if self._attack_upload else ""
-                        ),
+                        cohort_size=cfg.server.cohort_size,
+                        attack=self.attack_kind if self._attack_upload else "",
                         attack_scale=cfg.attack.scale,
                         attack_eps=cfg.attack.eps,
-                        on_device_mask=self._spec_inputs,
+                    )
+                elif self.fedbuff:
+                    self.round_fn = make_async_round_fn(
+                        self.model, cfg.client, cfg.dp, self.task, self.mesh,
+                        server_update, buffer_size=cfg.server.cohort_size,
+                        window=2 * cfg.server.async_max_staleness + 1,
+                        client_vmap_width=cfg.run.client_vmap_width,
+                        local_dtype=self._local_dtype(),
+                        clip_delta_norm=cfg.server.clip_delta_norm,
+                        scan_unroll=cfg.run.scan_unroll,
                         client_ledger=self._ledger_on,
                         ledger_ema=lcfg.ema,
                         ledger_zmax=lcfg.zmax,
@@ -556,74 +549,120 @@ class Experiment:
                         rep_floor=cfg.server.reputation.floor,
                         rep_strength=cfg.server.reputation.strength,
                         rep_z_gain=cfg.server.reputation.z_gain,
-                        fused_apply=cfg.server.fused_apply,
-                        # hierarchy re-dispatches the SAME params/opt
-                        # buffers once per edge — donation would delete
-                        # them after the first edge's call; the device
-                        # control plane moves donation to its outer
-                        # wrapper jit (donate=False here)
-                        donate=donate and not self._hier,
                     )
+                else:
+                    def _make_engine(fuse, donate=True):
+                        return make_sharded_round_fn(
+                            self.model, cfg.client, cfg.dp, self.task, self.mesh,
+                            server_update,
+                            self._poisson_cap or cfg.server.cohort_size,
+                            dp_fixed_denom=cfg.server.cohort_size,
+                            client_vmap_width=cfg.run.client_vmap_width,
+                            cohort_layout=cfg.run.cohort_layout,
+                            local_dtype=self._local_dtype(), agg=agg,
+                            scaffold=self.scaffold,
+                            num_clients=self.fed.num_clients,
+                            aggregator=cfg.server.aggregator,
+                            trim_ratio=cfg.server.trim_ratio,
+                            compression=cfg.server.compression,
+                            topk_ratio=cfg.server.compression_topk_ratio,
+                            qsgd_levels=cfg.server.compression_qsgd_levels,
+                            topk_exact=cfg.server.compression_topk_exact,
+                            clip_delta_norm=cfg.server.clip_delta_norm,
+                            feddyn_alpha=(
+                                cfg.server.feddyn_alpha if self.feddyn else 0.0
+                            ),
+                            byzantine_f=cfg.server.krum_byzantine,
+                            scan_unroll=cfg.run.scan_unroll,
+                            secagg=self.secagg,
+                            secagg_quant_step=cfg.server.secagg_quant_step,
+                            secagg_mode=cfg.server.secagg_mode,
+                            client_dp_noise=cfg.server.dp_client_noise_multiplier,
+                            downlink=cfg.server.downlink_compression,
+                            downlink_levels=cfg.server.downlink_qsgd_levels,
+                            error_feedback=self.ef,
+                            fuse_rounds=fuse,
+                            attack=(
+                                self.attack_kind if self._attack_upload else ""
+                            ),
+                            attack_scale=cfg.attack.scale,
+                            attack_eps=cfg.attack.eps,
+                            on_device_mask=self._spec_inputs,
+                            client_ledger=self._ledger_on,
+                            ledger_ema=lcfg.ema,
+                            ledger_zmax=lcfg.zmax,
+                            reputation=cfg.server.reputation.enabled,
+                            rep_floor=cfg.server.reputation.floor,
+                            rep_strength=cfg.server.reputation.strength,
+                            rep_z_gain=cfg.server.reputation.z_gain,
+                            fused_apply=cfg.server.fused_apply,
+                            # hierarchy re-dispatches the SAME params/opt
+                            # buffers once per edge — donation would delete
+                            # them after the first edge's call; the device
+                            # control plane moves donation to its outer
+                            # wrapper jit (donate=False here)
+                            donate=donate and not self._hier,
+                        )
 
-                self.round_fn = _make_engine(cfg.run.fuse_rounds)
-                # an unfused twin is built lazily (one extra compile)
-                # only when a resume lands off a chunk boundary — see
-                # _unfused_round_fn / the _fit_body catch-up loop; the
-                # device control plane keeps the factory for its
-                # donate-free inner engines
-                if cfg.run.fuse_rounds > 1 or self._cp_device:
-                    self._make_engine = _make_engine
-            self._data_sharding = mesh_lib.replicated(self.mesh)
-            self._cohort_sharding = mesh_lib.cohort_sharded(self.mesh)
-            self._client_sharding = mesh_lib.client_sharded(self.mesh)
-            self.n_chips = lanes * batch_shards
-            # per-client state store rows: N padded up to a lane multiple
-            # (pad rows are never sampled into a cohort, so they stay 0)
-            self._state_rows = -(-self.fed.num_clients // lanes) * lanes
-        else:
-            self.mesh = None
-            self.round_fn = make_sequential_round_fn(
-                self.model, cfg.client, cfg.dp, self.task, server_update,
-                dp_fixed_denom=cfg.server.cohort_size,
-                local_dtype=self._local_dtype(), agg=agg,
-                scaffold=self.scaffold, num_clients=self.fed.num_clients,
-                aggregator=cfg.server.aggregator,
-                trim_ratio=cfg.server.trim_ratio,
-                compression=cfg.server.compression,
-                topk_ratio=cfg.server.compression_topk_ratio,
-                qsgd_levels=cfg.server.compression_qsgd_levels,
-                topk_exact=cfg.server.compression_topk_exact,
-                clip_delta_norm=cfg.server.clip_delta_norm,
-                feddyn_alpha=(
-                    cfg.server.feddyn_alpha if self.feddyn else 0.0
-                ),
-                byzantine_f=cfg.server.krum_byzantine,
-                secagg=self.secagg,
-                secagg_quant_step=cfg.server.secagg_quant_step,
-                secagg_mode=cfg.server.secagg_mode,
-                scan_unroll=cfg.run.scan_unroll,
-                client_dp_noise=cfg.server.dp_client_noise_multiplier,
-                downlink=cfg.server.downlink_compression,
-                downlink_levels=cfg.server.downlink_qsgd_levels,
-                error_feedback=self.ef,
-                attack=self.attack_kind if self._attack_upload else "",
-                attack_scale=cfg.attack.scale,
-                attack_eps=cfg.attack.eps,
-                on_device_mask=self._spec_inputs,
-                client_ledger=self._ledger_on,
-                ledger_ema=lcfg.ema,
-                ledger_zmax=lcfg.zmax,
-                reputation=cfg.server.reputation.enabled,
-                rep_floor=cfg.server.reputation.floor,
-                rep_strength=cfg.server.reputation.strength,
-                rep_z_gain=cfg.server.reputation.z_gain,
-                fused_apply=cfg.server.fused_apply,
-            )
-            self._data_sharding = None
-            self._cohort_sharding = None
-            self._client_sharding = None
-            self.n_chips = 1
-            self._state_rows = self.fed.num_clients
+                    self.round_fn = _make_engine(cfg.run.fuse_rounds)
+                    # an unfused twin is built lazily (one extra compile)
+                    # only when a resume lands off a chunk boundary — see
+                    # _unfused_round_fn / the _fit_body catch-up loop; the
+                    # device control plane keeps the factory for its
+                    # donate-free inner engines
+                    if cfg.run.fuse_rounds > 1 or self._cp_device:
+                        self._make_engine = _make_engine
+                self._data_sharding = mesh_lib.replicated(self.mesh)
+                self._cohort_sharding = mesh_lib.cohort_sharded(self.mesh)
+                self._client_sharding = mesh_lib.client_sharded(self.mesh)
+                self.n_chips = lanes * batch_shards
+                # per-client state store rows: N padded up to a lane multiple
+                # (pad rows are never sampled into a cohort, so they stay 0)
+                self._state_rows = -(-self.fed.num_clients // lanes) * lanes
+            else:
+                self.mesh = None
+                self.round_fn = make_sequential_round_fn(
+                    self.model, cfg.client, cfg.dp, self.task, server_update,
+                    dp_fixed_denom=cfg.server.cohort_size,
+                    local_dtype=self._local_dtype(), agg=agg,
+                    scaffold=self.scaffold, num_clients=self.fed.num_clients,
+                    aggregator=cfg.server.aggregator,
+                    trim_ratio=cfg.server.trim_ratio,
+                    compression=cfg.server.compression,
+                    topk_ratio=cfg.server.compression_topk_ratio,
+                    qsgd_levels=cfg.server.compression_qsgd_levels,
+                    topk_exact=cfg.server.compression_topk_exact,
+                    clip_delta_norm=cfg.server.clip_delta_norm,
+                    feddyn_alpha=(
+                        cfg.server.feddyn_alpha if self.feddyn else 0.0
+                    ),
+                    byzantine_f=cfg.server.krum_byzantine,
+                    secagg=self.secagg,
+                    secagg_quant_step=cfg.server.secagg_quant_step,
+                    secagg_mode=cfg.server.secagg_mode,
+                    scan_unroll=cfg.run.scan_unroll,
+                    client_dp_noise=cfg.server.dp_client_noise_multiplier,
+                    downlink=cfg.server.downlink_compression,
+                    downlink_levels=cfg.server.downlink_qsgd_levels,
+                    error_feedback=self.ef,
+                    attack=self.attack_kind if self._attack_upload else "",
+                    attack_scale=cfg.attack.scale,
+                    attack_eps=cfg.attack.eps,
+                    on_device_mask=self._spec_inputs,
+                    client_ledger=self._ledger_on,
+                    ledger_ema=lcfg.ema,
+                    ledger_zmax=lcfg.zmax,
+                    reputation=cfg.server.reputation.enabled,
+                    rep_floor=cfg.server.reputation.floor,
+                    rep_strength=cfg.server.reputation.strength,
+                    rep_z_gain=cfg.server.reputation.z_gain,
+                    fused_apply=cfg.server.fused_apply,
+                )
+                self._data_sharding = None
+                self._cohort_sharding = None
+                self._client_sharding = None
+                self.n_chips = 1
+                self._state_rows = self.fed.num_clients
 
         if self.secagg:
             # after engine construction: the poisson cap (if any) is now
@@ -673,7 +712,8 @@ class Experiment:
         # copies instead of device_put-ing across processes.
         put = self._put_data
         self._stream = cfg.data.placement == "stream"
-        self._check_memory_budget()
+        with span("setup.model"):  # the parameter statistics (eval_shape)
+            self._check_memory_budget()
         # Fused-chunk placement (run.fuse_rounds > 1): the stacked
         # [F, K, ...] host slabs go through the same _put path as the
         # per-round tensors, with the fuse dim replicated — under
@@ -739,8 +779,9 @@ class Experiment:
             self._store_ownership = apply_store_shard_ownership(self.fed)
         else:
             self._store_ownership = None
-            self.train_x = put(jnp.asarray(self.fed.train_x))
-            self.train_y = put(jnp.asarray(self.fed.train_y))
+            with span("setup.data.place"):
+                self.train_x = put(jnp.asarray(self.fed.train_x))
+                self.train_y = put(jnp.asarray(self.fed.train_y))
         # Device control plane: build the static plan (cohort table via
         # the UNMODIFIED host sampler — device cohorts are bitwise-equal
         # to host mode by construction — churn thresholds, shard table),
@@ -793,10 +834,12 @@ class Experiment:
 
         self._eval_all = exec_mod.instrument("eval.all", jax.jit(_eval_all))
         # eval batches are fixed for the run: build + upload exactly once
-        xb, yb, mb = eval_batches(
-            self.fed.test_x, self.fed.test_y, cfg.client.batch_size
-        )
-        self._eval_data = (put(jnp.asarray(xb)), put(jnp.asarray(yb)), put(jnp.asarray(mb)))
+        with span("setup.eval_batches"):
+            xb, yb, mb = eval_batches(
+                self.fed.test_x, self.fed.test_y, cfg.client.batch_size
+            )
+            self._eval_data = (put(jnp.asarray(xb)), put(jnp.asarray(yb)),
+                               put(jnp.asarray(mb)))
         # Multi-host: every process runs the identical fit loop (SPMD over
         # the global mesh), but artifacts are SINGLE-WRITER — only process
         # 0 writes/echoes metrics. Checkpointing stays collective (orbax
@@ -819,21 +862,9 @@ class Experiment:
             append=cfg.run.resume,
             tensorboard=cfg.run.tensorboard,
         )
-        # Round-lifecycle telemetry (run.obs, obs/): the tracer times
-        # host phases (and attributes retraces via compile hooks); the
-        # health monitor watches the fetched losses at flush
-        # boundaries. Under multi-process EVERY process traces into its
-        # own lane (pid = process_index): non-primaries export per-host
-        # `trace.p<i>.json` fragments and the primary merges them into
-        # the final trace.json — the merged timeline replaces the old
-        # process-0-only export. The JSONL stays single-writer.
+        # The health monitor watches the fetched losses at flush
+        # boundaries (the tracer beside it in run.obs was built first).
         obs = cfg.run.obs
-        self._process_index = jax.process_index()
-        self.tracer = Tracer(
-            enabled=obs.spans, trace=obs.trace,
-            max_events=obs.trace_max_events,
-            process_index=self._process_index,
-        )
         self.health = (
             HealthMonitor(obs.divergence_factor) if obs.health else None
         )
@@ -863,7 +894,9 @@ class Experiment:
             obs.counters and obs.phase_cost
             and not (self.gossip or self.fedbuff)
         )
-        self._phase_costs: Dict[int, Dict[str, Dict[str, int]]] = {}
+        # round -> (k, steps, batch, host_input_bytes): what the
+        # dispatch path keeps; the flush makes the record of it
+        self._phase_grids: Dict[int, tuple] = {}
         self._step_flops_cache = None
         # Federation health observatory (run.obs.population, obs/
         # population.py): population/data-plane telemetry — coverage,
@@ -934,21 +967,22 @@ class Experiment:
                 and not cfg.data.store.dir):
             from colearn_federated_learning_tpu import native
 
-            if native.available():
-                self._native = native.NativeRoundPipeline(
-                    self.fed.client_indices,
-                    self.shape.local_epochs, self.shape.steps_per_epoch,
-                    self.shape.batch_size, self.shape.cap,
-                    seed=cfg.run.seed,
-                    # spec-input engines rebuild the mask on device —
-                    # the pipeline skips the float mask slab entirely
-                    build_mask=not self._spec_inputs,
-                )
-            elif cfg.run.host_pipeline == "native":
-                raise RuntimeError(
-                    f"run.host_pipeline=native but the C++ pipeline cannot "
-                    f"be built: {native.build_error()}"
-                )
+            with span("setup.engine"):  # g++ on first use, then the load
+                if native.available():
+                    self._native = native.NativeRoundPipeline(
+                        self.fed.client_indices,
+                        self.shape.local_epochs, self.shape.steps_per_epoch,
+                        self.shape.batch_size, self.shape.cap,
+                        seed=cfg.run.seed,
+                        # spec-input engines rebuild the mask on device —
+                        # the pipeline skips the float mask slab entirely
+                        build_mask=not self._spec_inputs,
+                    )
+                elif cfg.run.host_pipeline == "native":
+                    raise RuntimeError(
+                        f"run.host_pipeline=native but the C++ pipeline "
+                        f"cannot be built: {native.build_error()}"
+                    )
 
     # ------------------------------------------------------------------
 
@@ -1190,17 +1224,19 @@ class Experiment:
             self._step_flops_cache = (int(flops), source)
         return self._step_flops_cache
 
-    def _record_phase_cost(self, round_idx: int, k: int, steps: int,
-                           batch: int, host_input_bytes: int) -> None:
+    def _phase_cost(self, k: int, steps: int, batch: int,
+                    host_input_bytes: int) -> Dict[str, Dict[str, int]]:
         """Analytic per-phase FLOP/byte costs for one round on its
         REALIZED (bucketed) grid — a pure function of the config and
         the grid, so the sharded, sequential, and fused engines record
         identical numbers (parity-pinned in tests/test_roofline.py).
-        Drained into `phase_cost` JSONL records at flush boundaries."""
+        The dispatch path only keeps the grid's four integers
+        (``_phase_grids``); this runs when the flush writes the round's
+        `phase_cost` JSONL record."""
         cfg = self.cfg
         step_flops, _ = self._train_step_flops()
         coords, _ = self._param_stats()
-        self._phase_costs[round_idx] = round_phase_costs(
+        return round_phase_costs(
             k=k, steps=steps, batch=batch, n_coords=coords,
             compute_bytes=self._compute_itemsize(), step_flops=step_flops,
             aggregator=cfg.server.aggregator,
@@ -1385,19 +1421,26 @@ class Experiment:
         return RoundData(train_x, self.frozen_base)
 
     def init_state(self, seed: Optional[int] = None) -> Dict[str, Any]:
+        with self.tracer.span("setup.init_state"):
+            return self._init_state(seed)
+
+    def _init_state(self, seed: Optional[int]) -> Dict[str, Any]:
         seed = self.cfg.run.seed if seed is None else seed
         rng = jax.random.PRNGKey(seed)
         init_rng, run_rng = jax.random.split(rng)
         from colearn_federated_learning_tpu.client.trainer import normalize_input
 
         dummy = normalize_input(jnp.asarray(self.fed.train_x[:1]))
-        variables = self.model.init(init_rng, dummy, train=False)
+        with self.tracer.span("setup.init.model"):  # flax's eager init
+            variables = self.model.init(init_rng, dummy, train=False)
         params = variables["params"]
         if self._lora and self._frozen_base_seed != seed:
             self._init_frozen_base(init_rng, dummy, seed)
+        with self.tracer.span("setup.init.server_opt"):
+            server_opt_state = self.server_opt_init(params)
         state = {
             "params": params,
-            "server_opt_state": self.server_opt_init(params),
+            "server_opt_state": server_opt_state,
             "round": 0,
             "rng_key": run_rng,
         }
@@ -1562,6 +1605,10 @@ class Experiment:
 
     def _place_state(self, state: Dict[str, Any]) -> Dict[str, Any]:
         """Replicate params/opt state over the mesh (fresh init or restore)."""
+        with self.tracer.span("setup.place_state"):
+            return self._place_state_on_mesh(state)
+
+    def _place_state_on_mesh(self, state: Dict[str, Any]) -> Dict[str, Any]:
         if self._data_sharding is not None:
             state["params"] = self._put_data(state["params"])
             state["server_opt_state"] = self._put_data(state["server_opt_state"])
@@ -2265,10 +2312,8 @@ class Experiment:
                     stats["shape_bucket_steps"] = steps_g
             self._comm_stats[round_idx] = stats
             if self._phase_cost_on:
-                self._record_phase_cost(
-                    round_idx, rows, steps_g, batch_g,
-                    stats["host_input_bytes"],
-                )
+                self._phase_grids[round_idx] = (
+                    rows, steps_g, batch_g, stats["host_input_bytes"])
         if not place:
             # fuse>1 requires hbm placement (validate), so slab is None
             return (cohort, idx, mask, n_ex,
@@ -3024,10 +3069,9 @@ class Experiment:
                 ))
                 self._comm_stats[ridx] = stats
                 if self._phase_cost_on:
-                    self._record_phase_cost(
-                        ridx, len(cohort), self.shape.steps,
-                        self.shape.batch_size, 0,
-                    )
+                    self._phase_grids[ridx] = (
+                        len(cohort), self.shape.steps,
+                        self.shape.batch_size, 0)
                 fail = {
                     key: int(s[src]) for key, src in (
                         ("churn_unavailable", "unavailable"),
@@ -4658,15 +4702,15 @@ class Experiment:
                     if k in record:
                         self._run_totals[k] += int(record[k])
                 self.logger.log(record)
-                pc = self._phase_costs.pop(ridx, None)
-                if pc is not None:
+                grid = self._phase_grids.pop(ridx, None)
+                if grid is not None:
                     # the analytic cost record rides next to the round
                     # it describes — `colearn mfu` joins these with the
                     # spans records into the waterfall
                     self.logger.log({
                         "event": "phase_cost", "round": ridx + 1,
                         "process_index": int(self._process_index),
-                        "phases": pc,
+                        "phases": self._phase_cost(*grid),
                     })
             last_round = pending[-1][0] + 1
             self._rounds_done = max(self._rounds_done, last_round)
@@ -4986,7 +5030,7 @@ class Experiment:
             fail = self._fail_stats.pop(r, None)
             cohort = self._digest_cohorts.pop(r, None)
             for scratch in (self._async_stats, self._hier_stats,
-                            self._attack_stats, self._phase_costs):
+                            self._attack_stats, self._phase_grids):
                 scratch.pop(r, None)
             if r + 1 > window_start:
                 # rounds at or before the window start were digested

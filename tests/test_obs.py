@@ -763,3 +763,202 @@ def test_spans_and_phase_cost_records_carry_process_index(tmp_path):
                                     "phase_cost_model")]
     assert tagged, "expected spans + phase_cost records"
     assert all(r.get("process_index") == 0 for r in tagged)
+
+
+# ---------------------------------------------------------------------------
+# the start-up record: what the tracer keeps past drain()
+
+
+def test_kept_spans_survive_drain_and_the_aggregate_is_still_reset():
+    clock = iter(float(t) for t in range(100))
+    tracer = Tracer(clock=lambda: next(clock))
+    with tracer.span("setup.experiment"):            # [1, 6]
+        with tracer.span("setup.data.load", dataset="toy"):   # [2, 3]
+            pass
+        with tracer.span("round.host_inputs"):       # [4, 5]: hot, not kept
+            pass
+    first = tracer.drain()
+    assert set(first) == {"setup.experiment", "setup.data.load",
+                          "round.host_inputs"}
+    assert tracer.drain() == {}  # the window's aggregate was reset
+    with tracer.span("init.frozen_base"):            # [7, 8]
+        pass
+    record = tracer.startup_record()
+    assert [e["name"] for e in record] == [
+        "setup.data.load", "setup.experiment", "init.frozen_base"]
+    load, exp, base = record
+    assert (load["start"], load["end"], load["self_s"]) == (2.0, 3.0, 1.0)
+    assert load["args"] == {"dataset": "toy"}
+    assert load["parent"] == "setup.experiment" and load["lane"] == 0
+    # the experiment's self time excludes both children, kept or not
+    assert (exp["start"], exp["end"], exp["self_s"]) == (1.0, 6.0, 3.0)
+    assert exp["parent"] is None and base["parent"] is None
+    assert set(tracer.drain()) == {"init.frozen_base"}
+    assert len(tracer.startup_record()) == 3  # and drain() left it alone
+    # the accessor hands out a copy
+    tracer.startup_record().clear()
+    assert len(tracer.startup_record()) == 3
+
+
+def test_note_past_enters_aggregate_and_record_as_a_top_level_span():
+    tracer = Tracer()
+    tracer.note_past("setup.import", 10.0, 12.5)
+    (entry,) = tracer.startup_record()
+    assert (entry["name"], entry["start"], entry["end"], entry["self_s"],
+            entry["parent"], entry["lane"]) == (
+                "setup.import", 10.0, 12.5, 2.5, None, 0)
+    assert tracer.drain()["setup.import"]["total_ms"] == pytest.approx(2500.0)
+    off = Tracer(enabled=False)
+    off.note_past("setup.import", 10.0, 12.5)
+    assert off.startup_record() == [] and off.drain() == {}
+
+
+def test_compiles_are_counted_under_the_spans_they_ran_in():
+    """jax's backend_compile and compilation-cache events reach the
+    innermost open span of the compiling thread and add up outwards;
+    each drain that saw compiles leaves its ``compile`` pseudo-phase in
+    the record."""
+    tracer = Tracer()
+    with tracer.span("setup.init_state"):
+        with tracer.span("setup.init.model") as model:
+            tracer._note_compile(0.25)
+            tracer._note_cache(3)  # written anew
+            tracer._note_compile(0.5)
+            tracer._note_cache(2)  # loaded
+            assert model.cache == "miss"
+        with tracer.span("setup.init.server_opt") as opt:
+            assert opt.cache == "off"
+            tracer._note_compile(0.125)
+            tracer._note_cache(2)
+            assert opt.cache == "hit"
+    tracer._note_compile(1.0)  # under no span: the drain still counts it
+    phases = tracer.drain()
+    assert phases["compile"]["count"] == 4
+    assert phases["compile"]["cache_hits"] == 2
+    assert phases["compile"]["cache_misses"] == 1
+    by_name = {e["name"]: e for e in tracer.startup_record()}
+    assert (by_name["setup.init.model"]["compiles"],
+            by_name["setup.init.model"]["compile_s"],
+            by_name["setup.init.model"]["cache_hits"],
+            by_name["setup.init.model"]["cache_misses"]) == (2, 0.75, 1, 1)
+    assert by_name["setup.init_state"]["compiles"] == 3
+    assert by_name["setup.init_state"]["cache_hits"] == 2
+    kept = by_name["compile"]
+    assert (kept["compiles"], kept["cache_hits"], kept["cache_misses"],
+            kept["compile_s"]) == (4, 2, 1, 1.875)
+    assert kept["end"] >= by_name["setup.init_state"]["end"]
+    tracer.drain()  # no compile since: no second entry
+    assert [e["name"] for e in tracer.startup_record()].count("compile") == 1
+
+
+def test_a_compile_after_set_up_names_its_program_and_its_round():
+    """The registry's two spans, for a program whose shape changes in a
+    later round: both compiles are in the record under obs.executables,
+    with the program's name and the dispatch's round."""
+    import jax
+    import jax.numpy as jnp
+
+    from colearn_federated_learning_tpu.obs import executables as exec_mod
+
+    tracer = Tracer()
+    reg = exec_mod.ExecutableRegistry(tracer=tracer)
+    double = exec_mod.instrument("round.toy", jax.jit(lambda x: x * 2))
+    exec_mod.install(reg)
+    try:
+        with tracer.span("round.run", round=1):
+            with tracer.span("round.dispatch"):
+                double(jnp.ones(4))
+        tracer.drain()  # set-up is over
+        with tracer.span("round.run", round=7):
+            with tracer.span("round.dispatch"):
+                double(jnp.ones(4))  # cached: no compile
+                double(jnp.ones(8))  # a new shape
+    finally:
+        exec_mod.uninstall()
+    record = tracer.startup_record()
+    names = [e["name"] for e in record if e["name"] != "compile"]
+    assert names == ["compile.lower", "compile.backend", "obs.executables"] * 2
+    for e in record:
+        if e["name"].startswith("compile."):
+            assert e["parent"] == "obs.executables"
+            assert e["args"]["program"] == "round.toy"
+    assert [e["args"]["round"] for e in record
+            if e["name"] == "compile.lower"] == [1, 7]
+    backends = [e for e in record if e["name"] == "compile.backend"]
+    assert all(e["args"]["cache"] in ("hit", "miss", "off") for e in backends)
+    assert all(e["compiles"] == 1 for e in backends)
+    # what is left as the registry's self time is its harvest
+    for e in record:
+        if e["name"] == "obs.executables":
+            assert 0 <= e["self_s"] < e["end"] - e["start"]
+
+
+def _driven(cfg):
+    """An Experiment driven as the benchmark drives it: init, place, two
+    dispatches, with the registry installed."""
+    from colearn_federated_learning_tpu.obs import executables as exec_mod
+    from colearn_federated_learning_tpu.server.round_driver import Experiment
+
+    exp = Experiment(cfg, echo=False)
+    exec_mod.install(exp._exec_reg)
+    try:
+        state = exp._place_state(exp.init_state(cfg.run.seed))
+        for r in range(2):
+            state = exp.run_round(state, r)
+            state.pop("_metrics")
+    finally:
+        exp._stop_prefetch()
+        exec_mod.uninstall()
+    return exp
+
+
+def test_experiment_set_up_is_in_the_record_and_a_second_one_starts_empty(
+        tmp_path):
+    first = _driven(_tiny_cfg(tmp_path / "a"))
+    record = first.tracer.startup_record()
+    names = [e["name"] for e in record]
+    for name in ("setup.experiment", "setup.model", "setup.data.load",
+                 "setup.data.partition", "setup.engine", "setup.data.place",
+                 "setup.eval_batches", "setup.init_state", "setup.init.model",
+                 "setup.init.server_opt", "setup.place_state",
+                 "obs.executables", "compile.lower", "compile.backend"):
+        assert name in names, name
+    by_name = {e["name"]: e for e in record}
+    exp_entry = by_name["setup.experiment"]
+    children = [e for e in record if e["parent"] == "setup.experiment"]
+    assert {e["name"] for e in children} >= {"setup.model", "setup.data.load",
+                                             "setup.engine"}
+    held = sum(e["end"] - e["start"] for e in children)
+    assert exp_entry["self_s"] == pytest.approx(
+        exp_entry["end"] - exp_entry["start"] - held, abs=1e-9)
+    assert by_name["setup.data.load"]["args"] == {
+        "dataset": "mnist", "examples": 256 + 64}
+    assert by_name["setup.data.partition"]["args"]["clients"] == 2
+    for name in ("setup.experiment", "setup.init_state", "setup.place_state"):
+        assert by_name[name]["parent"] is None and by_name[name]["lane"] == 0
+    lower, backend = by_name["compile.lower"], by_name["compile.backend"]
+    assert lower["parent"] == backend["parent"] == "obs.executables"
+    assert lower["args"] == {"round": 1, "program": "round.sync"}
+    assert backend["args"]["program"] == "round.sync"
+    assert backend["args"]["round"] == 1 and "cache" in backend["args"]
+    # the first flush of a fit would carry the same phases
+    assert "setup.experiment" in first.tracer.drain()
+    # a second Experiment of the process has a record of its own, and the
+    # module's import is only the first one's
+    second = _driven(_tiny_cfg(tmp_path / "b"))
+    again = [e["name"] for e in second.tracer.startup_record()]
+    assert again.count("setup.experiment") == 1
+    assert again.count("compile.lower") == names.count("compile.lower")
+    assert "setup.import" not in again
+    assert len(first.tracer.startup_record()) == len(record) + 1  # + compile
+
+
+def test_spans_off_keeps_no_record(tmp_path):
+    from colearn_federated_learning_tpu.obs import spans as spans_mod
+
+    exp = _driven(_tiny_cfg(tmp_path, **{"run.obs.spans": False}))
+    assert exp.tracer.span("setup.experiment") is _NULL_SPAN
+    assert exp.tracer.startup_record() == [] and exp.tracer.drain() == {}
+    assert exp.tracer not in spans_mod.live_tracers()
+    _NULL_SPAN.note(cache="hit")  # what the registry and the loader call
+    assert _NULL_SPAN.cache is None
