@@ -21,6 +21,8 @@ Algorithm hooks:
 - Padded steps (mask all-zero) are algebraic no-ops: the parameter and
   optimizer-state updates are gated on step validity, so heterogeneous
   clients running out of data early do not drift via momentum decay.
+  The per-client scan still executes them; the megabatch block trainer
+  does not (``_block_steps``).
 """
 
 from __future__ import annotations
@@ -184,6 +186,22 @@ def shared_weight_phase(params) -> bool:
     return windowed_conv_share(params) <= 0.5
 
 
+def block_group(params, width: int) -> int:
+    """How many of a block's ``width`` clients take a diverged step
+    together, under one conditional (``_block_steps``); a divisor of
+    ``width``. One for a model of windowed convolutions: a client's
+    convolutions share nothing with its neighbour's, and alone they run
+    faster than batched over the block (ResNet-18 at batch 64 on the
+    v5e, every step live: 2.76 / 3.22 / 3.11 / 3.09 ms a client-step in
+    groups of 1 / 2 / 4 / 16, and 3.09 vmapped as before; PERF.md,
+    PR 35), so each client's dead steps are skipped. The whole block
+    for every other model: its diverged steps are batched GEMMs across
+    the clients, which is what the megabatch layout is for and has not
+    been measured in smaller groups; a step is then skipped only where
+    no client of the block has a row left."""
+    return width if shared_weight_phase(params) else 1
+
+
 class _DecomposedLoRA:
     """Megabatch view of a LoRA model: ``apply`` delegates to
     ``apply_decomposed`` (models/lora.py) so the frozen base is never
@@ -227,16 +245,26 @@ def make_local_train_fn(model, client_cfg: ClientConfig, dp_cfg: DPConfig, task:
     stateful algorithms' per-client correction) is not supported in the
     block signature — config.validate() rejects the pairing.
 
+    Dead steps are skipped (PR 35): the diverged steps are one loop,
+    ``_block_steps``, a scan over steps of a scan over the block's
+    groups of :func:`block_group` clients, and a group's step runs
+    under ``lax.cond`` on whether any of its batches holds a real row.
+    A client whose data ends before the grid does (most clients of a
+    cross-device federation) costs nothing from there on, where the
+    vmapped step computed a full forward, backward and update and
+    multiplied them by zero. A skipped step leaves what the executed
+    one left, to the bit (``_block_steps``).
+
     A model of windowed convolutions (:func:`shared_weight_phase` false:
     ``windowed_conv_share > 0.5``) runs no shared-weight phase: its
-    block is ``jax.vmap(local_train)``, every step diverged. A
-    convolution's GEMM rows are batch·H·W already, so one shared weight
-    buys it no rows, and on the v5e its shared step is the slow one (3x3
-    weight-gradient convolutions over the megabatch's activation
-    layout; PERF.md, PR 24), while models of GEMMs (pointwise
-    convolutions, Dense, attention) lose rounds/s without it. No model
-    of the zoo lies between 0.04 and 0.98, and nothing in that range has
-    been measured.
+    block is what ``jax.vmap(local_train)`` computes, every step
+    diverged and in that loop. A convolution's GEMM rows are batch·H·W
+    already, so one shared weight buys it no rows, and on the v5e its
+    shared step is the slow one (3x3 weight-gradient convolutions over
+    the megabatch's activation layout; PERF.md, PR 24), while models of
+    GEMMs (pointwise convolutions, Dense, attention) lose rounds/s
+    without it. No model of the zoo lies between 0.04 and 0.98, and
+    nothing in that range has been measured.
 
     ``batch_axis``: when the mesh carries a second axis that data-parallels
     each client's minibatch (mesh.py ``BATCH_AXIS``), every shard holds
@@ -493,6 +521,65 @@ def make_local_train_fn(model, client_cfg: ClientConfig, dp_cfg: DPConfig, task:
             "axis (run.batch_shards > 1)"
         )
 
+    def _block_steps(step, carry, xs, size):
+        """The block's step loop: a scan over steps of a scan over the
+        block's groups of ``size`` clients, each group's step under a
+        real conditional. ``carry``: stacked ``[width, ...]`` parameters
+        and optimizer state; ``xs``: ``(idx, mask, keys)`` with leading
+        axes ``[steps, width]``. Returns the carry and the ``[steps,
+        width]`` weighted losses.
+
+        A group's step whose batches hold no real row is not executed.
+        What it would have left behind is what it found: the step gates
+        its update on the same count (``p - 0*d``, ``1*m + 0*g``,
+        ``loss * 0``; the optax branch selects the old tree), so
+        skipping changes no finite result. Under ``vmap`` a conditional
+        on a batched predicate is a select whose both sides run, hence
+        the loop over groups: one predicate a group, one compiled body
+        for all of them. The group's slice is read from and written
+        back into the carry in place; as the inner scan's ``xs -> ys``
+        the stack would be held twice."""
+        width = xs[1].shape[1]
+        groups = width // size
+
+        # vmap also where a group is one client: with the step called on
+        # the squeezed slice instead, XLA for the v5e copies a whole 357 MB
+        # stack into another memory space and back in every live step
+        # (PERF.md, PR 35: r18_c16_k8 2.59 rounds/s against 3.81)
+        group_step = jax.vmap(step)
+
+        def one_group(carry, inp):
+            start, x = inp
+            rows = _global_count(x[1])
+
+            def run(carry):
+                part = jax.tree.map(
+                    lambda a: jax.lax.dynamic_slice_in_dim(a, start, size),
+                    carry)
+                part, weighted_loss = group_step(part, x)
+                return jax.tree.map(
+                    lambda a, p: jax.lax.dynamic_update_slice_in_dim(
+                        a, p, start, 0),
+                    carry, part), weighted_loss
+
+            def skip(carry):
+                # the step's weighted loss, loss * 0, tied to the data
+                # like local_train's vary0 so both sides type alike
+                # under shard_map
+                return carry, jnp.zeros((size,), jnp.float32) + 0.0 * rows
+
+            return jax.lax.cond(rows > 0, run, skip, carry)
+
+        def all_groups(carry, x):
+            grouped = jax.tree.map(
+                lambda a: a.reshape((groups, size) + a.shape[1:]), x)
+            starts = jnp.arange(groups, dtype=jnp.int32) * size
+            carry, weighted_loss = jax.lax.scan(
+                one_group, carry, (starts, grouped))
+            return carry, weighted_loss.reshape(width)
+
+        return jax.lax.scan(all_groups, carry, xs, unroll=scan_unroll)
+
     def local_train_block(global_params, train_x, train_y, idx, mask, keys,
                           lr_scale=None, grad_corr=None):
         """Megabatched block trainer — see the factory docstring.
@@ -503,48 +590,51 @@ def make_local_train_fn(model, client_cfg: ClientConfig, dp_cfg: DPConfig, task:
                 "megabatch block training does not support grad_corr "
                 "(stateful algorithms are spatial-layout only)"
             )
-        if not shared_weight_phase(global_params):
-            # no shared-weight phase (factory docstring): the spatial
-            # layout over the whole block
-            return jax.vmap(
-                local_train, in_axes=(None, None, None, 0, 0, 0, None)
-            )(global_params, train_x, train_y, idx, mask, keys, lr_scale)
         global_params = _cast_params(global_params)
         step = _make_step(global_params, train_x, train_y, lr_scale, None)
-        steps = idx.shape[1]
+        width, steps = idx.shape[:2]
+        n = jax.vmap(_global_count)(mask)
         # identical per-client key derivation as the per-client path:
         # split(rng_c, steps), consumed in step order
         step_keys = jax.vmap(lambda k: jax.random.split(k, steps))(keys)
+        xs = jax.tree.map(lambda a: jnp.swapaxes(a, 0, 1),
+                          (idx, mask, step_keys))
         base_state = _base_opt_state(global_params)
-        # Shared-weight phase (step 0): params AND the fresh optimizer
-        # state are replicated across the block — only the data is
-        # batched — so XLA sees the forward / activation-gradient
-        # contractions as single [C·batch, ...] × [..., d] GEMMs
-        # against ONE weight. No vary0 tie-in needed here: the carry
-        # leaves the vmap already data-derived (device-varying).
-        carry0, wl0 = jax.vmap(
-            lambda i, m, k: step((global_params, base_state), (i, m, k))
-        )(idx[:, 0], mask[:, 0], step_keys[:, 0])
-        if steps > 1:
-            # diverged phase: per-client params — the lane-local vmap
-            # (one batched GEMM per layer) over the SAME step fn
-            def scan_body(carry, inp):
-                return jax.vmap(step)(carry, inp)
-
-            xs = jax.tree.map(
-                lambda a: jnp.swapaxes(a[:, 1:], 0, 1),
-                (idx, mask, step_keys),
-            )
-            (params_c, _), wls = jax.lax.scan(
-                scan_body, carry0, xs, unroll=scan_unroll
-            )
-            weighted_losses = jnp.concatenate([wl0[None], wls], axis=0)
+        losses = []
+        if shared_weight_phase(global_params):
+            # Shared-weight phase (step 0): params AND the fresh optimizer
+            # state are replicated across the block — only the data is
+            # batched — so XLA sees the forward / activation-gradient
+            # contractions as single [C·batch, ...] × [..., d] GEMMs
+            # against ONE weight. No vary0 tie-in needed here: the carry
+            # leaves the vmap already data-derived (device-varying).
+            carry, first = jax.vmap(
+                lambda x: step((global_params, base_state), x)
+            )(jax.tree.map(lambda a: a[0], xs))
+            losses.append(first[None])
+            xs = jax.tree.map(lambda a: a[1:], xs)
         else:
-            params_c = carry0[0]
-            weighted_losses = wl0[None]
-        n = jax.vmap(_global_count)(mask)
-        mean_loss = weighted_losses.sum(0) / jnp.maximum(n, 1.0)
-        return params_c, LocalMetrics(loss=mean_loss, examples=n)
+            # no shared-weight phase (factory docstring): every step on
+            # the clients' own weights, from the broadcast and the fresh
+            # optimizer state tied to the data as local_train ties it
+            vary0 = 0.0 * n
+
+            def stack(x, tie=None):
+                x = jnp.broadcast_to(x, (width,) + x.shape)
+                if tie is None:
+                    return x
+                return x + tie.astype(x.dtype).reshape(
+                    (width,) + (1,) * (x.ndim - 1))
+
+            carry = (jax.tree.map(stack, global_params),
+                     jax.tree.map(lambda x: stack(x, vary0), base_state))
+        if xs[0].shape[0]:
+            # diverged steps: per-client params, the SAME step fn
+            carry, rest = _block_steps(
+                step, carry, xs, block_group(global_params, width))
+            losses.append(rest)
+        mean_loss = jnp.concatenate(losses).sum(0) / jnp.maximum(n, 1.0)
+        return carry[0], LocalMetrics(loss=mean_loss, examples=n)
 
     return local_train_block
 

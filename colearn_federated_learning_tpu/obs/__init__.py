@@ -38,6 +38,7 @@ run's JSONL into a per-phase timing/throughput table.
 """
 
 from colearn_federated_learning_tpu.obs.counters import (  # noqa: F401
+    block_step_counts,
     device_memory_stats,
     gossip_round_bytes,
     round_comm_bytes,

@@ -31,7 +31,8 @@ obs.roofline`) extends the same analytic-purity discipline from wire
 bytes to FLOPs/HBM bytes per round-program phase; its ``local_train``
 byte floor consumes :func:`round_host_input_bytes`, and the waterfall's
 padding component consumes :func:`round_shape_stats`'s
-``padded_step_fraction`` gauge.
+``padded_step_fraction`` gauge. :func:`block_step_counts` says how many
+of those padded steps the megabatch block trainer does not execute.
 """
 
 from __future__ import annotations
@@ -147,6 +148,44 @@ def round_shape_stats(spec, steps: int, batch: int,
         "padded_example_fraction": round(
             1.0 - float(real_examples.sum()) / total_examples, 4
         ),
+    }
+
+
+def block_step_counts(mask, steps: int, batch: int, local_epochs: int,
+                      width: int, group: int,
+                      shared_first: bool) -> Dict[str, int]:
+    """What the megabatch block trainer does with one round's grid, by
+    its own grouping rule (``client/trainer.py``: ``_block_steps``,
+    ``block_group``), from the ``[K, 2]`` mask spec (or the full ``[K,
+    steps, batch]`` mask): consecutive blocks of ``width`` clients, a
+    block's consecutive ``group`` clients a conditional.
+
+    - ``client_steps``: the grid, ``K x steps``.
+    - ``dead_steps``: client-steps without a real row (what
+      :func:`round_shape_stats` calls ``padded_step_fraction``).
+    - ``skipped_steps``: client-steps the program does not execute:
+      those of a group whose every client is dead at that step. With
+      ``shared_first`` the first step runs for the whole block whatever
+      its masks (the shared-weight phase).
+
+    ``tests/test_trainer.py`` lays it beside the predicates the
+    program's conditional evaluates."""
+    import numpy as np
+
+    mask = np.asarray(mask)
+    if mask.ndim == 2:  # the spec: data/loader.expand_mask_spec's rule
+        s = np.arange(steps)
+        spe = max(1, steps // max(1, local_epochs))
+        live = (((s % spe) * batch)[None] < mask[:, :1]) & (s[None] < mask[:, 1:])
+    else:
+        live = mask.sum(-1) > 0
+    ran = live.reshape(-1, width // group, group, steps).any(2)
+    if shared_first:
+        ran[:, :, 0] = True
+    return {
+        "client_steps": int(live.size),
+        "dead_steps": int(live.size - live.sum()),
+        "skipped_steps": int((ran.size - ran.sum()) * group),
     }
 
 

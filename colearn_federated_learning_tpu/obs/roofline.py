@@ -23,7 +23,10 @@ backend initialization):
    the dense 6·P·B approximation (default — no extra compile).
 2. **Waterfall** — :func:`waterfall`: headline MFU decomposed into
    effective compute, padding loss (``padded_step_fraction`` dead
-   steps), non-matmul compute (the cost model's non-train phases at
+   steps: executed at full cost in the spatial layout; the megabatch
+   block trainer skips those of a group whose every client is dead,
+   ``obs/counters.block_step_counts``, which this term does not read
+   yet), non-matmul compute (the cost model's non-train phases at
    roofline speed), host-exposed time (spans not hidden under
    ``round.dispatch``), and residual kernel inefficiency. The
    components sum to 100% of wall time within
@@ -386,7 +389,13 @@ def waterfall(phase_costs: Dict[str, Dict[str, int]],
     - ``headline_mfu_pct`` — the bench's number: padded-grid local-
       train FLOPs × rounds/sec ÷ peak.
     - ``effective_compute`` + ``padding`` — the headline split by
-      ``padded_step_fraction`` (dead scan steps burn full-step FLOPs).
+      ``padded_step_fraction``: the grid's dead scan steps, each at a
+      full step's FLOPs. That is what the spatial layout executes; the
+      megabatch block trainer runs a group's step under a conditional
+      and skips it where the whole group is dead
+      (``client/trainer.py`` ``_block_steps``), so there the term
+      overstates what was burnt by ``skipped_steps`` of
+      ``obs/counters.block_step_counts``.
     - ``non_matmul`` — the cost model's non-train phases at roofline
       speed (each phase's max(compute, memory) floor).
     - ``host_exposed`` — measured span time NOT hidden under

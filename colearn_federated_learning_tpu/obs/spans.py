@@ -15,6 +15,12 @@ Design constraints, in order:
    metrics-flush boundaries and logs ONE ``spans`` record per window —
    the JSONL stays one-line-per-round-scale, not one-line-per-span.
 
+Counts ride spans: ``Tracer.count(name, **counts)`` adds whole numbers
+to the aggregate of the span of that name, and ``drain()`` reports their
+sums beside its ``count`` / ``total_ms`` (the block trainer's
+client-steps, dead and skipped, on ``round.host_inputs.slab_build``), so
+a ratio is measured where the work is laid out.
+
 One clock: while a ``jax.profiler`` session runs (``--profile N``, the
 benchmark's traced run) every enabled span is also an event of its name
 on the ``/host:CPU`` plane of that trace, on the thread that opened it
@@ -269,6 +275,8 @@ class Tracer:
         self._lock = threading.Lock()
         # name -> [count, total_s, max_s, self_s]
         self._agg: Dict[str, List[float]] = {}
+        # name -> {what: sum} of the counts that ride that span
+        self._counts: Dict[str, Dict[str, int]] = {}
         # per-thread span stacks; a thread's lane index is the order in
         # which it first opened a span here (the Chrome trace's tid)
         self._local = threading.local()
@@ -317,6 +325,20 @@ class Tracer:
         if self.enabled:
             self._record(name, start, end - start, end - start,
                          self._lane().index, args or None)
+
+    def count(self, name: str, **counts: int) -> None:
+        """Adds ``counts`` to what the span ``name`` has counted since
+        the last :meth:`drain`, which reports the sums in that span's
+        entry (module docstring)."""
+        if not self.enabled:
+            return
+        with self._lock:
+            # a drain between the span's end and this call: the entry
+            # still has every key its readers expect
+            self._agg.setdefault(name, [0, 0.0, 0.0, 0.0])
+            held = self._counts.setdefault(name, {})
+            for what, n in counts.items():
+                held[what] = held.get(what, 0) + int(n)
 
     def _lane(self) -> _Lane:
         lane = getattr(self._local, "lane", None)
@@ -433,13 +455,14 @@ class Tracer:
 
     def drain(self) -> Dict[str, Dict[str, float]]:
         """Return and reset the per-phase aggregates since the last
-        drain: ``{phase: {count, total_ms, max_ms, self_ms}}``, with
-        compiles (retraces included) reported as the ``compile``
-        pseudo-phase (which nothing nests in: all of it is self time;
-        it also counts the persistent cache's ``cache_hits`` and
-        ``cache_misses``)."""
+        drain: ``{phase: {count, total_ms, max_ms, self_ms}}`` and what
+        :meth:`count` added to a phase beside them, with compiles
+        (retraces included) reported as the ``compile`` pseudo-phase
+        (which nothing nests in: all of it is self time; it also counts
+        the persistent cache's ``cache_hits`` and ``cache_misses``)."""
         with self._lock:
             agg, self._agg = self._agg, {}
+            counts, self._counts = self._counts, {}
             compiles, self._compiles = self._compiles, 0
             csecs, self._compile_secs = self._compile_secs, 0.0
             cmax, self._compile_max = self._compile_max, 0.0
@@ -460,6 +483,8 @@ class Tracer:
             }
             for name, (c, t, m, own) in sorted(agg.items())
         }
+        for name, held in counts.items():
+            out[name].update(held)
         if compiles:
             out["compile"] = {
                 "count": compiles,

@@ -24,6 +24,7 @@ import numpy as np
 
 from colearn_federated_learning_tpu.client.trainer import (
     RoundData,
+    block_group,
     make_eval_fn,
     make_local_train_fn,
     shared_weight_phase,
@@ -47,6 +48,7 @@ from colearn_federated_learning_tpu.obs import (
     HealthAbortError,
     HealthMonitor,
     Tracer,
+    block_step_counts,
     device_memory_stats,
     gossip_round_bytes,
     round_comm_bytes,
@@ -423,6 +425,10 @@ class Experiment:
         # below. _param_stats_cache backs both the HBM
         # pre-flight and the comm-byte model.
         self._param_stats_cache = None
+        self._param_shapes_cache = None
+        # the megabatch block trainer's (width, group, shared first
+        # step), for _count_block_steps; None: another layout
+        self._block = None
         self._comm_stats: Dict[int, Dict[str, int]] = {}
         self._fail_stats: Dict[int, Dict[str, int]] = {}
         # unfused engine twin for non-chunk-aligned resumes under
@@ -616,6 +622,12 @@ class Experiment:
                 self._cohort_sharding = mesh_lib.cohort_sharded(self.mesh)
                 self._client_sharding = mesh_lib.client_sharded(self.mesh)
                 self.n_chips = lanes * batch_shards
+                if cfg.run.cohort_layout == "megabatch":
+                    width = (self._poisson_cap
+                             or cfg.server.cohort_size) // lanes
+                    shapes = self._param_shapes()
+                    self._block = (width, block_group(shapes, width),
+                                   shared_weight_phase(shapes))
                 # per-client state store rows: N padded up to a lane multiple
                 # (pad rows are never sampled into a cohort, so they stay 0)
                 self._state_rows = -(-self.fed.num_clients // lanes) * lanes
@@ -1043,11 +1055,10 @@ class Experiment:
                     f"risk explicitly"
                 )
 
-    def _param_stats(self) -> tuple:
-        """(n_coords, bytes) of one params tree at run.param_dtype, via
-        eval_shape (no compute, no device memory — shapes only). Cached:
-        the HBM pre-flight and the per-round comm-byte model share it."""
-        if self._param_stats_cache is None:
+    def _param_shapes(self):
+        """The params tree's shapes (eval_shape: no compute, no device
+        memory). Cached: the model is traced once for it."""
+        if self._param_shapes_cache is None:
             from colearn_federated_learning_tpu.client.trainer import (
                 normalize_input,
             )
@@ -1057,13 +1068,33 @@ class Experiment:
                 self.fed.train_x.dtype,  # LM corpora are int tokens — an
                 # f32 dummy would crash nn.Embed's integer check
             )
-            shapes = jax.eval_shape(
+            self._param_shapes_cache = jax.eval_shape(
                 lambda d: self.model.init(
                     jax.random.PRNGKey(0), normalize_input(d), train=False
                 )["params"],
                 dummy,
             )
-            leaves = jax.tree.leaves(shapes)
+        return self._param_shapes_cache
+
+    def _count_block_steps(self, mask, shape) -> None:
+        """Under the megabatch layout: the round's client-steps, the
+        dead ones and those the block trainer skips
+        (``obs/counters.block_step_counts``), on the span that built
+        the inputs."""
+        if self._block and self.tracer.enabled:
+            self.tracer.count(
+                "round.host_inputs.slab_build",
+                **block_step_counts(
+                    mask, shape.steps, shape.batch_size, shape.local_epochs,
+                    *self._block,
+                ))
+
+    def _param_stats(self) -> tuple:
+        """(n_coords, bytes) of one params tree at run.param_dtype, via
+        eval_shape (no compute, no device memory — shapes only). Cached:
+        the HBM pre-flight and the per-round comm-byte model share it."""
+        if self._param_stats_cache is None:
+            leaves = jax.tree.leaves(self._param_shapes())
             coords = sum(int(np.prod(l.shape)) for l in leaves)
             nbytes = sum(
                 int(np.prod(l.shape)) * l.dtype.itemsize for l in leaves
@@ -1913,6 +1944,7 @@ class Experiment:
                     [mask, np.zeros((pad,) + mask.shape[1:], mask.dtype)]
                 )
                 n_ex = np.concatenate([n_ex, np.zeros(pad, n_ex.dtype)])
+        self._count_block_steps(mask, shape)
         slab = (
             self._stream_slab(idx) if self._stream and build_slab else None
         )

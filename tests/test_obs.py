@@ -162,6 +162,33 @@ def test_an_own_round_argument_wins_over_the_inherited_one():
     assert "args" not in events["outside"]
 
 
+def test_counts_ride_a_spans_aggregate_until_the_next_drain():
+    clock = iter(float(t) for t in range(100))
+    tracer = Tracer(enabled=True, clock=lambda: next(clock))
+    for dead in (5, 7):
+        with tracer.span("round.host_inputs.slab_build"):
+            pass
+        tracer.count("round.host_inputs.slab_build",
+                     client_steps=32, dead_steps=dead)
+    with tracer.span("round.run"):
+        pass
+    agg = tracer.drain()
+    assert agg["round.host_inputs.slab_build"] == {
+        "count": 2, "total_ms": 2000.0, "max_ms": 1000.0, "self_ms": 2000.0,
+        "client_steps": 64, "dead_steps": 12}
+    assert set(agg["round.run"]) == {"count", "total_ms", "max_ms", "self_ms"}
+    # a count that lands after the drain that took its span: an entry of
+    # its own in the next window, with the keys every reader expects
+    tracer.count("round.host_inputs.slab_build", client_steps=32)
+    assert tracer.drain() == {"round.host_inputs.slab_build": {
+        "count": 0, "total_ms": 0.0, "max_ms": 0.0, "self_ms": 0.0,
+        "client_steps": 32}}
+    assert tracer.drain() == {}
+    off = Tracer(enabled=False)
+    off.count("round.host_inputs.slab_build", client_steps=32)
+    assert off.drain() == {}
+
+
 def test_tracer_disabled_is_noop():
     tracer = Tracer(enabled=False)
     assert tracer.span("anything") is _NULL_SPAN  # shared singleton

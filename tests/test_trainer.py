@@ -7,7 +7,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from colearn_federated_learning_tpu.client import trainer as trainer_mod
 from colearn_federated_learning_tpu.client.trainer import (
+    block_group,
     make_local_train_fn,
     make_loss_fn,
     shared_weight_phase,
@@ -128,13 +130,18 @@ class _DenseOnly(nn.Module):
 # hundredfold per local step at any lr that trains (GroupNorm over a few
 # elements, relu6 kinks): its block reads 2.5e-5 against spatial after
 # two real steps, at the commit before PR 24 and here alike. ResNet-18
-# runs no shared-weight step and IS the spatial layout.
+# runs no shared-weight step and IS the spatial layout, since PR 35 a
+# client's step alone under a conditional: against the vmap over the
+# block, whose convolutions XLA batches, it reads 1.19e-7 at most after
+# three steps (one ulp of a weight between 1 and 2) and 5.96e-8 after
+# one, where it was the same program and 0 before (_R18).
 _TIGHT = (1e-6, 2e-5)
+_R18 = (2.4e-7, 0)
 _BLOCK_CASES = {
     # windowed convolutions: no shared-weight phase
     "resnet18": (
         lambda: build_model("resnet18", num_classes=10, width=8),
-        (32, 32, 3), dict(momentum=0.9), (0, 0),
+        (32, 32, 3), dict(momentum=0.9), _R18,
     ),
     # pointwise and depthwise kernels: shared-weight phase; FedProx
     # against the un-batched global
@@ -264,7 +271,171 @@ def test_which_models_run_a_shared_weight_phase(name, steps):
             jax.random.split(jax.random.PRNGKey(0), clients))
     jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
     loops = [e.params["length"] for e in _scans(jaxpr)]
-    assert loops == ([steps - shared] if steps > shared else [])
+    # the loop over steps and, inside it, the loop over the block's
+    # groups of clients
+    assert loops == ([steps - shared, clients // block_group(params, clients)]
+                     if steps > shared else [])
     out_shapes = jax.eval_shape(fn, *args)[0]
     jax.tree.map(lambda p, o: np.testing.assert_equal(
         o.shape, (clients,) + p.shape), params, out_shapes)
+
+
+# -- the block's step loop: a group's step under a real conditional --
+
+# examples a client: one with none, two that fill every step (3 steps
+# of 4), and no order among them
+_BLOCK_EXAMPLES = [0, 12, 4, 5, 12, 1, 8, 9]
+_LOOP_CASES = [(opt, path) for path in ("diverged", "shared")
+               for opt in ("sgd_momentum", "adamw")]
+
+
+def _loop_inputs(steps=3, batch=4):
+    from colearn_federated_learning_tpu.data.loader import expand_mask_spec
+
+    rng = np.random.default_rng(0)
+    clients = len(_BLOCK_EXAMPLES)
+    x, y = _fake_data()
+    idx = jnp.asarray(
+        rng.integers(0, 64, (clients, steps, batch)).astype(np.int32))
+    spec = np.stack([_BLOCK_EXAMPLES, np.full(clients, steps)], 1)
+    mask = jnp.asarray(expand_mask_spec(spec, steps, batch, 1))
+    keys = jax.random.split(jax.random.PRNGKey(3), clients)
+    return spec, (x, y, idx, mask, keys)
+
+
+def _loop_cfg(opt):
+    if opt == "adamw":
+        return ClientConfig(local_epochs=1, batch_size=4, lr=1e-3,
+                            optimizer="adamw")
+    return ClientConfig(local_epochs=1, batch_size=4, lr=0.02, momentum=0.9)
+
+
+def _assert_trees_bitwise(got, want):
+    jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+        np.asarray(a), np.asarray(b)), got, want)
+
+
+# against jax.vmap(local_train), the largest difference of any parameter
+# of any client, measured here (PR 35) and allowed: the diverged block
+# under SGD with momentum is vmap(local_train) to the bit; under AdamW it
+# reads 2.98e-8, one ulp of a weight between 0.25 and 0.5 (a client's
+# convolution alone against the same one batched over the block, divided
+# by sqrt(nu)); the shared-weight block reads 2.98e-8 / 3.54e-8 (its
+# first step contracts the megabatch against one weight, as before PR 35)
+_LOOP_ATOL = {("sgd_momentum", "diverged"): 0.0,
+              ("adamw", "diverged"): 6e-8,
+              ("sgd_momentum", "shared"): 6e-8,
+              ("adamw", "shared"): 8e-8}
+
+
+@pytest.mark.parametrize("opt,path", _LOOP_CASES)
+def test_block_loop_skips_dead_steps_bitwise(lenet, monkeypatch, opt, path):
+    """The block's step loop against ``jax.vmap(local_train)``, every
+    client: the one without examples, the two that fill every step and
+    the unordered rest. Parameters, loss and examples are equal to the
+    bit for the diverged block (a model of windowed convolutions;
+    LeNet-5 made to take that path, each client's step alone) under SGD
+    with momentum, and within ``_LOOP_ATOL`` elsewhere. Skipping a dead
+    step leaves what multiplying it by zero left, to the bit, on both
+    paths and under both optimizers: the same block made to run every
+    step of the grid (its conditional replaced by its live branch, which
+    for the shared-weight block is the loop before PR 35). And the
+    conditional is taken where ``obs/counters.block_step_counts`` says:
+    the live predicates the program evaluates are counted beside the
+    host's rule on the spec and a loop over the grid."""
+    from colearn_federated_learning_tpu.obs.counters import block_step_counts
+
+    model, params = lenet
+    cfg = _loop_cfg(opt)
+    spec, args = _loop_inputs()
+    width, steps = args[3].shape[:2]
+    shared = path == "shared"
+    if not shared:
+        monkeypatch.setattr(trainer_mod, "shared_weight_phase",
+                            lambda params: False)
+    group = block_group(params, width)
+    assert group == (width if shared else 1)
+
+    def run_block():
+        block = make_local_train_fn(model, cfg, DPConfig(), "classify",
+                                    megabatch=True)
+        out = jax.jit(block)(params, *args)
+        jax.effects_barrier()
+        return out
+
+    taken, cond = [], jax.lax.cond
+
+    def counted_cond(live, run, skip, carry):
+        jax.debug.callback(lambda p: taken.append(bool(p)), live)
+        return cond(live, run, skip, carry)
+
+    with monkeypatch.context() as counting:
+        counting.setattr(jax.lax, "cond", counted_cond)
+        w_b, m_b = run_block()
+    with monkeypatch.context() as every_step:
+        every_step.setattr(jax.lax, "cond",
+                           lambda live, run, skip, carry: run(carry))
+        w_r, m_r = run_block()
+    _assert_trees_bitwise(w_b, w_r)
+    np.testing.assert_array_equal(m_b.loss, m_r.loss)
+    np.testing.assert_array_equal(m_b.examples, m_r.examples)
+    # the client without examples comes back as it went in
+    cast = jax.tree.map(lambda p, w: p.astype(w.dtype), params, w_b)
+    _assert_trees_bitwise(jax.tree.map(lambda w: w[0], w_b), cast)
+
+    # the spatial layout: local_train vmapped over the whole block
+    w_s, m_s = jax.jit(jax.vmap(
+        make_local_train_fn(model, cfg, DPConfig(), "classify"),
+        in_axes=(None, None, None, 0, 0, 0)))(params, *args)
+    atol = _LOOP_ATOL[opt, path]
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(
+        np.asarray(a), np.asarray(b), atol=atol, rtol=0), w_b, w_s)
+    np.testing.assert_allclose(m_b.loss, m_s.loss, atol=0,
+                               rtol=1e-6 if atol else 0)
+    np.testing.assert_array_equal(m_b.examples, m_s.examples)
+    np.testing.assert_array_equal(m_b.examples,
+                                  1.0 * np.array(_BLOCK_EXAMPLES))
+
+    counts = block_step_counts(spec, steps, 4, 1, width, group, shared)
+    live = np.array([[s * 4 < n for s in range(steps)]
+                     for n in _BLOCK_EXAMPLES])
+    executed = 0
+    for s in range(steps):
+        for g0 in range(0, width, group):
+            if (shared and s == 0) or live[g0:g0 + group, s].any():
+                executed += group
+    assert counts == {"client_steps": width * steps,
+                      "dead_steps": int((~live).sum()),
+                      "skipped_steps": width * steps - executed}
+    assert (counts["skipped_steps"] > 0) == (not shared)
+    # one predicate a group-step of the loop, true where it runs
+    assert len(taken) == (steps - shared) * (width // group)
+    assert sum(taken) * group + shared * width == executed
+    # the full mask gives the same counts as the spec
+    assert block_step_counts(np.asarray(args[3]), steps, 4, 1, width, group,
+                             shared) == counts
+
+
+def test_block_loop_is_a_conditional_and_no_select_over_the_parameters(
+        lenet, monkeypatch):
+    """The compiled block holds a ``conditional`` (``vmap`` over the
+    predicate would have made it a select whose both sides run), and no
+    ``select`` produces a parameter-shaped array: the fused SGD step has
+    none of its own."""
+    import re
+
+    model, params = lenet
+    _, args = _loop_inputs()
+    width = args[3].shape[0]
+    monkeypatch.setattr(trainer_mod, "shared_weight_phase",
+                        lambda params: False)
+    block = make_local_train_fn(model, _loop_cfg("sgd_momentum"), DPConfig(),
+                                "classify", megabatch=True)
+    text = jax.jit(block).lower(params, *args).compile().as_text()
+    assert len(re.findall(r" conditional\(", text)) == 1
+    shapes = {lead + tuple(p.shape) for p in jax.tree.leaves(params)
+              for lead in ((), (width,), (1,))}
+    selected = {tuple(int(d) for d in dims.split(",") if d)
+                for dims in re.findall(r"= \w+\[([\d,]*)\][^ ]* select\(",
+                                       text)}
+    assert not selected & shapes, selected & shapes
