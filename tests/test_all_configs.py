@@ -11,8 +11,11 @@ import pytest
 from colearn_federated_learning_tpu.config import get_named_config, list_named_configs
 from colearn_federated_learning_tpu.server.round_driver import Experiment
 
-# Per-config shrink overrides. Everything structural (algorithm, engine,
-# partition kind, dp.enabled, model family, task) is untouched.
+# Per-config shrink overrides, applied over the blanket ones below.
+# Everything structural (algorithm, engine, partition kind, dp.enabled,
+# model family, task) is untouched. The three decoders, whose kernels run
+# in Pallas interpret mode here, take one local step a round in a cohort
+# of two: their numerics at length are tests/test_*_decoder.py's.
 _SHRINK = {
     "mnist_fedavg_2": {},
     # the Keye decoder at a toy size: every mechanism (selection at
@@ -27,6 +30,7 @@ _SHRINK = {
         "model.kwargs.index_head_dim": 8, "model.kwargs.index_topk": 8,
         "model.kwargs.mrope_section": [1, 1, 2], "model.kwargs.q_chunk": 16,
         "model.kwargs.moe_tile": 4, "run.local_param_dtype": "",
+        "server.cohort_size": 2, "data.max_examples_per_client": 8,
     },
     # A.X-K1 at a toy size: latent attention at two query chunks, the
     # dense layer and one expert layer, 4 of 16 experts held in 4 groups
@@ -43,6 +47,7 @@ _SHRINK = {
         "model.kwargs.topk_group": 2, "model.kwargs.q_chunk": 16,
         "model.kwargs.moe_tile": 4, "model.lora.rank": 4,
         "run.local_param_dtype": "",
+        "server.cohort_size": 2, "data.max_examples_per_client": 8,
     },
     # Mellum2 at a toy size: two periods of (sliding, full), so the scan
     # over periods is a loop; 40 positions under a window of 12 in tiles
@@ -60,6 +65,7 @@ _SHRINK = {
         "model.kwargs.sliding_window": 12, "model.kwargs.rope_original": 16,
         "model.kwargs.q_chunk": 8, "model.kwargs.moe_tile": 4,
         "run.local_param_dtype": "",
+        "server.cohort_size": 2, "data.max_examples_per_client": 8,
     },
     "cifar10_fedavg_100": {"data.num_clients": 16, "model.kwargs.width": 16},
     # the north-star config keeps its FULL 1000-client federation — the
@@ -104,7 +110,6 @@ _SHRINK = {
     # rank 4 stays low-rank for the shrunk 64-hidden qkv kernels
     "vit_lora_dp": {
         "data.num_clients": 8,
-        "server.cohort_size": 8,
         "model.kwargs.image_size": 32,
         "model.kwargs.patch_size": 8,
         "model.kwargs.hidden": 64,
@@ -115,7 +120,6 @@ _SHRINK = {
     },
     "imagenet_silo_dp": {
         "data.num_clients": 8,
-        "server.cohort_size": 8,
         # shrink the ViT, keep the family + the DP path; image_size must
         # stay divisible by patch_size
         "model.kwargs.image_size": 32,
@@ -130,9 +134,8 @@ _SHRINK = {
 
 
 @pytest.mark.parametrize("name", list_named_configs())
-def test_named_config_runs_rounds(name, tmp_path):
+def test_named_config_runs_rounds(name, tmp_path, shallow_zoo):
     cfg = get_named_config(name)
-    cfg.apply_overrides(_SHRINK[name])
     cfg.apply_overrides({
         "server.num_rounds": 2,
         "server.eval_every": 1,
@@ -146,6 +149,7 @@ def test_named_config_runs_rounds(name, tmp_path):
         "run.metrics_flush_every": 1,
         "run.compute_dtype": "float32",
     })
+    cfg.apply_overrides(_SHRINK[name])
     cfg.validate()
     exp = Experiment(cfg, echo=False)
     state = exp.fit()

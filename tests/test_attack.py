@@ -437,34 +437,47 @@ def _fit_acc(tmp_path, name, **over):
     return exp.evaluate(state["params"])["eval_acc"]
 
 
-def test_sign_flip_breaks_fedavg_but_not_robust_aggregators(tmp_path):
+_SIGN_FLIP = {"attack.kind": "sign_flip", "attack.fraction": 0.25}
+_CHANCE = 0.1 + 0.2  # chance + margin
+
+
+@pytest.fixture(scope="module")
+def undefended_accs(tmp_path_factory):
+    """(benign, attacked) accuracy of the plain weighted mean: fitted
+    once for the defenses' cases below."""
+    out = tmp_path_factory.mktemp("undefended")
+    return (_fit_acc(out, "benign_mean"),
+            _fit_acc(out, "attacked_mean", **_SIGN_FLIP))
+
+
+_DEFENSES = {
+    "krum": {"server.aggregator": "krum", "server.krum_byzantine": 2},
+    "median": {"server.aggregator": "median"},
+    "trimmed_mean": {"server.aggregator": "trimmed_mean",
+                     "server.trim_ratio": 0.25},
+}
+
+
+@pytest.mark.parametrize("label", sorted(_DEFENSES))
+def test_sign_flip_breaks_fedavg_but_not_robust_aggregators(
+        tmp_path, undefended_accs, label):
     """THE acceptance story: sign_flip at f=2 of cohort 8 drives the
     undefended weighted mean to chance while each robust aggregator
     under the identical attack stays within ITS OWN benign-run accuracy
     band (krum converges slower than the mean by construction — it
     applies one client's update per round — so each defense is held to
     its own benign baseline, not FedAvg's)."""
-    attack = {"attack.kind": "sign_flip", "attack.fraction": 0.25}
-    benign_acc = _fit_acc(tmp_path, "benign_mean")
+    agg_over = _DEFENSES[label]
+    benign_acc, broken_acc = undefended_accs
     assert benign_acc > 0.75, benign_acc  # the task is learnable
-
-    broken_acc = _fit_acc(tmp_path, "attacked_mean", **attack)
-    assert broken_acc <= 0.1 + 0.2, (  # chance + margin
+    assert broken_acc <= _CHANCE, (
         f"weighted_mean survived sign_flip: {broken_acc}"
     )
-
-    defended = {
-        "krum": {"server.aggregator": "krum", "server.krum_byzantine": 2},
-        "median": {"server.aggregator": "median"},
-        "trimmed_mean": {"server.aggregator": "trimmed_mean",
-                         "server.trim_ratio": 0.25},
-    }
-    for label, agg_over in defended.items():
-        benign = _fit_acc(tmp_path, f"benign_{label}", **agg_over)
-        acc = _fit_acc(tmp_path, f"attacked_{label}", **attack, **agg_over)
-        assert acc >= benign - 0.15 and acc > 2 * (0.1 + 0.2), (
-            f"{label} failed to defend: attacked acc {acc} vs its "
-            f"benign {benign}"
-        )
-        # and the defense really was under the same fire FedAvg died to
-        assert acc > broken_acc + 0.2, (label, acc, broken_acc)
+    benign = _fit_acc(tmp_path, f"benign_{label}", **agg_over)
+    acc = _fit_acc(tmp_path, f"attacked_{label}", **_SIGN_FLIP, **agg_over)
+    assert acc >= benign - 0.15 and acc > 2 * _CHANCE, (
+        f"{label} failed to defend: attacked acc {acc} vs its "
+        f"benign {benign}"
+    )
+    # and the defense really was under the same fire FedAvg died to
+    assert acc > broken_acc + 0.2, (label, acc, broken_acc)

@@ -76,27 +76,55 @@ def _model_loss(model, adapters, frozen):
     return -jnp.take_along_axis(logp, TARGETS[..., None], -1)[..., 0].mean()
 
 
-def _ref_loss(adapters, frozen):
-    return jnp.mean(jnp.stack([
-        ref.loss(adapters, frozen, TOKENS[b], TARGETS[b], SIZES, jnp.float32)
-        for b in range(2)]))
-
-
-def test_logits_and_loss_match_the_reference(setup):
+@pytest.fixture(scope="module")
+def model_side(setup):
+    """One compiled program for the three tests below: (logits and aux
+    of the forward pass, the training loss, its gradient in the
+    adapters)."""
     model, frozen, adapters = setup
-    logits, aux = model.apply({"params": adapters, "frozen": frozen}, TOKENS)
+
+    @jax.jit
+    def run(adapters, frozen):
+        logits, aux = model.apply({"params": adapters, "frozen": frozen},
+                                  TOKENS)
+        loss, grads = jax.value_and_grad(
+            lambda a: _model_loss(model, a, frozen))(adapters)
+        return logits, aux, loss, grads
+
+    return run(adapters, frozen)
+
+
+@pytest.fixture(scope="module")
+def reference_side(setup):
+    """The reference's (logits, (groups, chosen), loss, gradient), one
+    compiled program for both sequences."""
+    _, frozen, adapters = setup
+
+    @jax.jit
+    def run(adapters, frozen, tokens, targets):
+        logits, picked = ref.forward(frozen, adapters, tokens, SIZES,
+                                     jnp.float32)
+        loss, grads = jax.value_and_grad(ref.loss)(
+            adapters, frozen, tokens, targets, SIZES, jnp.float32)
+        return logits, picked, loss, grads
+
+    return [run(adapters, frozen, TOKENS[b], TARGETS[b]) for b in range(2)]
+
+
+def test_logits_and_loss_match_the_reference(model_side, reference_side):
+    logits, aux, loss, _ = model_side
     assert set(aux) == {"counters"}  # no auxiliary loss
-    for b in range(2):
-        want, _ = ref.forward(frozen, adapters, TOKENS[b], SIZES, jnp.float32)
+    for b, (want, _, _, _) in enumerate(reference_side):
         np.testing.assert_allclose(logits[b], want, atol=2e-4)
-    np.testing.assert_allclose(_model_loss(model, adapters, frozen),
-                               _ref_loss(adapters, frozen), rtol=1e-5)
+    np.testing.assert_allclose(
+        loss, jnp.mean(jnp.stack([side[2] for side in reference_side])),
+        rtol=1e-5)
 
 
-def test_adapter_gradients_match_the_reference(setup):
-    model, frozen, adapters = setup
-    got = jax.grad(lambda a: _model_loss(model, a, frozen))(adapters)
-    want = jax.grad(_ref_loss)(adapters, frozen)
+def test_adapter_gradients_match_the_reference(model_side, reference_side):
+    got = model_side[3]
+    want = jax.tree.map(lambda *g: sum(g) / 2,
+                        *(side[3] for side in reference_side))
     assert len(jax.tree.leaves(got)) == 20  # 5 projections x 2 x 2 factors
     for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(got)[0],
                             jax.tree.leaves(want)):
@@ -105,12 +133,10 @@ def test_adapter_gradients_match_the_reference(setup):
             g, w, atol=2e-5 * float(jnp.abs(w).max()) + 1e-8, err_msg=str(path))
 
 
-def test_counters_read_the_references_groups_and_experts(setup):
-    model, frozen, adapters = setup
-    _, aux = model.apply({"params": adapters, "frozen": frozen}, TOKENS)
-    for b in range(2):
-        _, (groups, chosen) = ref.forward(frozen, adapters, TOKENS[b], SIZES,
-                                          jnp.float32)
+def test_counters_read_the_references_groups_and_experts(model_side,
+                                                         reference_side):
+    aux = model_side[1]
+    for b, (_, (groups, chosen), _, _) in enumerate(reference_side):
         held = (chosen >= 4) & (chosen < 8)
         np.testing.assert_allclose(
             aux["counters"]["held_assignment_share"][b], held.mean(),
@@ -367,13 +393,15 @@ def test_one_round_through_run_round_is_the_references_round(one_round):
             ref.loss(adapters, frozen, x_all[r], y_all[r], SIZES, jnp.float32)
             for r in rows]))
 
+    # one compiled program for every client's every step
+    step = jax.jit(jax.value_and_grad(batch_loss))
     delta = jax.tree.map(jnp.zeros_like, before)
     losses, weights = [], []
     for c in range(len(cohort)):
         local, opt_state, client_loss = before, opt.init(before), []
         for s in range(idx.shape[1]):
             assert mask[c, s].all()
-            value, grads = jax.value_and_grad(batch_loss)(local, idx[c, s])
+            value, grads = step(local, jnp.asarray(idx[c, s]))
             updates, opt_state = opt.update(grads, opt_state, local)
             local = optax.apply_updates(local, updates)
             client_loss.append(float(value))
@@ -460,7 +488,12 @@ def test_the_round_program_takes_the_base_as_an_argument(named, one_round):
                 "data.max_examples_per_client": 16, "client.batch_size": 4,
                 "run.obs.executables": True}
         if named == "vit_lora_dp":
+            # a base of two narrow layers has kernels to look for as
+            # well as one of twelve at the published width
             over.update({"model.kwargs.image_size": 32,
+                         "model.kwargs.patch_size": 8,
+                         "model.kwargs.hidden": 64, "model.kwargs.layers": 2,
+                         "model.kwargs.heads": 2, "model.kwargs.mlp_dim": 128,
                          "model.num_classes": 10, "dp.microbatch_size": 2})
         exp = Experiment(resolve_config(named, over), echo=False)
         _, texts = _run_round(exp, exp.init_state())

@@ -391,8 +391,28 @@ _FAULT_ATTACK = {"attack.kind": "sign_flip", "attack.fraction": 0.125}
 _FAULT_CHURN = dict(_CHURN, **{"run.churn.dropout_hazard": 0.01})
 
 
+@pytest.fixture(scope="module")
+def undefended_accs(tmp_path_factory):
+    """(benign, attacked) accuracy of the plain weighted mean under
+    churn: fitted once for the two defenses' cases below."""
+    out = tmp_path_factory.mktemp("undefended")
+    return (_fit_acc(out, "churn_benign", **_FAULT_CHURN),
+            _fit_acc(out, "churn_attacked_mean", **_FAULT_CHURN,
+                     **_FAULT_ATTACK))
+
+
+_FAULT_DEFENSES = {
+    "krum": {"server.aggregator": "krum", "server.krum_byzantine": 2},
+    "reputation": {"run.obs.client_ledger.enabled": True,
+                   "server.reputation.enabled": True,
+                   "server.aggregator": "trimmed_mean",
+                   "server.trim_ratio": 0.25},
+}
+
+
+@pytest.mark.parametrize("defense", sorted(_FAULT_DEFENSES))
 def test_crashing_compromised_clients_break_mean_not_krum_or_reputation(
-    tmp_path,
+    tmp_path, undefended_accs, defense,
 ):
     """The fault-injection headline: sign_flip at f=2/16 (scale 10)
     WITH diurnal churn + mid-round crashes on everyone, compromised
@@ -407,36 +427,19 @@ def test_crashing_compromised_clients_break_mean_not_krum_or_reputation(
     clipping by design, so nothing bounds round 0 — robust order
     statistics are the structural answer there, and trust composes
     with them.)"""
-    benign_acc = _fit_acc(tmp_path, "churn_benign", **_FAULT_CHURN)
+    benign_acc, broken_acc = undefended_accs
     assert benign_acc > 0.6, benign_acc  # learnable even under churn
-
-    broken_acc = _fit_acc(tmp_path, "churn_attacked_mean", **_FAULT_CHURN,
-                          **_FAULT_ATTACK)
     assert broken_acc <= 0.35, (
         f"weighted_mean survived sign_flip under churn: {broken_acc}"
     )
-
-    krum_over = {"server.aggregator": "krum", "server.krum_byzantine": 2}
-    krum_benign = _fit_acc(tmp_path, "churn_benign_krum", **_FAULT_CHURN,
-                           **krum_over)
-    krum_acc = _fit_acc(tmp_path, "churn_attacked_krum", **_FAULT_CHURN,
-                        **_FAULT_ATTACK, **krum_over)
-    assert krum_acc >= krum_benign - 0.15 and krum_acc > broken_acc + 0.2, (
-        f"krum failed under churn+attack: {krum_acc} vs benign "
-        f"{krum_benign}, broken mean {broken_acc}"
-    )
-
-    rep_over = {"run.obs.client_ledger.enabled": True,
-                "server.reputation.enabled": True,
-                "server.aggregator": "trimmed_mean",
-                "server.trim_ratio": 0.25}
-    rep_benign = _fit_acc(tmp_path, "churn_benign_rep", **_FAULT_CHURN,
-                          **rep_over)
-    rep_acc = _fit_acc(tmp_path, "churn_attacked_rep", **_FAULT_CHURN,
-                       **_FAULT_ATTACK, **rep_over)
-    assert rep_acc >= rep_benign - 0.15 and rep_acc > broken_acc + 0.2, (
-        f"reputation-scaled trimmed mean failed under churn+attack: "
-        f"{rep_acc} vs benign {rep_benign}, broken mean {broken_acc}"
+    over = _FAULT_DEFENSES[defense]
+    benign = _fit_acc(tmp_path, f"churn_benign_{defense}", **_FAULT_CHURN,
+                      **over)
+    acc = _fit_acc(tmp_path, f"churn_attacked_{defense}", **_FAULT_CHURN,
+                   **_FAULT_ATTACK, **over)
+    assert acc >= benign - 0.15 and acc > broken_acc + 0.2, (
+        f"{defense} failed under churn+attack: {acc} vs benign "
+        f"{benign}, broken mean {broken_acc}"
     )
 
 

@@ -580,24 +580,34 @@ def _headline_cfg(out, engine):
     return cfg.validate()
 
 
-def test_smoke_headline_krum_byzantine_ledger(tmp_path):
+def test_smoke_headline_krum_byzantine_ledger(tmp_path, shallow_zoo):
     """CI smoke for the acceptance story: the headline adversarial
     config runs with the ledger on, sharded↔sequential ledgers agree,
     and the anomaly flag detects the known sign_flip set with
-    precision/recall >= 0.5 through `colearn clients`' scoring."""
-    leds, exps = {}, {}
-    for engine in ("sharded", "sequential"):
-        cfg = _headline_cfg(tmp_path / engine, engine)
-        exp, state = _fit(cfg)
-        leds[engine] = _ledger(state)
-        exps[engine] = exp
-    _assert_ledger_parity(leds["sharded"], leds["sequential"])
-    exp = exps["sharded"]
+    precision/recall >= 0.5 through `colearn clients`' scoring.
+
+    The engines are compared after ONE round, where both start from the
+    same parameters and differ in ulps: from the second round on krum's
+    pick among near-tied candidates feeds back into every cosine EMA,
+    and which candidate wins follows the rounding of the machine's
+    thread count (the five-round comparison passed on one machine and
+    failed on the next). The sharded side of it is the first round's
+    record of the five-round run that detection is scored on."""
+    cfg = _headline_cfg(tmp_path / "sharded", "sharded")
+    cfg.run.obs.client_ledger.log_every = cfg.run.metrics_flush_every = 1
+    exp, _ = _fit(cfg)
     assert len(exp.compromised) == 2  # f = 2/16 federation, cohort 8
     path = os.path.join(
         str(tmp_path / "sharded"), "cifar10_krum_byzantine.metrics.jsonl"
     )
     recs = [json.loads(l) for l in open(path)]
+    first = next(r for r in recs
+                 if r.get("event") == "client_ledger" and r["round"] == 1)
+    led_sh = np.zeros((16, LEDGER_WIDTH), np.float32)
+    led_sh[first["ids"]] = np.stack([first[c] for c in LEDGER_COLS], 1)
+    cfg = _headline_cfg(tmp_path / "sequential", "sequential")
+    cfg.server.num_rounds = 1
+    _assert_ledger_parity(led_sh, _ledger(_fit(cfg)[1]))
     report = clients_report(recs)
     atk = report["attack"]
     assert atk["n_compromised_seen"] >= 1

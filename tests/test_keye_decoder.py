@@ -65,48 +65,50 @@ def _model_losses(model, params, tokens, targets):
     return lm, aux["loss"]
 
 
-def test_logits_and_both_losses_match_the_reference(setup):
+@pytest.fixture(scope="module")
+def model_side(setup):
+    """One compiled program for the three tests below: (logits, aux,
+    both losses, each loss's gradient)."""
     model, params, tokens, targets = setup
-    logits, aux = model.apply({"params": params}, tokens, train=True)
-    lm, li = _model_losses(model, params, tokens, targets)
-    for b in range(2):
-        r_logits, r_li, _ = ref.forward(params, tokens[b], SIZES, jnp.float32)
-        np.testing.assert_allclose(logits[b], r_logits, atol=2e-4)
-        _, (r_lm, r_li2) = ref.losses(params, tokens[b], targets[b], SIZES,
-                                      jnp.float32)
-        np.testing.assert_allclose(lm[b], r_lm, rtol=1e-5)
-        np.testing.assert_allclose(li[b], r_li, rtol=1e-5)
-        np.testing.assert_allclose(r_li, r_li2, rtol=1e-6)
-    assert set(aux["counters"]) == set(model.aux_counters)
-    # T = 32, top-8: sum_t min(t + 1, 8) = 228 of 528 causal pairs
-    np.testing.assert_allclose(aux["counters"]["selected_key_share"],
-                               228 / 528, rtol=1e-6)
+
+    def losses(params):
+        logits, aux = model.apply({"params": params}, tokens, train=True)
+        logp = jax.nn.log_softmax(logits, -1)
+        lm = -jnp.take_along_axis(logp, targets[..., None], -1)[..., 0].mean(-1)
+        return (lm.sum(), aux["loss"].sum()), (logits, aux, lm)
+
+    @jax.jit
+    def run(params):
+        # one forward pass, and a backward pass for each loss
+        _, pull, (logits, aux, lm) = jax.vjp(losses, params, has_aux=True)
+        grads = [pull(ct)[0] for ct in ((1.0, 0.0), (0.0, 1.0))]
+        return logits, aux, lm, aux["loss"], grads
+
+    return run(params)
 
 
-def test_gradients_of_every_leaf_match_the_reference(setup):
-    model, params, tokens, targets = setup
-    got = jax.grad(lambda p: sum(
-        a.sum() for a in _model_losses(model, p, tokens, targets)))(params)
-    want = jax.grad(lambda p: sum(
-        ref.losses(p, tokens[b], targets[b], SIZES, jnp.float32)[0]
-        for b in range(2)))(params)
-    assert set(got) == set(want) == set(params)
-    for name in params:
-        scale = float(jnp.abs(want[name]).max())
-        if name == "layers_router":  # a share's gates are constants
-            assert scale == 0 and not np.any(np.asarray(got[name]))
-            continue
-        assert scale > 0, name
-        np.testing.assert_allclose(got[name], want[name],
-                                   atol=3e-5 * scale, err_msg=name)
+@pytest.fixture(scope="module")
+def reference_side(setup):
+    """The reference's forward, losses and gradient of their sum, one
+    compiled program a sequence."""
+    _, params, tokens, targets = setup
+
+    @jax.jit
+    def run(params, tokens, targets):
+        logits, li, _ = ref.forward(params, tokens, SIZES, jnp.float32)
+        (_, (lm, li2)), grads = jax.value_and_grad(
+            lambda p: ref.losses(p, tokens, targets, SIZES, jnp.float32),
+            has_aux=True)(params)
+        return logits, lm, li, li2, grads
+
+    return [run(params, tokens[b], targets[b]) for b in range(2)]
 
 
-def test_the_indexer_learns_from_its_own_loss_only(setup):
-    model, params, tokens, targets = setup
-    g_lm = jax.grad(lambda p: _model_losses(model, p, tokens, targets)[0]
-                    .sum())(params)
-    g_li = jax.grad(lambda p: _model_losses(model, p, tokens, targets)[1]
-                    .sum())(params)
+# reads the model's side alone, so it comes first: the two sides are
+# compiled inside two tests, not one
+def test_the_indexer_learns_from_its_own_loss_only(model_side, setup):
+    params = setup[1]
+    g_lm, g_li = model_side[4]
     for name in params:
         if name in INDEXER_LEAVES:
             assert not np.any(np.asarray(g_lm[name])), name
@@ -118,6 +120,38 @@ def test_the_indexer_learns_from_its_own_loss_only(setup):
         else:
             assert not np.any(np.asarray(g_li[name])), name
             assert np.any(np.asarray(g_lm[name])), name
+
+
+def test_logits_and_both_losses_match_the_reference(model_side,
+                                                    reference_side, setup):
+    model = setup[0]
+    logits, aux, lm, li, _ = model_side
+    for b, (r_logits, r_lm, r_li, r_li2, _) in enumerate(reference_side):
+        np.testing.assert_allclose(logits[b], r_logits, atol=2e-4)
+        np.testing.assert_allclose(lm[b], r_lm, rtol=1e-5)
+        np.testing.assert_allclose(li[b], r_li, rtol=1e-5)
+        np.testing.assert_allclose(r_li, r_li2, rtol=1e-6)
+    assert set(aux["counters"]) == set(model.aux_counters)
+    # T = 32, top-8: sum_t min(t + 1, 8) = 228 of 528 causal pairs
+    np.testing.assert_allclose(aux["counters"]["selected_key_share"],
+                               228 / 528, rtol=1e-6)
+
+
+def test_gradients_of_every_leaf_match_the_reference(model_side,
+                                                     reference_side, setup):
+    params = setup[1]
+    g_lm, g_li = model_side[4]
+    got = jax.tree.map(jnp.add, g_lm, g_li)
+    want = jax.tree.map(jnp.add, *(side[4] for side in reference_side))
+    assert set(got) == set(want) == set(params)
+    for name in params:
+        scale = float(jnp.abs(want[name]).max())
+        if name == "layers_router":  # a share's gates are constants
+            assert scale == 0 and not np.any(np.asarray(got[name]))
+            continue
+        assert scale > 0, name
+        np.testing.assert_allclose(got[name], want[name],
+                                   atol=3e-5 * scale, err_msg=name)
 
 
 @pytest.mark.parametrize("case", ["ties", "zeros", "random", "short"])
@@ -323,8 +357,8 @@ def test_local_metrics_carry_the_counters_and_only_for_this_model(setup):
         DPConfig(), "lm")
     assert fn.aux_names == model.aux_counters
     idx = jnp.arange(2).reshape(2, 1)
-    _, metrics = fn(params, tokens, targets, idx, jnp.ones((2, 1)),
-                    jax.random.PRNGKey(0))
+    _, metrics = jax.jit(fn)(params, tokens, targets, idx, jnp.ones((2, 1)),
+                             jax.random.PRNGKey(0))
     assert set(metrics.aux) == set(model.aux_counters)
     lm, li = _model_losses(model, params, tokens[:1], targets[:1])
     assert float(metrics.aux["indexer_loss"]) > 0

@@ -437,46 +437,53 @@ _ATTACK = {"attack.kind": "sign_flip", "attack.fraction": 0.25,
            "attack.scale": 10.0}
 
 
-def test_signflip_on_lowrank_factors_matrix(tmp_path):
+def _robust_run(out, name, **over):
+    from colearn_federated_learning_tpu.server.round_driver import Experiment
+
+    exp = Experiment(_robust_cfg(out, name, **over), echo=False)
+    state = exp.fit()
+    return exp, state, exp.evaluate(state["params"])
+
+
+@pytest.fixture(scope="module")
+def undefended_losses(tmp_path_factory):
+    """(benign, attacked) eval loss of the plain weighted mean: fitted
+    once for the two defenses' cases below."""
+    out = tmp_path_factory.mktemp("undefended")
+    return (_robust_run(out, "lr_benign")[2]["eval_loss"],
+            _robust_run(out, "lr_mean_atk", **_ATTACK)[2]["eval_loss"])
+
+
+_LOWRANK_DEFENSES = {
+    "krum": {"server.aggregator": "krum", "server.krum_byzantine": 2},
+    "reputation": {"run.obs.client_ledger.enabled": True,
+                   "server.reputation.enabled": True},
+}
+
+
+@pytest.mark.parametrize("defense", sorted(_LOWRANK_DEFENSES))
+def test_signflip_on_lowrank_factors_matrix(tmp_path, undefended_losses,
+                                            defense):
     """sign_flip on the adapter factors at f = 2/8: the plain weighted
     mean degrades past chance while krum — ranking FLATTENED FACTORS —
     and the reputation-weighted mean (ledger norm/cosine computed in
     adapter space) hold the benign band; the in-program flags identify
     the compromised set."""
-    from colearn_federated_learning_tpu.server.round_driver import Experiment
-
-    def run(name, **over):
-        exp = Experiment(
-            _robust_cfg(tmp_path, name, **over), echo=False
-        )
-        state = exp.fit()
-        return exp, state, exp.evaluate(state["params"])
-
-    _, _, benign = run("lr_benign")
-    assert benign["eval_loss"] < _BAND, benign
-
-    _, _, mean_atk = run("lr_mean_atk", **_ATTACK)
-    assert mean_atk["eval_loss"] > math.log(32), (
+    benign, mean_atk = undefended_losses
+    assert benign < _BAND, benign
+    assert mean_atk > math.log(32), (
         f"weighted_mean survived sign_flip on low-rank factors: "
         f"{mean_atk} (benign {benign})"
     )
-
-    _, _, krum_atk = run(
-        "lr_krum_atk", **_ATTACK,
-        **{"server.aggregator": "krum", "server.krum_byzantine": 2},
+    exp_r, state_r, defended = _robust_run(
+        tmp_path, f"lr_{defense}_atk", **_ATTACK,
+        **_LOWRANK_DEFENSES[defense],
     )
-    assert krum_atk["eval_loss"] < _BAND, (
-        f"krum lost the benign band in adapter space: {krum_atk}"
+    assert defended["eval_loss"] < _BAND, (
+        f"{defense} lost the benign band in adapter space: {defended}"
     )
-
-    exp_r, state_r, rep_atk = run(
-        "lr_rep_atk", **_ATTACK,
-        **{"run.obs.client_ledger.enabled": True,
-           "server.reputation.enabled": True},
-    )
-    assert rep_atk["eval_loss"] < _BAND, (
-        f"reputation-weighted mean lost the benign band: {rep_atk}"
-    )
+    if defense != "reputation":
+        return
     # the adapter-space forensics found the attackers
     led = np.asarray(jax.device_get(state_r["ledger"]))
     byz = np.asarray(exp_r.compromised)

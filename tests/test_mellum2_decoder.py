@@ -60,40 +60,65 @@ def setup():
     return model, params, tokens, targets
 
 
-def _model_loss(model, params, tokens, targets):
-    logits, _ = model.apply({"params": params}, tokens, train=True)
-    logp = jax.nn.log_softmax(logits, -1)
-    return -jnp.take_along_axis(logp, targets[..., None], -1)[..., 0].mean(-1)
-
-
-def test_logits_loss_and_counters_match_the_reference(setup):
+@pytest.fixture(scope="module")
+def model_side(setup):
+    """One compiled program for the tests that read the model's side:
+    (logits, aux, loss a sequence, gradient of the losses' sum, the
+    counters' names in the model's order)."""
     model, params, tokens, targets = setup
-    logits, aux = model.apply({"params": params}, tokens, train=True)
-    loss = _model_loss(model, params, tokens, targets)
+    order = []  # of the counters as the model returns them: jit sorts a dict
+
+    def loss(params):
+        logits, aux = model.apply({"params": params}, tokens, train=True)
+        order[:] = aux["counters"]
+        logp = jax.nn.log_softmax(logits, -1)
+        loss = -jnp.take_along_axis(
+            logp, targets[..., None], -1)[..., 0].mean(-1)
+        return loss.sum(), (logits, aux, loss)
+
+    (_, out), grads = jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
+    return (*out, grads, tuple(order))
+
+
+@pytest.fixture(scope="module")
+def reference_side(setup):
+    """The reference's (logits, chosen experts, loss, gradient), one
+    compiled program for both sequences."""
+    _, params, tokens, targets = setup
+
+    @jax.jit
+    def run(params, tokens, targets):
+        logits, chosen = ref.forward(params, tokens, SIZES, jnp.float32)
+        loss, grads = jax.value_and_grad(ref.loss)(
+            params, tokens, targets, SIZES, jnp.float32)
+        return logits, chosen, loss, grads
+
+    return [run(params, tokens[b], targets[b]) for b in range(2)]
+
+
+def test_logits_loss_and_counters_match_the_reference(model_side,
+                                                      reference_side, setup):
+    model = setup[0]
+    logits, aux, loss, _, counters = model_side
     assert "loss" not in aux
-    for b in range(2):
-        r_logits, chosen = ref.forward(params, tokens[b], SIZES, jnp.float32)
+    for b, (r_logits, chosen, r_loss, _) in enumerate(reference_side):
         np.testing.assert_allclose(logits[b], r_logits, atol=2e-5)
-        np.testing.assert_allclose(
-            loss[b], ref.loss(params, tokens[b], targets[b], SIZES,
-                              jnp.float32), rtol=1e-5)
+        np.testing.assert_allclose(loss[b], r_loss, rtol=1e-5)
         held = (chosen >= 2) & (chosen < 4)
         np.testing.assert_allclose(aux["counters"]["held_assignment_share"][b],
                                    held.mean(), rtol=1e-5)
-    assert tuple(aux["counters"]) == model.aux_counters
+    assert counters == model.aux_counters
     # T = 48, window 20 in tiles of 16: 210 + 28 x 20 = 770 kept pairs;
     # the three query tiles visit 1 + 2 + 3 key tiles of 256 pairs
     np.testing.assert_allclose(aux["counters"]["band_pair_share"],
                                770 / (6 * 256), rtol=1e-6)
 
 
-def test_gradients_of_every_leaf_match_the_reference(setup):
-    model, params, tokens, targets = setup
-    got = jax.grad(lambda p: _model_loss(model, p, tokens, targets).sum())(
-        params)
-    want = jax.grad(lambda p: sum(
-        ref.loss(p, tokens[b], targets[b], SIZES, jnp.float32)
-        for b in range(2)))(params)
+def test_gradients_of_every_leaf_match_the_reference(model_side,
+                                                     reference_side, setup):
+    params = setup[1]
+    got = model_side[3]
+    want = jax.tree.map(jnp.add, *(side[3] for side in reference_side))
     assert set(got) == set(want) == set(params)
     for name in params:
         scale = float(jnp.abs(want[name]).max())
@@ -107,11 +132,11 @@ def test_gradients_of_every_leaf_match_the_reference(setup):
 
 @pytest.mark.parametrize("wrong", ["triangle", "one_rope"])
 def test_the_comparison_sees_a_triangle_for_a_band_and_one_rope_for_two(
-        setup, wrong, monkeypatch):
+        setup, model_side, wrong, monkeypatch):
     """What the benchmark's two controls change in the reference moves
     the logits far beyond the agreement above."""
-    model, params, tokens, _ = setup
-    logits, _ = model.apply({"params": params}, tokens[:1], train=True)
+    _, params, tokens, _ = setup
+    logits = model_side[0]
     if wrong == "triangle":
         monkeypatch.setattr(ref, "window_of", lambda kind, sizes: None)
     else:
@@ -294,8 +319,8 @@ def test_local_metrics_carry_the_counters(setup):
         DPConfig(), "lm")
     assert fn.aux_names == model.aux_counters
     idx = jnp.arange(2).reshape(2, 1)
-    _, metrics = fn(params, tokens, targets, idx, jnp.ones((2, 1)),
-                    jax.random.PRNGKey(0))
+    _, metrics = jax.jit(fn)(params, tokens, targets, idx, jnp.ones((2, 1)),
+                             jax.random.PRNGKey(0))
     assert set(metrics.aux) == set(model.aux_counters)
     assert 0.0 < float(metrics.aux["expert_tile_fill"]) <= 1.0
     np.testing.assert_allclose(metrics.aux["band_pair_share"], 770 / 1536,

@@ -12,6 +12,7 @@ import re
 import socket
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -20,6 +21,7 @@ pytestmark = pytest.mark.multihost
 
 _WORKER = os.path.join(os.path.dirname(__file__), "multihost_worker.py")
 _FIT_WORKER = os.path.join(os.path.dirname(__file__), "multihost_fit_worker.py")
+_WAIT_S = 240
 
 
 def _free_port():
@@ -28,11 +30,14 @@ def _free_port():
         return s.getsockname()[1]
 
 
-def _run_workers(worker, extra_args=(), timeout=300, nprocs=2):
+def _run_workers(worker, extra_args=(), nprocs=2):
     """Launch the nprocs-process cluster, collect stdout, kill on ANY
     exit path (a hung worker must not leak processes holding the
     coordinator port for the rest of the CI run). Skips when the host
-    lacks cross-process CPU collectives."""
+    lacks cross-process CPU collectives. The wait is under the suite's
+    own limit for one test (conftest.TEST_LIMIT_S), so that a cluster
+    that hangs fails its test with the workers' errors, not the worker
+    of the suite that waits for it."""
     port = _free_port()
     env = {
         k: v for k, v in os.environ.items()
@@ -47,9 +52,11 @@ def _run_workers(worker, extra_args=(), timeout=300, nprocs=2):
         for pid in range(nprocs)
     ]
     outs = []
+    deadline = time.monotonic() + _WAIT_S
     try:
         for p in procs:
-            out, err = p.communicate(timeout=timeout)
+            out, err = p.communicate(
+                timeout=max(1.0, deadline - time.monotonic()))
             if p.returncode != 0 and (
                 "gloo" in err.lower() or "collectives" in err.lower()
             ):
@@ -189,7 +196,7 @@ def test_two_process_fit_eval_checkpoint_resume(tmp_path):
     import pathlib
 
     out_dir = str(tmp_path / "runs")
-    outs = _run_workers(_FIT_WORKER, extra_args=(out_dir,), timeout=600)
+    outs = _run_workers(_FIT_WORKER, extra_args=(out_dir,))
     parsed = _parse(
         outs,
         r"MULTIHOST_FIT_OK pid=(\d) round=(\d+) acc=([\d.]+) "
@@ -225,7 +232,6 @@ def test_two_process_gossip_fit(tmp_path):
     checkpoint/resume complete with identical consensus means."""
     outs = _run_workers(
         _FIT_WORKER, extra_args=(str(tmp_path / "runs"), "gossip"),
-        timeout=600,
     )
     parsed = _parse(
         outs,
@@ -242,7 +248,6 @@ def test_two_process_ef_fit(tmp_path):
     over the process boundary); identical final params on both hosts."""
     outs = _run_workers(
         _FIT_WORKER, extra_args=(str(tmp_path / "runs"), "ef"),
-        timeout=600,
     )
     parsed = _parse(
         outs,
@@ -262,7 +267,6 @@ def test_two_process_fused_fit(tmp_path):
     identical final params on both hosts."""
     outs = _run_workers(
         _FIT_WORKER, extra_args=(str(tmp_path / "runs"), "fused"),
-        timeout=600,
     )
     parsed = _parse(
         outs,
@@ -280,7 +284,7 @@ def test_four_process_fit(tmp_path):
     where the process boundaries fall."""
     out_dir = str(tmp_path / "runs")
     outs = _run_workers(
-        _FIT_WORKER, extra_args=(out_dir,), timeout=600, nprocs=4,
+        _FIT_WORKER, extra_args=(out_dir,), nprocs=4,
     )
     parsed = _parse(
         outs,
@@ -300,7 +304,6 @@ def test_two_process_scaffold_fit(tmp_path):
     a resume on both hosts identically."""
     outs = _run_workers(
         _FIT_WORKER, extra_args=(str(tmp_path / "runs"), "scaffold"),
-        timeout=600,
     )
     parsed = _parse(
         outs,
@@ -323,7 +326,6 @@ def test_two_process_fedbuff_fit(tmp_path):
     flagged as untested)."""
     outs = _run_workers(
         _FIT_WORKER, extra_args=(str(tmp_path / "runs"), "fedbuff"),
-        timeout=600,
     )
     parsed = _parse(
         outs,
@@ -340,7 +342,6 @@ def test_two_process_stream_placement_fit(tmp_path):
     host_local_array; both hosts converge to identical params."""
     outs = _run_workers(
         _FIT_WORKER, extra_args=(str(tmp_path / "runs"), "stream"),
-        timeout=600,
     )
     parsed = _parse(
         outs,
@@ -360,7 +361,6 @@ def test_two_process_poisson_fit(tmp_path):
     params."""
     outs = _run_workers(
         _FIT_WORKER, extra_args=(str(tmp_path / "runs"), "poisson"),
-        timeout=600,
     )
     parsed = _parse(
         outs,
@@ -381,7 +381,6 @@ def test_two_process_pairwise_secagg_fit(tmp_path):
     final params on both hosts."""
     outs = _run_workers(
         _FIT_WORKER, extra_args=(str(tmp_path / "runs"), "pairwise"),
-        timeout=600,
     )
     parsed = _parse(
         outs,

@@ -10,6 +10,8 @@ sequential oracle, the int32-wrap config gate, and e2e convergence
 under masking.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -157,6 +159,19 @@ def _setup(cohort=8, n=256, dropped=()):
             idx, mask, jnp.asarray(n_ex))
 
 
+@functools.cache
+def _sequential_round_fn(secagg):
+    """The oracle's round program, masked or plain, traced and compiled
+    once for the five tests that run it: who dropped is in ``n_ex``, an
+    argument."""
+    model, _, ccfg, _, server_update, *_ = _setup()
+    kw = dict(secagg=True, secagg_quant_step=1e-4) if secagg else {}
+    return make_sequential_round_fn(
+        model, ccfg, DPConfig(), "classify", server_update,
+        clip_delta_norm=10.0, **kw,
+    )
+
+
 @pytest.mark.parametrize("dropped", [(), (3, 5)])
 def test_secagg_matches_plain_aggregation(dropped):
     """Masked round == unmasked round up to the fixed-point quantization
@@ -164,14 +179,7 @@ def test_secagg_matches_plain_aggregation(dropped):
     clients recovered via server-side mask reconstruction."""
     (model, params, ccfg, server_init, server_update, tx, ty, idx, mask,
      n_ex) = _setup(dropped=dropped)
-    common = dict(clip_delta_norm=10.0)
-    plain = make_sequential_round_fn(
-        model, ccfg, DPConfig(), "classify", server_update, **common,
-    )
-    masked = make_sequential_round_fn(
-        model, ccfg, DPConfig(), "classify", server_update,
-        secagg=True, secagg_quant_step=1e-4, **common,
-    )
+    plain, masked = _sequential_round_fn(False), _sequential_round_fn(True)
     rng = jax.random.PRNGKey(7)
     p_plain, _, m_plain = plain(
         params, server_init(params), tx, ty, idx, mask, n_ex, rng
@@ -205,10 +213,7 @@ def test_secagg_sharded_matches_sequential_bitwise(lanes):
         cohort_size=8, donate=False, clip_delta_norm=10.0,
         secagg=True, secagg_quant_step=1e-4,
     )
-    seq = make_sequential_round_fn(
-        model, ccfg, DPConfig(), "classify", server_update,
-        clip_delta_norm=10.0, secagg=True, secagg_quant_step=1e-4,
-    )
+    seq = _sequential_round_fn(True)
     rng = jax.random.PRNGKey(11)
     p_sh, _, m_sh = sharded(
         params, server_init(params), tx, ty, idx, mask, n_ex, rng

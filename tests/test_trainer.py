@@ -180,13 +180,18 @@ def _block_inputs(shape, clients, steps, batch, seed=0):
 
 
 @pytest.mark.parametrize("case,steps", _BLOCK_RUNS)
-def test_block_trainer_matches_vmapped_local_train(case, steps):
+def test_block_trainer_matches_vmapped_local_train(case, steps, shallow_zoo):
     """The block trainer's stacked [C, ...] output equals
     ``jax.vmap(local_train)`` (the spatial layout) leaf for leaf, with
-    or without a shared-weight phase."""
+    or without a shared-weight phase. The two deep families at two
+    stages (conftest's ``shallow_zoo``): whether the layouts agree is a
+    question of a model's kinds of kernel, which both keep, as they keep
+    their side of ``shared_weight_phase``'s threshold."""
     build, shape, ckw, (atol, rtol) = _BLOCK_CASES[case]
     model = build()
-    params = init_params(model, shape, seed=0)
+    # one program, not one for every leaf's shape
+    params = jax.jit(lambda: init_params(model, shape, seed=0))()
+    assert shared_weight_phase(params) == (case != "resnet18")
     cfg = ClientConfig(**{"local_epochs": 1, "batch_size": 4, "lr": 0.02,
                           **ckw})
     args = (params,) + _block_inputs(shape, 4, steps, 4)
@@ -258,7 +263,7 @@ def test_which_models_run_a_shared_weight_phase(name, steps):
     shape = (32, 32, 3) if name == "resnet18" else (28, 28, 1)
     model = build_model(name, num_classes=10,
                         **(dict(width=8) if name == "resnet18" else {}))
-    params = init_params(model, shape, seed=0)
+    params = jax.eval_shape(lambda: init_params(model, shape, seed=0))
     shared = shared_weight_phase(params)
     assert shared == (name == "lenet5")
     cfg = ClientConfig(local_epochs=1, batch_size=2, lr=0.1, momentum=0.9)
@@ -269,15 +274,14 @@ def test_which_models_run_a_shared_weight_phase(name, steps):
             jnp.zeros((clients, steps, 2), jnp.int32),
             jnp.ones((clients, steps, 2)),
             jax.random.split(jax.random.PRNGKey(0), clients))
-    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
-    loops = [e.params["length"] for e in _scans(jaxpr)]
+    closed, out_shapes = jax.make_jaxpr(fn, return_shape=True)(*args)
+    loops = [e.params["length"] for e in _scans(closed.jaxpr)]
     # the loop over steps and, inside it, the loop over the block's
     # groups of clients
     assert loops == ([steps - shared, clients // block_group(params, clients)]
                      if steps > shared else [])
-    out_shapes = jax.eval_shape(fn, *args)[0]
     jax.tree.map(lambda p, o: np.testing.assert_equal(
-        o.shape, (clients,) + p.shape), params, out_shapes)
+        o.shape, (clients,) + p.shape), params, out_shapes[0])
 
 
 # -- the block's step loop: a group's step under a real conditional --
