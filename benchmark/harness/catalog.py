@@ -9,7 +9,10 @@ configuration or a metric by adding files and appending one entry to
                                    dtype policy, FLOPs family (+ arguments)
     workloads/<cell>.json          named config + dotted-key overrides,
                                    warm-up, loss band, reference
-                                   implementation and tolerances
+                                   implementation and tolerances; with
+                                   ``traffic_seed`` the cell fixes who
+                                   trains when and ``--seed`` draws the
+                                   weights only
     layer_metrics/<metric>.json    layer, unit, moves, reader (+ arguments)
     readers/<reader>.py            one ``read(ctx, **args)`` per file
     flops/<family>.py              ``forward_macs(**args)`` and
@@ -78,6 +81,14 @@ def load_workload(name: str, bench_dir: str = BENCH_DIR) -> Dict[str, Any]:
     for key in ("impl", "rounds", "loss_rel_tols", "state_rel_l2_tol"):
         if key not in cell["reference"]:
             raise CatalogError(f"workload {name!r}: reference lacks {key!r}")
+    if "traffic_seed" in cell:
+        seed = cell["traffic_seed"]
+        if isinstance(seed, bool) or not isinstance(seed, int) or seed < 0:
+            raise CatalogError(f"workload {name!r}: traffic_seed must be a "
+                               f"whole number, not {seed!r}")
+        if not cell.get("traffic_seed_reason"):
+            raise CatalogError(f"workload {name!r} fixes its traffic_seed "
+                               f"and gives no traffic_seed_reason")
     return cell
 
 
@@ -134,10 +145,16 @@ def experiment_overrides(cell: Dict[str, Any], config: Dict[str, Any],
                          seed: int) -> Dict[str, Any]:
     """Dotted-key overrides for ``resolve_config(cell["named_config"])``:
     the configuration's own, then the cell's, then what the command line
-    fixes (the seed, and one lane per chip the cell asks for)."""
+    fixes (the seed, and one lane per chip the cell asks for).
+
+    ``run.seed`` draws the partition, the cohort schedule and the example
+    order. A cell whose file carries ``traffic_seed`` fixes them there and
+    leaves ``--seed`` the initial weights and the run's key (the harness
+    hands ``--seed`` to ``Experiment.init_state`` itself, ``window.Run``);
+    a cell without the key gives ``--seed`` both."""
     out = dict(config["overrides"])
     out.update(cell["overrides"])
-    out["run.seed"] = int(seed)
+    out["run.seed"] = int(cell.get("traffic_seed", seed))
     out["run.num_lanes"] = int(cell["chips"])
     return out
 

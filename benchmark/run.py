@@ -19,6 +19,7 @@ _T0 = time.perf_counter()
 
 import argparse  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -123,6 +124,41 @@ def check_reference(cell, config, run, bench_dir=BENCH_DIR) -> dict:
     )
     out["seconds"] = time.perf_counter() - t0
     return out
+
+
+def compared(compiles_in_window, result, loss, ref, cell) -> dict:
+    """Every number that decides ``correct``, beside its limit:
+    ``[number, upper limit]``, or ``[number, lower, upper]`` for a band.
+    A number that is missing or not finite, or a limit that is missing,
+    is ``null`` (the line stays JSON) and fails."""
+    def number(v):
+        return v if v is not None and math.isfinite(v) else None
+
+    tols = cell["reference"]
+    loss_tols = tols.get("loss_rel_tols") or []
+    out = {
+        "compiles_in_window": [compiles_in_window, 0],
+        "rounds_failed": [result["failed"], 0],
+        "rounds_completed": [result["completed"], 1, result["attempted"]],
+        f"loss_round_{loss['round']}": [number(loss["train_loss"]),
+                                        *(loss["band"] or [None, None])],
+    }
+    for k, err in enumerate(ref["loss_rel_errs"]):
+        out[f"ref_loss_rel_err_round_{k + 1}"] = [
+            number(err), loss_tols[k] if k < len(loss_tols) else None]
+    out["ref_delta_rel_l2_err"] = [number(ref["delta_rel_l2_err"]),
+                                   tols.get("state_rel_l2_tol")]
+    return out
+
+
+def inside(check) -> bool:
+    """One entry of ``compared``: nothing of it is ``null`` and the
+    number is inside its limit(s)."""
+    if any(v is None for v in check):
+        return False
+    if len(check) == 3:
+        return check[1] <= check[0] <= check[2]
+    return check[0] <= check[1]
 
 
 def round_programs(exp) -> list:
@@ -279,11 +315,9 @@ def main(argv=None) -> int:
     say("loss_check", loss)
     ref = check_reference(cell, config, run)
     say("reference", ref)
-    correct = bool(
-        compiles_in_window == 0 and result["failed"] == 0
-        and result["error"] is None and result["completed"] > 0
-        and loss["ok"] and ref["agrees"]
-    )
+    checks = compared(compiles_in_window, result, loss, ref, cell)
+    correct = bool(result["error"] is None
+                   and all(inside(c) for c in checks.values()))
 
     first = used[0]
     device = {"platform": first.platform, "kind": first.device_kind,
@@ -355,6 +389,12 @@ def main(argv=None) -> int:
         }
     if trace_dir:  # a check's tree stays small enough to copy
         shutil.rmtree(trace_dir, ignore_errors=True)
+    # what decided `correct`, each number beside its limit: last on the
+    # error stream, and last in the result's line
+    out["checks"] = checks
+    for name, check in checks.items():
+        print(f"[check] {name}: {json.dumps(check)}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(out), flush=True)
     return 0
 

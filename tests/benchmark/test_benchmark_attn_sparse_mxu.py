@@ -77,8 +77,12 @@ def test_reads_nothing_from_a_trace_without_the_scope(tmp_path):
 
 
 def test_data_file_and_benchmark_entry_agree():
-    entry = catalog.load_benchmark()["per_layer"][-1]
-    assert entry["name"] == SPEC["name"] == "attn_sparse_mxu_pct"
+    # looked up by name: it was the last entry only until PR 28 appended
+    names = [m["name"] for m in catalog.load_benchmark()["per_layer"]]
+    assert SPEC["name"] == "attn_sparse_mxu_pct"
+    assert names.count(SPEC["name"]) == 1
+    assert names.index(SPEC["name"]) > names.index("attn_sparse_ms_round")
+    entry = catalog.load_benchmark()["per_layer"][names.index(SPEC["name"])]
     for key in ("unit", "better", "source", "layer", "moves", "workloads"):
         assert entry[key] == SPEC[key], key
     assert (entry["unit"], entry["better"], entry["source"]) == (

@@ -107,7 +107,10 @@ def test_workload_overrides_validate(entry):
     config = catalog.load_config(cell["config"])
     cfg = resolve_config(cell["named_config"],
                          catalog.experiment_overrides(cell, config, seed=3))
-    assert cfg.run.seed == 3 and cfg.run.num_lanes == entry["chips"]
+    # who trains when: the cell's file where it fixes it, else --seed
+    # (the rule itself: test_benchmark_traffic_seed.py)
+    assert cfg.run.seed == cell.get("traffic_seed", 3)
+    assert cfg.run.num_lanes == entry["chips"]
     assert cfg.server.eval_every == 0 and cfg.server.checkpoint_every == 0
     assert cfg.run.out_dir == ""
     assert cfg.model.name == config["model"]["name"]

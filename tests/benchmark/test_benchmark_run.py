@@ -26,7 +26,8 @@ def test_dry_run_prints_the_contracts_keys_and_counts_only(preset, tmp_path):
     assert proc.returncode == 0, proc.stderr[-2000:]
     lines = proc.stdout.strip().splitlines()
     last = json.loads(lines[-1])
-    assert set(last) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(last) == ["correct", "attempted", "failed", "metrics",
+                          "device", "checks"]  # the numbers compared, last
     assert last["correct"] is True and last["failed"] == 0
     assert last["attempted"] >= last["metrics"]["rounds_completed"]["value"] > 0
     assert last["metrics"]["compiles_in_window"]["value"] == 0

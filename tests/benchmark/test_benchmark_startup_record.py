@@ -178,10 +178,14 @@ def test_data_file_and_benchmark_entry_agree(name):
 
 
 def test_the_new_entries_are_appended_after_the_accepted_ones():
+    """By name, not by place: later PRs append after them (PR 35 did)."""
     names = [m["name"] for m in BENCH["per_layer"]]
-    assert names[-len(TABLE):] == TABLE
-    # the two metrics they split stay as they were
-    assert {"compile_s", "host_prep_s"} <= set(names[:-len(TABLE)])
+    assert all(names.count(n) == 1 for n in TABLE)
+    # in the table's order among themselves
+    assert sorted(TABLE, key=names.index) == TABLE
+    # after the two metrics they split, which stay as they were
+    first = names.index(TABLE[0])
+    assert {"compile_s", "host_prep_s"} <= set(names[:first])
 
 
 def test_rehearsal_reports_every_span_and_every_metric(tmp_path):
