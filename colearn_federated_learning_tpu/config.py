@@ -611,18 +611,24 @@ class DPConfig:
     l2_clip: float = 1.0
     noise_multiplier: float = 1.0
     delta: float = 1e-5
-    # per-example grads are memory-heavy; vmap over microbatches of this size
+    # examples per step of the trainer's scan over the batch: a
+    # microbatch's per-example quantities are held at once
     microbatch_size: int = 16
-    # Clipping strategy (privacy/dp.py — both EXACT, same mechanism):
-    #   microbatch — lax.scan over microbatches of vmap(grad); one
-    #                backward total but poorly batched (the vmapped
-    #                backward can't use full-batch matmuls).
-    #   two_pass   — ghost-norm-style: pass 1 computes per-example grad
-    #                NORMS only (grads discarded), pass 2 is ONE fully
-    #                batched weighted backward whose gradient IS the
-    #                clipped sum (grad of the scale-masked mean × Σscale).
-    #                Two backwards, but both MXU-batched. Measured on
-    #                imagenet_silo_dp: BASELINE.md r5.
+    # Clipping strategy (privacy/dp.py — both exact, same mechanism):
+    #   microbatch — lax.scan over microbatches of one vmapped forward
+    #                and backward. The kernel of an nn.Dense, or of an
+    #                nn.Conv that is a patch embedding, never has its
+    #                per-example gradient formed: its norm comes from
+    #                two Gram products of the product's input rows and
+    #                output cotangents, its clipped sum from one
+    #                weighted product. Every other leaf's per-example
+    #                gradient is materialised [microbatch, ...].
+    #   two_pass   — pass 1 computes per-example grad NORMS only (grads
+    #                discarded), pass 2 is ONE fully batched weighted
+    #                backward whose gradient IS the clipped sum (grad of
+    #                the scale-masked mean × Σscale). Two backwards; the
+    #                norm and the sum come from different backward
+    #                passes (dp_grads_two_pass on what that costs).
     clipping: str = "microbatch"  # microbatch | two_pass
 
 

@@ -1,11 +1,36 @@
 """DP-SGD on TPU (SURVEY.md §2 C12; BASELINE config #5).
 
 Per-example gradient clipping + Gaussian noise, Abadi et al. 2016. The
-TPU-shaped part (SURVEY.md §7 "hard parts"): per-example grads via
-``jax.vmap(jax.grad)`` are memory-heavy, so the batch is processed as a
-``lax.scan`` over microbatches of vmapped per-example grads — peak
-memory is ``microbatch_size`` gradient pytrees, compute stays batched
-enough to keep the MXU busy.
+batch is processed as a ``lax.scan`` over microbatches of
+``dp.microbatch_size`` examples; what a microbatch computes depends on
+the leaf (``dp.clipping="microbatch"``, the default):
+
+- A **product leaf** never has its per-example gradient formed. It is
+  the ``kernel`` of an ``nn.Dense``, or of an ``nn.Conv`` whose strides
+  equal its window under ``VALID`` padding (a patch embedding: a Dense
+  over unfolded patches), that is a trained leaf as it stands, is used
+  by that one call alone and has ``T² ≤ d_in·d_out`` for its ``T`` input
+  rows per example (:func:`_product_leaves`; the layer and the static
+  shapes decide, never the model's name). Such a kernel acts as
+  ``y_t = a_t W``, so example ``i``'s gradient is ``a_iᵀ δ_i`` with
+  ``δ`` the cotangent at the product's output: its squared norm is
+  ``Σ_{t,t'} (a_i a_iᵀ)_{tt'} (δ_i δ_iᵀ)_{tt'}``, two ``T x T`` Gram
+  matrices, and the microbatch's clipped sum is one weighted product
+  ``Σ_i s_i a_iᵀ δ_i``, fused so that no single ``a_iᵀ δ_i`` is ever
+  written (:func:`_gram_sqnorms`, :func:`_weighted_product`). One
+  forward and one backward through the activations, with these kernels
+  held un-batched, hand out ``a`` and ``δ`` (a zero perturbation at the
+  product's output, differentiated).
+- **Every other leaf** (biases, LayerNorm, ``cls``, positions, windowed
+  convolutions, embeddings, LoRA factors, anything of a loss that is no
+  flax model) has its per-example gradient materialised ``[mb, ...]``
+  by the same vmapped backward, its squared norm summed from it and its
+  clipped sum taken as ``einsum("b,b...->...")``. A model without a
+  product leaf compiles to that program alone.
+
+Norms, clip scales, the accumulated clipped sum and the noise are
+float32 whatever dtype training uses; see :func:`make_dp_grad_fn` on
+what that means for the sensitivity.
 
 Padding interaction: padded examples (mask 0) get their clip scale
 forced to 0, so they contribute nothing; the mean divides by the real
@@ -14,11 +39,217 @@ example count and noise is scaled to clip/denominator as usual.
 
 from __future__ import annotations
 
+import math
+
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from colearn_federated_learning_tpu.config import DPConfig
 from colearn_federated_learning_tpu.utils import trees
+
+_F32 = jnp.float32
+
+
+def _product_rows(module, x):
+    """The input rows ``a`` ``[..., d_in]`` of the product a flax module
+    computes, ``y = a @ kernel.reshape(d_in, d_out)`` (+ bias) with
+    ``y``'s leading axes those of ``a``; ``None`` for a module that is
+    no such product. ``nn.Dense``: its input. ``nn.Conv`` over ``[B, H,
+    W, C]`` whose strides equal its window, ``VALID``, undilated,
+    ungrouped, the window dividing ``H`` and ``W``: the unfolded
+    patches ``[B, H/kh · W/kw, kh·kw·C]``."""
+    if type(module) is nn.Dense:
+        if module.dot_general is None and module.dot_general_cls is None:
+            return x
+        return None
+    if type(module) is not nn.Conv or x.ndim != 4:
+        return None
+    window = tuple(module.kernel_size)
+
+    def each(v):  # flax takes None, one int or one per axis
+        return (v or 1,) * 2 if v is None or isinstance(v, int) else tuple(v)
+
+    if (len(window) != 2 or module.padding != "VALID"
+            or each(module.strides) != window
+            or each(module.input_dilation) != (1, 1)
+            or each(module.kernel_dilation) != (1, 1)
+            or module.feature_group_count != 1 or module.mask is not None
+            or module.conv_general_dilated is not None
+            or module.conv_general_dilated_cls is not None
+            or x.shape[1] % window[0] or x.shape[2] % window[1]):
+        return None
+    b, h, w, c = x.shape
+    (kh, kw), nh, nw = window, h // window[0], w // window[1]
+    return (x.reshape(b, nh, kh, nw, kw, c).transpose(0, 1, 3, 2, 4, 5)
+            .reshape(b, nh * nw, kh * kw * c))
+
+
+def _intercept_products(index, on_product):
+    """flax interceptor: ``on_product(leaf index, rows, out) -> out`` at
+    every product module (:func:`_product_rows`) whose ``kernel`` IS —
+    the same object, not an equal or a derived one — a leaf of
+    ``index`` (``{id(leaf): leaf index}``). A kernel the model merged,
+    normalised or lifted (LoRA's ``W + AB``, ``nn.scan``) is another
+    object and matches nothing."""
+
+    def interceptor(next_fun, args, kwargs, context):
+        out = next_fun(*args, **kwargs)
+        module = context.module
+        if (context.method_name != "__call__" or not args
+                or not module.has_variable("params", "kernel")):
+            return out
+        i = index.get(id(module.get_variable("params", "kernel")))
+        if i is None:
+            return out
+        return on_product(i, _product_rows(module, args[0]), out)
+
+    return nn.intercept_methods(interceptor)
+
+
+def _product_leaves(loss_fn, params, x1, y1, **loss_kwargs):
+    """Which leaves of ``params`` take the Gram / weighted-product form
+    in :func:`make_dp_grad_fn`'s default path: ``{leaf index (flatten
+    order): ShapeDtypeStruct of the product's output for one example}``.
+    Arrays or shapes in, nothing computed: one abstract trace of
+    ``loss_fn`` on the single example ``(x1, y1)``.
+
+    A leaf qualifies when a product module's ``kernel`` is that very
+    leaf (:func:`_intercept_products`), the module is called once in
+    the forward pass, the traced loss uses the leaf in that one place
+    (a shared or tied weight's gradient is a sum of products, whose norm
+    no single Gram pair gives), and ``T² ≤ d_in·d_out`` (else forming
+    ``a_iᵀ δ_i`` is the cheaper way, which is what the materialised
+    form does). A loss whose products the interception does not see
+    qualifies nothing: the whole tree is then materialised, never a
+    part of a norm left out."""
+    calls = {}
+
+    def probe(params, x1, y1, loss_kwargs):
+        index = {id(p): i for i, p in enumerate(jax.tree.leaves(params))}
+
+        def record(i, rows, out):
+            calls.setdefault(i, []).append(
+                rows is not None and (math.prod(rows.shape[:-1]),
+                                      rows.shape[-1], out.shape, out.dtype))
+            return out
+
+        with _intercept_products(index, record):
+            return loss_fn(params, x1[None], y1[None], jnp.ones((1,), _F32),
+                           **loss_kwargs)
+
+    jaxpr = jax.make_jaxpr(probe)(params, x1, y1, loss_kwargs).jaxpr
+    found = {}
+    for i, seen in sorted(calls.items()):
+        if len(seen) != 1 or not seen[0]:
+            continue
+        (t, d_in, shape, dtype), leaf = seen[0], jaxpr.invars[i]
+        uses = sum(v is leaf for eqn in jaxpr.eqns for v in eqn.invars)
+        if (uses == 1 and t * t <= d_in * shape[-1]
+                and leaf.aval.size == d_in * shape[-1]):
+            found[i] = jax.ShapeDtypeStruct(shape, dtype)
+    return found
+
+
+def ghost_param_counts(loss_fn, cfg: DPConfig, params, x1, y1,
+                       **loss_kwargs):
+    """``dp_params``: the trained parameters; ``dp_ghost_params``: those
+    of them in product leaves, whose per-example gradients
+    :func:`make_dp_grad_fn` never forms under ``cfg`` — by the trainer's
+    own predicate (:func:`_product_leaves`), from shapes alone; none
+    under ``clipping="two_pass"``, which has no such form."""
+    sizes = [math.prod(p.shape) for p in jax.tree.leaves(params)]
+    ghost = (_product_leaves(loss_fn, params, x1, y1, **loss_kwargs)
+             if cfg.clipping == "microbatch" else {})
+    return {"dp_params": sum(sizes),
+            "dp_ghost_params": sum(sizes[i] for i in ghost)}
+
+
+def _gram_sqnorms(a, d):
+    """``‖a_iᵀ δ_i‖²`` per example from ``a`` ``[mb, T, d_in]`` and
+    ``δ`` ``[mb, T, d_out]``: ``Σ_{t,t'} (a_i a_iᵀ)(δ_i δ_iᵀ)``, both
+    Gram matrices accumulated, multiplied and summed in float32 from the
+    operands as the backward pass left them (bfloat16 products are exact
+    in float32; ``HIGHEST`` keeps float32 operands whole on a TPU)."""
+    ga = jnp.einsum("bti,bsi->bts", a, a, precision="highest",
+                    preferred_element_type=_F32)
+    gd = jnp.einsum("bto,bso->bts", d, d, precision="highest",
+                    preferred_element_type=_F32)
+    return jnp.sum(ga * gd, axis=(1, 2))
+
+
+_LANES = 128
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _weighted_product(a, d, scale):
+    """``Σ_i s_i a_iᵀ δ_i`` ``[d_in, d_out]`` in float32, scaled AFTER
+    the products: each example's ``a_iᵀ δ_i`` accumulates in float32,
+    is multiplied by its float32 ``s_i`` there and summed over the
+    microbatch. The one product ``aᵀ (s ⊙ δ)`` over ``(mb, T)`` would
+    be faster and is wrong on a TPU: at default precision the MXU takes
+    a float32 operand rounded to bfloat16 (``s ⊙ δ`` then errs by
+    1.7e-3 relative on the v5e, PERF.md PR 44), and at ``HIGHEST`` it
+    takes three passes.
+
+    Two forms of that one sum. Everywhere: the three steps as written,
+    which the TPU compiler fuses into one pass that never writes the
+    ``[mb, d_in, d_out]`` products (compiled-form guard in
+    ``tests/test_sparse_attention_kernel.py``). On a TPU, for bfloat16
+    operands whose widths are whole lanes and whose rows per example
+    fill an MXU pass (measured at ViT's 197; fewer rows are not):
+    :func:`_post_scaled_kernel`, the same pass with the output tile
+    resident (PERF.md PR 44 on what each measured)."""
+    if (_on_tpu() and a.dtype == d.dtype == jnp.bfloat16
+            and a.shape[1] >= _LANES
+            and a.shape[-1] % _LANES == 0 and d.shape[-1] % _LANES == 0):
+        return _post_scaled_product(a, d, scale)
+    g = jnp.einsum("bti,bto->bio", a, d, precision="highest",
+                   preferred_element_type=_F32)
+    return jnp.sum(g * scale[:, None, None], axis=0)
+
+
+def _post_scaled_kernel(s_ref, a_ref, d_ref, out_ref):
+    """One example's ``[T, tm]ᵀ [T, tn]`` product, scaled in float32,
+    onto the output tile that stays in VMEM while the grid's innermost
+    axis walks the microbatch."""
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    g = jax.lax.dot_general(a_ref[0], d_ref[0], (((0,), (0,)), ((), ())),
+                            preferred_element_type=_F32)
+    out_ref[...] += s_ref[0] * g
+
+
+def _post_scaled_product(a, d, scale, interpret=False):
+    """:func:`_weighted_product` as a Pallas TPU kernel: grid ``(d_in /
+    tm, d_out / tn, mb)``, tiles the widest multiples of 128 up to 768
+    that divide the widths (ViT-B/16: 768 x 768, 2.4 MB of float32)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (mb, t, d_in), d_out = a.shape, d.shape[-1]
+    tm, tn = (max(w for w in range(_LANES, 768 + 1, _LANES) if n % w == 0)
+              for n in (d_in, d_out))
+    lanes = frozenset().union(*(jax.typeof(v).vma for v in (a, d, scale)))
+    return pl.pallas_call(
+        _post_scaled_kernel, name="dp_weighted_product",
+        grid=(d_in // tm, d_out // tn, mb),
+        in_specs=[pl.BlockSpec((1, 1, 1), lambda i, j, b: (b, 0, 0)),
+                  pl.BlockSpec((1, t, tm), lambda i, j, b: (b, 0, i)),
+                  pl.BlockSpec((1, t, tn), lambda i, j, b: (b, 0, j))],
+        out_specs=pl.BlockSpec((tm, tn), lambda i, j, b: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((d_in, d_out), _F32, vma=lanes),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(scale.reshape(mb, 1, 1), a, d)
 
 
 def make_dp_grad_fn(loss_fn, cfg: DPConfig, batch_axis: str | None = None):
@@ -34,14 +265,23 @@ def make_dp_grad_fn(loss_fn, cfg: DPConfig, batch_axis: str | None = None):
     shards), so every shard adds the identical noise draw to the
     identical post-psum sum — one noise application, exactly as in the
     unsharded mechanism.
-    """
 
-    def single_example_grad(params, x1, y1):
-        one = jnp.ones((1,), jnp.float32)
-        loss, grads = jax.value_and_grad(loss_fn)(
-            params, x1[None], y1[None], one
-        )
-        return loss, grads
+    Sensitivity of the default path. Example ``i`` is released as
+    ``s_i·g_i`` with ``s_i = min(1, C/‖g_i‖)·mask_i``. For a product
+    leaf (module docstring) the norm and the released sum come from
+    different products of the same ``a`` and ``δ``: Gram products for
+    the norm, one weighted product for the sum. Both take the operands
+    as the backward pass left them (bfloat16 under bfloat16 training),
+    whose products are exact in float32, and accumulate in float32, and
+    ``s_i`` meets its example's product in float32, after the MXU; so
+    ``‖s_i·g_i‖ ≤ l2_clip`` holds up to float32 reassociation (order
+    1e-6 relative), test-pinned. That is tighter than clipping a
+    per-example gradient after it was rounded to bfloat16, which is
+    what a materialised leaf gets under bfloat16 training. Were a Gram
+    matrix or ``s ⊙ δ`` ever rounded to bfloat16 before its product,
+    the bound would be lost (:func:`_weighted_product` on how a TPU
+    does that unasked).
+    """
 
     def dp_grads(params, x, y, m, rng):
         if batch_axis is not None:
@@ -65,42 +305,76 @@ def make_dp_grad_fn(loss_fn, cfg: DPConfig, batch_axis: str | None = None):
         ym = y.reshape((n_micro, mb) + y.shape[1:])
         mm = m.reshape(n_micro, mb)
 
+        leaves, treedef = jax.tree.flatten(params)
+        ghost = _product_leaves(loss_fn, params, x[0], y[0])
+        rest = [i for i in range(len(leaves)) if i not in ghost]
+        # Accumulators and perturbations derive their sharding type from
+        # the data (0·Σm), so the scan carry type-checks identically
+        # inside a shard_map lane (device-varying) and in plain jit, and
+        # a perturbation's cotangent stays the shard's own.
+        zero_scalar = 0.0 * m.sum()
+        taps = {i: jnp.zeros(t.shape, t.dtype) + zero_scalar.astype(t.dtype)
+                for i, t in ghost.items()}
+        kernel_index = {id(leaves[i]): i for i in ghost}
+
+        def example_loss(small, taps, x1, y1):
+            """One example's loss as a function of the materialised
+            leaves and of a zero perturbation at every product leaf's
+            output; the product kernels are closed over, un-batched
+            under the vmap. Also returns the products' input rows."""
+            full = list(leaves)
+            for i, p in zip(rest, small):
+                full[i] = p
+            rows = {}
+
+            def tap(i, a, out):
+                rows[i] = a.astype(out.dtype)  # as the product consumed it
+                return out + taps[i]
+
+            with _intercept_products(kernel_index, tap):
+                loss = loss_fn(jax.tree.unflatten(treedef, full), x1[None],
+                               y1[None], jnp.ones((1,), _F32))
+            return loss, rows
+
         def micro_step(acc, inp):
             xs, ys, ms = inp
             with jax.named_scope("dp_example_grad"):
-                losses, grads = jax.vmap(
-                    single_example_grad, in_axes=(None, 0, 0)
-                )(params, xs, ys)  # grads: pytree with leading [mb]
+                (losses, rows), (grads, deltas) = jax.vmap(
+                    jax.value_and_grad(example_loss, argnums=(0, 1),
+                                       has_aux=True),
+                    in_axes=(None, None, 0, 0),
+                )([leaves[i] for i in rest], taps, xs, ys)
             # The privacy-critical math runs in f32 no matter what dtype
-            # training uses (run.local_param_dtype may be bf16): the clip
-            # norm is an f32 sum of squares of the exact released values,
-            # so ‖scale·g‖₂ ≤ l2_clip holds in f32 and the accountant's
-            # sensitivity assumption stays valid.
+            # training uses (run.local_param_dtype may be bf16): norms,
+            # scales and the accumulated sum (make_dp_grad_fn's
+            # docstring on the sensitivity).
             with jax.named_scope("dp_clip"):
-                grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
-                norms = jnp.sqrt(
-                    sum(
-                        jnp.sum(jnp.square(g.reshape(mb, -1)), axis=1)
-                        for g in jax.tree.leaves(grads)
-                    )
-                )
+                grads = dict(zip(rest, (g.astype(_F32) for g in grads)))
+                pairs = {
+                    i: (rows[i].reshape(mb, -1, rows[i].shape[-1]),
+                        deltas[i].reshape(mb, -1, deltas[i].shape[-1]))
+                    for i in ghost
+                }
+                norms = jnp.sqrt(sum(
+                    _gram_sqnorms(*pairs[i]) if i in ghost
+                    else jnp.sum(jnp.square(grads[i].reshape(mb, -1)), axis=1)
+                    for i in range(len(leaves))
+                ))
                 scale = jnp.minimum(
                     1.0, cfg.l2_clip / jnp.maximum(norms, 1e-12)
                 ) * ms
-                clipped_sum = jax.tree.map(
-                    lambda g: jnp.einsum("b,b...->...", scale, g), grads
-                )
+                clipped_sum = jax.tree.unflatten(treedef, [
+                    _weighted_product(*pairs[i], scale).reshape(
+                        leaves[i].shape) if i in ghost
+                    else jnp.einsum("b,b...->...", scale, grads[i])
+                    for i in range(len(leaves))
+                ])
                 acc_g, acc_loss = acc
                 acc_g = trees.tree_add(acc_g, clipped_sum)
             return (acc_g, acc_loss + (losses * ms).sum()), None
 
-        # Initial accumulators derive their sharding type from the data
-        # (0·Σm), so the scan carry type-checks identically inside a
-        # shard_map lane (device-varying) and in plain jit. Accumulation
-        # is f32 even under bf16 training (see micro_step).
-        zero_scalar = 0.0 * m.sum()
         zero = jax.tree.map(
-            lambda p: jnp.zeros(p.shape, jnp.float32) + zero_scalar.astype(jnp.float32),
+            lambda p: jnp.zeros(p.shape, _F32) + zero_scalar.astype(_F32),
             params,
         )
         (g_sum, loss_sum), _ = jax.lax.scan(
@@ -137,10 +411,8 @@ def make_dp_grad_fn(loss_fn, cfg: DPConfig, batch_axis: str | None = None):
             return loss_sum / denom, noisy
 
     def dp_grads_two_pass(params, x, y, m, rng):
-        """Ghost-norm-style exact clipping in its JAX-native form
-        (VERDICT r4 missing-#5): the expensive part of `dp_grads` is
-        that vmap(grad)'s per-example backward cannot use full-batch
-        matmuls. Instead:
+        """Exact clipping from two backward passes (VERDICT r4
+        missing-#5), for any loss, whatever its layers:
 
         - **Pass 1 (norms)**: per-example gradient NORMS only, via the
           same microbatched vmap(grad) but with the grads reduced to
@@ -152,10 +424,9 @@ def make_dp_grad_fn(loss_fn, cfg: DPConfig, batch_axis: str | None = None):
           Σ sᵢ·lᵢ / Σ sᵢ, and multiplying its gradient by the
           θ-independent Σ sᵢ yields exactly Σ sᵢ·gᵢ.
 
-        Two backwards total, but both MXU-batched — a win whenever the
-        vmapped backward is > 2× the batched one (measured on the ViT
-        silo config: BASELINE.md r5). Same clip scales, same noise
-        stream as the microbatch path; parity is test-pinned.
+        Two backwards total, but both MXU-batched (no cell measures
+        this path: ROADMAP D5). Same clip scales, same noise stream as
+        the microbatch path; parity is test-pinned.
 
         Sensitivity caveat (stated, not hidden): the clip NORMS come
         from pass 1's per-example backwards while the released sum
@@ -163,9 +434,9 @@ def make_dp_grad_fn(loss_fn, cfg: DPConfig, batch_axis: str | None = None):
         contributions can differ by floating-point reassociation —
         ‖sᵢ·gᵢ‖ ≤ l2_clip then holds only up to that rounding
         (f32: ~1e-6 relative; bf16 compute: up to ~1e-2). The
-        microbatch path clips the exact released values and is the
-        right choice when strict sensitivity matters — which is also
-        the measured-faster default.
+        microbatch path keeps the bound to float32 reassociation in
+        either dtype (``make_dp_grad_fn``) and is the right choice when
+        strict sensitivity matters.
         """
         if batch_axis is not None:
             vparams = jax.tree.map(

@@ -27,6 +27,7 @@ from colearn_federated_learning_tpu.client.trainer import (
     block_group,
     make_eval_fn,
     make_local_train_fn,
+    make_loss_fn,
     shared_weight_phase,
     windowed_conv_share,
 )
@@ -429,6 +430,7 @@ class Experiment:
         # the megabatch block trainer's (width, group, shared first
         # step), for _count_block_steps; None: another layout
         self._block = None
+        self._dp_param_counts_cache = None
         self._comm_stats: Dict[int, Dict[str, int]] = {}
         self._fail_stats: Dict[int, Dict[str, int]] = {}
         # unfused engine twin for non-chunk-aligned resumes under
@@ -1088,6 +1090,29 @@ class Experiment:
                     mask, shape.steps, shape.batch_size, shape.local_epochs,
                     *self._block,
                 ))
+
+    def _count_dp_params(self) -> None:
+        """Under example-level DP-SGD: the trained parameters and those
+        of them whose per-example gradients the trainer never forms
+        (``privacy/dp.ghost_param_counts``: the trainer's own predicate
+        on the model's shapes), once a round on the span that built the
+        inputs."""
+        if not (self.cfg.dp.enabled and self.tracer.enabled):
+            return
+        if self._dp_param_counts_cache is None:
+            from colearn_federated_learning_tpu.privacy.dp import (
+                ghost_param_counts,
+            )
+
+            frozen = ({} if self.frozen_base is None
+                      else {"frozen": self.frozen_base})
+            self._dp_param_counts_cache = ghost_param_counts(
+                make_loss_fn(self.model, self.task), self.cfg.dp,
+                self._param_shapes(),
+                *(jax.ShapeDtypeStruct(a.shape[1:], a.dtype)
+                  for a in (self.fed.train_x, self.fed.train_y)), **frozen)
+        self.tracer.count("round.host_inputs.slab_build",
+                          **self._dp_param_counts_cache)
 
     def _param_stats(self) -> tuple:
         """(n_coords, bytes) of one params tree at run.param_dtype, via
@@ -1945,6 +1970,7 @@ class Experiment:
                 )
                 n_ex = np.concatenate([n_ex, np.zeros(pad, n_ex.dtype)])
         self._count_block_steps(mask, shape)
+        self._count_dp_params()
         slab = (
             self._stream_slab(idx) if self._stream and build_slab else None
         )

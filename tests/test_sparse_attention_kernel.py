@@ -561,3 +561,84 @@ def test_compiled_for_a_v5e_banded_attentions_scores_stay_in_the_kernels(
     _experts_meet_their_weights_in_the_kernels(text, 8, 2304, 896)
     assert not re.search(r"f32\[(1,)?(32|4,8),(512|2048),(512|1024|2048)\]",
                          text)
+
+
+@pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "fused"])
+def test_compiled_for_a_v5e_the_dp_step_writes_no_per_example_kernel_gradient(
+        chip_mesh, monkeypatch, kernel):
+    """What the chip's compiler keeps of DP-SGD's clipped sum: one step
+    of a ViT at the published widths (one block, 197 tokens, bfloat16, a
+    microbatch of 16) inside a manual ``clients`` region, compiled for a
+    described v5e. ``privacy/dp.py`` wants each example's ``a_iᵀ δ_i``
+    in float32, scaled by ``s_i`` and summed over the microbatch (the
+    one exact form that is a single MXU pass), in two forms. As a Pallas
+    kernel (what a TPU runs where the widths are whole lanes): Mosaic
+    accepts the tiling, 197 rows and a transposed left operand among it,
+    and the call's type against operands that vary over the mesh; the
+    patch embedding and the block's four products have their call, the
+    head (1,000 classes) has not. As three XLA steps (every other
+    backend, and the head): the compiler fuses them. Either way no
+    instruction of the program writes ``[16, *kernel.shape]`` in any
+    layout or dtype, which is what the per-example weight gradients this
+    path exists to avoid would be. The patch embedding's ``[16, 768,
+    768]`` is left out: a Gram matrix of 768 rows would have its shape
+    (none has: T is 197)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from colearn_federated_learning_tpu.client.trainer import make_loss_fn
+    from colearn_federated_learning_tpu.config import DPConfig
+    from colearn_federated_learning_tpu.privacy import dp as dp_lib
+
+    monkeypatch.setattr(dp_lib, "_on_tpu", lambda: kernel)
+    mb = 16
+    model = build_model("vit_b16", 1000, layers=1,
+                        compute_dtype=jnp.bfloat16)
+    everywhere = NamedSharding(chip_mesh, P())
+    rows = NamedSharding(chip_mesh, P("clients"))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                       sharding=everywhere),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 224, 224, 3)))["params"]))
+    dp_grads = dp_lib.make_dp_grad_fn(
+        make_loss_fn(model, "classify"),
+        DPConfig(enabled=True, l2_clip=1.0, noise_multiplier=0.8,
+                 microbatch_size=mb))
+
+    def lane(params, x, y, m, key):
+        params = jax.lax.pcast(params, ("clients",), to="varying")
+        return jax.lax.psum(dp_grads(params, x[0], y[0], m[0], key[0]),
+                            "clients")
+
+    step = jax.jit(jax.shard_map(
+        lane, mesh=chip_mesh, in_specs=(P(),) + (P("clients"),) * 4,
+        out_specs=P()))
+    text = step.lower(
+        params,
+        jax.ShapeDtypeStruct((1, 2 * mb, 224, 224, 3), jnp.uint8, sharding=rows),
+        jax.ShapeDtypeStruct((1, 2 * mb), jnp.int32, sharding=rows),
+        jax.ShapeDtypeStruct((1, 2 * mb), jnp.float32, sharding=rows),
+        jax.ShapeDtypeStruct((1, 2), jnp.uint32, sharding=rows),
+    ).compile().as_text()
+    # what an instruction outside a fused computation produces is a
+    # buffer; what one inside produces stays in the fusion
+    fused = set(re.findall(r"fusion\(.*calls=%?([\w.\-]+)", text))
+    written, inside = set(), False
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$", line)
+        if head:
+            inside = head.group(1) in fused
+        made = re.search(r"= [a-z]+[0-9]+\[([0-9,]+)\]\S* (?!parameter\()",
+                         line)
+        if made and not inside:
+            written.add(made.group(1))
+    assert "16,197,768" in written  # a block's input rows, as a check
+    for d_in, d_out in ((768, 2304), (768, 3072), (3072, 768), (768, 1000)):
+        for form in (f"{mb},{d_in},{d_out}", f"{mb},{d_out},{d_in}"):
+            assert form not in written, form
+    assert f"{mb},16,16,3,768" not in written
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == (
+        5 if kernel else 0)
+    if not kernel:  # the products, fused with their scale and their sum
+        assert re.search(r"= f32\[768,3072\]\S* fusion\(.*bti,bto->bio",
+                         text)
