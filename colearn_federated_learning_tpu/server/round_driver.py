@@ -15,7 +15,8 @@ import time
 from typing import Any, Dict, Optional
 
 # first of the imports that cost anything, on purpose: it reads the
-# clock before orbax, flax and the models' Pallas kernels load
+# clock before flax, optax and the models' Pallas kernels load (orbax
+# is not among them: the first checkpoint store built imports it)
 from colearn_federated_learning_tpu.server import import_clock  # isort: skip
 
 import jax
@@ -3772,10 +3773,21 @@ class Experiment:
         if ex is not None:
             ex.shutdown(wait=True, cancel_futures=True)
 
-    def _ckpt_store(self) -> Optional[CheckpointStore]:
-        if not self.cfg.run.out_dir:
+    def _ckpt_store(self, required: bool = False) -> Optional[CheckpointStore]:
+        """The store under ``_run_dir()``; without ``run.out_dir`` None,
+        unless ``required`` (evaluate and export read a checkpoint back
+        from the cwd's run directory then). The one place the driver
+        builds a store, and so the place a process imports orbax
+        (utils/checkpoint.py): the span ``setup.checkpoint_store`` holds
+        those seconds in the start-up record of exactly the runs that
+        pay them. ``_fit`` builds its store before the round loop, so a
+        checkpointing run pays them at the start of ``fit()`` (or at a
+        resume, retry, replay, evaluate or export) and never inside a
+        round."""
+        if not (self.cfg.run.out_dir or required):
             return None
-        return CheckpointStore(os.path.join(self._run_dir(), "ckpt"))
+        with self.tracer.span("setup.checkpoint_store"):
+            return CheckpointStore(os.path.join(self._run_dir(), "ckpt"))
 
     # EF residuals and scaffold/feddyn control variates share the
     # checkpoint key "c_clients" (same [N_pad, ...] shapes); a resume
@@ -5437,7 +5449,7 @@ class Experiment:
         side."""
         from colearn_federated_learning_tpu.utils.checkpoint import export_params
 
-        store = CheckpointStore(os.path.join(self._run_dir(), "ckpt"))
+        store = self._ckpt_store(required=True)
         state, step = store.restore(step=step, template=self.init_state())
         store.close()
         params = state["params"]
@@ -5458,7 +5470,7 @@ class Experiment:
                             federated: bool = False,
                             federated_clients: int = 64,
                             **personalize_kwargs) -> Dict[str, float]:
-        store = CheckpointStore(os.path.join(self._run_dir(), "ckpt"))
+        store = self._ckpt_store(required=True)
         template = self.init_state()
         state, step = store.restore(step=step, template=template)
         store.close()

@@ -8,6 +8,11 @@ stacked f32 tree of every client's control variate). The cohort sampler
 is stateless (pure function of seed+round), so resume at round r
 replays the exact schedule — determinism test §4.5 covers this across a
 save/restore boundary.
+
+Importing this module does not import orbax: the first
+:class:`CheckpointStore` built does (12.9 s on the chip's host, PERF.md
+PR 34), so a process that never checkpoints never loads it.
+``export_params`` / ``load_params`` are flax msgpack and need none of it.
 """
 
 from __future__ import annotations
@@ -17,13 +22,16 @@ from typing import Any, Dict, Optional
 
 import jax
 import numpy as np
-import orbax.checkpoint as ocp
 
 
 class CheckpointStore:
     def __init__(self, directory: str):
         self.directory = os.path.abspath(os.path.expanduser(directory))
         os.makedirs(self.directory, exist_ok=True)
+        # here and not at module level (module docstring)
+        import orbax.checkpoint as ocp
+
+        self._ocp = ocp
         self._mngr = ocp.CheckpointManager(self.directory)
 
     def save(self, step: int, state: Dict[str, Any], force: bool = False,
@@ -48,7 +56,7 @@ class CheckpointStore:
                 if isinstance(a, np.ndarray) else a,
                 state,
             )
-        self._mngr.save(step, args=ocp.args.StandardSave(state), force=force)
+        self._mngr.save(step, args=self._ocp.args.StandardSave(state), force=force)
         if block:
             self._mngr.wait_until_finished()
 
@@ -77,7 +85,8 @@ class CheckpointStore:
                 template["rng_key"] = np.asarray(
                     jax.random.key_data(template["rng_key"])
                 )
-            restored = self._mngr.restore(step, args=ocp.args.StandardRestore(template))
+            restored = self._mngr.restore(
+                step, args=self._ocp.args.StandardRestore(template))
         else:
             restored = self._mngr.restore(step)
         restored = dict(restored)
