@@ -22,7 +22,8 @@ On success the LAST line of stdout is one JSON object::
     {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
 
 One process per chip: a chip belongs to one process at a time, so
-never run this next to another chip process (bench.py, colearn fit).
+never run this next to another chip process (benchmark/run.py,
+colearn fit).
 """
 
 from __future__ import annotations
@@ -35,6 +36,8 @@ import sys
 import time
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
+# the repo's one table of device peaks, keyed by ``device_kind``
+PEAKS_FILE = os.path.join(ROOT, "benchmark", "harness", "peaks.json")
 
 SMOKE_CONFIG = "cifar10_fedavg_100"
 SMOKE_ROUNDS = 8
@@ -65,9 +68,10 @@ def say(msg: str) -> None:
 
 
 def smoke_overrides(out_dir: str) -> dict:
-    """The driver's bench shape of the smoke config (bench.py
-    ``_SHAPES`` + its CIFAR-cardinality synthetic corpus), cut to
-    ``SMOKE_ROUNDS`` rounds with one eval and one checkpoint at the end."""
+    """The headline cell's shape of the smoke config (``r18_c16_k8``:
+    four fused rounds, the fused apply, a CIFAR-cardinality synthetic
+    corpus), cut to ``SMOKE_ROUNDS`` rounds with one eval and one
+    checkpoint at the end."""
     return {
         "run.fuse_rounds": 4,
         "server.fused_apply": True,
@@ -81,6 +85,17 @@ def smoke_overrides(out_dir: str) -> dict:
         "run.metrics_flush_every": 4,
         "run.out_dir": out_dir,
     }
+
+
+def require_known_device_kind(kind: str) -> None:
+    """A device the benchmark has no peaks for is refused here too."""
+    with open(PEAKS_FILE) as f:
+        known = [k for k in json.load(f) if not k.startswith("_")]
+    if kind not in known:
+        raise RuntimeError(
+            f"device_kind {kind!r} is not a key of {PEAKS_FILE} "
+            f"(it has {known}); add its published peaks there first"
+        )
 
 
 def require_live_buffers(devices) -> None:
@@ -169,14 +184,12 @@ def run_fit(config: str, overrides: dict, t_start: float) -> dict:
         )
 
     summary = next(r for r in records if r.get("event") == "run_summary")
-    cost = next(r for r in records if r.get("event") == "phase_cost_model")
     return {
         "first_round_sec": round(rounds[0]["time"] - t_start, 1),
         "fit_wall_sec": summary["wall_time_sec"],
         "compile_sec": round(summary["compile_ms"] / 1e3, 1),
         "compiles": summary["compiles"],
         "host_pipeline": "native" if exp._native else "numpy",
-        "device_kind_recorded": cost["device_kind"],
         "n_chips": exp.n_chips,
         "final_train_loss": losses[-1],
         "final_eval_loss": final_eval["eval_loss"],
@@ -644,8 +657,6 @@ def main() -> int:
         return 1
     from importlib import metadata
 
-    from colearn_federated_learning_tpu.obs.roofline import PEAK_DEVICE_KIND
-
     dev = jax.devices()[0]
     say(f"jax {jax.__version__} jaxlib {jaxlib.__version__} "
         f"libtpu {metadata.version('libtpu')}")
@@ -653,19 +664,12 @@ def main() -> int:
         f"compile_cache={cache_dir} "
         f"(entries at start: "
         f"{len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0})")
-    if dev.device_kind != PEAK_DEVICE_KIND:
-        raise RuntimeError(
-            f"device_kind {dev.device_kind!r} is not the "
-            f"{PEAK_DEVICE_KIND!r} obs/roofline.py's peaks describe"
-        )
+    require_known_device_kind(dev.device_kind)
 
     # (c) the main path
     out_dir = os.path.join(ROOT, "runs", "chip_smoke")
     shutil.rmtree(out_dir, ignore_errors=True)
     facts = run_fit(SMOKE_CONFIG, smoke_overrides(out_dir), t_start)
-    if facts["device_kind_recorded"] != dev.device_kind:
-        raise RuntimeError(f"phase_cost_model recorded device_kind "
-                           f"{facts['device_kind_recorded']!r}")
     # (f) the start-up facts
     say("fit: " + json.dumps(facts))
 

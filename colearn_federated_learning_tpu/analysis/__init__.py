@@ -16,7 +16,7 @@ disciplines into checked artifacts:
   site.
 - :mod:`analysis.schema` — the JSONL record-type registry, statically
   cross-checked against the MetricsLogger emit sites and the
-  summarize/watch/mfu/population/clients consumers (plus a runtime
+  summarize/watch/population/clients consumers (plus a runtime
   validator the tier-1 tests run over a live fit's JSONL).
 
 :mod:`analysis.check` orchestrates all three; ``colearn check`` is the

@@ -10,8 +10,7 @@ from __future__ import annotations
 import os
 from typing import Any, Dict, List, Optional
 
-# bump when an analyzer's rules or the matrix schema change — BENCH_r*
-# extras carry this (+ the clean bit) as provenance
+# bump when an analyzer's rules or the matrix schema change
 ANALYZER_VERSION = 2
 
 
@@ -113,16 +112,3 @@ def format_report(report: Dict[str, Any]) -> str:
             )
     return "\n".join(lines)
 
-
-def bench_provenance() -> Dict[str, Any]:
-    """The `static_check` extra BENCH_r* entries carry: analyzer
-    version + whether the repo passed clean at bench time (best-effort;
-    a crash in the analyzers must never take the bench down)."""
-    try:
-        report = run_check()
-        return {"analyzer_version": report["analyzer_version"],
-                "clean": bool(report["clean"]),
-                "violations": len(report["violations"])}
-    except Exception as e:  # pragma: no cover - defensive
-        return {"analyzer_version": ANALYZER_VERSION, "clean": False,
-                "error": repr(e)[:200]}

@@ -1,7 +1,7 @@
 """JSONL record-schema registry + emit/consume cross-check
 (`colearn check` analyzer c).
 
-Five pure-host CLIs (summarize / watch / mfu / population / clients)
+Four pure-host CLIs (summarize / watch / population / clients)
 consume the metrics JSONL that the driver and obs modules emit — three
 hand-maintained shapes with no machine check that they agree. This
 module is the single registry of every record type plus two static
@@ -166,21 +166,6 @@ REGISTRY: Dict[str, RecordSpec] = {
                   "fused_apply", "double_buffer", "control_plane"),
         doc="dtype/fusion/control-plane provenance at fit start",
     ),
-    "phase_cost_model": RecordSpec(
-        required=("step_flops", "flop_source", "n_coords", "n_coords_full",
-                  "param_bytes", "compute_bytes", "mfu_basis", "peak_flops",
-                  "peak_hbm_bytes_per_sec", "device_kind", "n_chips",
-                  "process_index",
-                  "cohort_layout", "clients_per_lane", "gemm_rows",
-                  "lora_all_steps", "mxu_tile_pad_fraction",
-                  "windowed_conv_share",
-                  "shared_weight_phase"),
-        doc="static half of the roofline cost model (obs/roofline.py)",
-    ),
-    "phase_cost": RecordSpec(
-        required=("round", "process_index", "phases"),
-        doc="per-round analytic FLOP/HBM phase costs",
-    ),
     "poisson_sampling": RecordSpec(
         required=("q", "cap", "dp_delta_abort"),
         doc="poisson-sampling provenance (cap + abort probability)",
@@ -271,12 +256,11 @@ EVENT_DICT_MODULES = (
     "colearn_federated_learning_tpu/obs/population.py",
     "colearn_federated_learning_tpu/obs/executables.py",
 )
-# the pure-host report modules `colearn summarize/watch/mfu/population/
-# clients` run (bench-report reads BENCH_r*.json, a different artifact)
+# the pure-host report modules `colearn summarize/watch/population/
+# clients` run
 CONSUMER_MODULES = (
     "colearn_federated_learning_tpu/obs/summary.py",
     "colearn_federated_learning_tpu/obs/population.py",
-    "colearn_federated_learning_tpu/obs/roofline.py",
     "colearn_federated_learning_tpu/obs/ledger.py",
     "colearn_federated_learning_tpu/obs/digest.py",
 )

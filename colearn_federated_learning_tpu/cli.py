@@ -5,7 +5,11 @@ Entry points with capability parity to the reference's
 
     colearn fit --config cifar10_fedavg_100 --set server.num_rounds=50
     colearn evaluate --config cifar10_fedavg_100
+    colearn export --config <c> --output m.msgpack  # a checkpoint's
+                               # global model as one flax msgpack file
     colearn configs            # list the named BASELINE configs
+    colearn store build|info   # on-disk mmap client store
+                               # (data/store.py): write or describe one
     colearn summarize <run>    # per-phase timing table from a run's JSONL
     colearn watch <run>        # live tail of a run (mid-fit or done):
                                # rounds/sec, loss, health, coverage,
@@ -14,10 +18,6 @@ Entry points with capability parity to the reference's
                                # (population_health JSONL records)
     colearn clients <run>      # per-client forensic ledger report
                                # (anomalies + attack precision/recall)
-    colearn mfu <run>          # MFU waterfall + roofline attribution
-                               # (obs/roofline.py phase-cost records)
-    colearn bench-report       # BENCH_r*.json trajectory + per-phase
-                               # budget gates (exit 1 on regression)
     colearn check              # static invariant analyzer: capability
                                # matrix golden pin, seed-purity
                                # lint, JSONL schema cross-check
@@ -26,9 +26,12 @@ Entry points with capability parity to the reference's
                                # digest chains and localize the first
                                # divergent round + component
                                # (exit 1 on divergence)
-    colearn replay <run> --round r  # re-execute one logged digest
+    colearn replay --config <c> --round r  # re-execute one logged digest
                                # round from the nearest checkpoint and
                                # verify the recomputed digest
+    colearn preflight --config <c>  # compile every round program
+                               # abstractly and report predicted peak
+                               # HBM against the budget
 
 ``--config`` accepts a registry name or a YAML path; ``--set a.b=v``
 overrides any field. ``fit --resume`` continues from the latest
@@ -40,7 +43,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 
 
@@ -273,23 +275,6 @@ def build_parser():
                     help="emit the report as one JSON object instead of "
                          "the table")
 
-    mf = sub.add_parser(
-        "mfu",
-        help="MFU waterfall + roofline attribution from a run's "
-             "phase_cost/spans JSONL records: headline MFU decomposed "
-             "into padding / host-exposed / non-matmul / residual, "
-             "each phase classified compute- vs memory-bound (no "
-             "backend needed)",
-    )
-    mf.add_argument("run", metavar="RUN",
-                    help="run name (looked up under --out-dir), a run "
-                         "directory, or a .metrics.jsonl path")
-    mf.add_argument("--out-dir", default="runs",
-                    help="where <RUN>.metrics.jsonl lives (default: runs)")
-    mf.add_argument("--json", action="store_true",
-                    help="emit the report as one JSON object instead of "
-                         "the table")
-
     ck = sub.add_parser(
         "check",
         help="static invariant analyzer (analysis/): capability-matrix "
@@ -308,23 +293,6 @@ def build_parser():
     ck.add_argument("--json", action="store_true",
                     help="emit the full report as one JSON object "
                          "instead of the table")
-
-    br = sub.add_parser(
-        "bench-report",
-        help="bench regression observatory: the BENCH_r*.json "
-             "trajectory with per-phase ms deltas vs best-so-far and "
-             "budget gates from BENCH_BUDGETS.json — exits 1 naming "
-             "the offending phase/metric on a gate failure (no "
-             "backend needed)",
-    )
-    br.add_argument("--dir", default=".", dest="bench_dir",
-                    help="directory holding BENCH_r*.json (default: .)")
-    br.add_argument("--baseline", default=None,
-                    help="budget file (default: <dir>/BENCH_BUDGETS.json "
-                         "when present; no gates otherwise)")
-    br.add_argument("--json", action="store_true",
-                    help="emit the report as one JSON object instead of "
-                         "the table")
 
     df = sub.add_parser(
         "diff",
@@ -484,36 +452,6 @@ def main(argv=None):
             print(_check.format_report(report))
         return 0 if report["clean"] else 1
 
-    if args.cmd == "bench-report":
-        # pure-host trajectory analysis over the checked-in BENCH
-        # history — the CI regression gate (obs/roofline.py)
-        from colearn_federated_learning_tpu.obs import roofline
-
-        entries = roofline.load_bench_history(args.bench_dir)
-        if not entries:
-            print(f"error: no BENCH_r*.json under {args.bench_dir!r}",
-                  file=sys.stderr)
-            return 2
-        budgets = None
-        bpath = args.baseline or os.path.join(
-            args.bench_dir, "BENCH_BUDGETS.json"
-        )
-        if args.baseline or os.path.isfile(bpath):
-            try:
-                with open(bpath) as f:
-                    budgets = json.load(f)
-            except (OSError, json.JSONDecodeError) as e:
-                print(f"error: cannot read budgets {bpath!r}: {e}",
-                      file=sys.stderr)
-                return 2
-        report = roofline.bench_report(entries, budgets)
-        if args.json:
-            print(json.dumps(report))
-        else:
-            print(roofline.format_bench_report(report, args.bench_dir))
-        # a tripped gate is the whole point: non-zero, naming the phase
-        return 1 if report["violations"] else 0
-
     if args.cmd == "diff":
         # pure-host digest-chain bisection — two logs in, the first
         # divergent round + component out (obs/digest.py)
@@ -546,7 +484,7 @@ def main(argv=None):
             return 2
         return 0 if report["status"] == "match" else 1
 
-    if args.cmd in ("summarize", "clients", "mfu", "watch", "population"):
+    if args.cmd in ("summarize", "clients", "watch", "population"):
         # pure-host JSONL aggregation — runs before (and without) any
         # jax backend initialization
         from colearn_federated_learning_tpu.obs import summary as obs_summary
@@ -591,22 +529,6 @@ def main(argv=None):
                 print(json.dumps(dict(report, path=path)))
             else:
                 print(obs_population.format_population_report(report, path))
-            return 0
-        if args.cmd == "mfu":
-            from colearn_federated_learning_tpu.obs import roofline
-
-            try:
-                report = roofline.mfu_report(records)
-            except ValueError as e:
-                # pre-observatory logs (or phase_cost off) get a clean
-                # one-line error, not a traceback
-                print(f"error: {e.args[0] if e.args else e}",
-                      file=sys.stderr)
-                return 2
-            if args.json:
-                print(json.dumps(dict(report, path=path)))
-            else:
-                print(roofline.format_mfu_report(report, path))
             return 0
         if args.cmd == "clients":
             from colearn_federated_learning_tpu.obs import ledger as obs_ledger

@@ -33,8 +33,8 @@ class LoRAConfig:
     checkpoints, wire counters) IS the adapter set, so every subsystem
     operates in adapter space by construction and the per-client
     upload drops ~d/(2r) per target (the realized ratio is logged as
-    ``wire_reduction_vs_full`` in the round counters, ``run_summary``,
-    and bench extras). Eval and export run against the merged model.
+    ``wire_reduction_vs_full`` in the round counters and
+    ``run_summary``). Eval and export run against the merged model.
     The frozen base params are a pure function of ``run.seed`` (the
     init rng) — re-derived on resume, never checkpointed or shipped
     (the one-time base broadcast is out of the per-round wire model,
@@ -797,8 +797,8 @@ class DigestConfig:
     runs are bitwise-identical to digest-off runs on the same seed
     (test-pinned). ``colearn diff`` bisects two streams to the first
     divergent round + component; ``colearn replay`` re-executes one
-    logged round and verifies its digest. Off by default (benches
-    never pay the O(P) host fetch)."""
+    logged round and verifies its digest. Off by default (the
+    benchmark's cells never pay the O(P) host fetch)."""
 
     enabled: bool = False
     # rounds between digest boundaries; the O(params) host-side fetch
@@ -838,20 +838,6 @@ class ObsConfig:
     # upload/download, pre/post compression — obs/counters.py) merged
     # into each round's JSONL record.
     counters: bool = True
-    # Per-round analytic phase-cost records (obs/roofline.py): FLOPs +
-    # HBM bytes per round-program stage (local train / attack /
-    # aggregation / server apply / ledger stats), logged as
-    # `phase_cost` JSONL records next to the spans and joined by
-    # `colearn mfu <run>` into the MFU waterfall. Pure-function model
-    # (engine-invariant); requires counters. Centralized rounds only —
-    # gossip/fedbuff rounds carry no phase_cost record.
-    phase_cost: bool = True
-    # Where the local-train step FLOP count comes from:
-    #   analytic — dense 6·P·B approximation, zero extra compiles
-    #   xla      — XLA's cost model of one scan-free train step (what
-    #              bench.py's model_tflops_per_round uses; one extra
-    #              compile at fit start, exact for conv models)
-    phase_cost_flops: str = "analytic"  # analytic | xla
     # Poll jax device memory stats at flush boundaries and log a
     # `device_memory` record (in-use / peak / limit bytes). Off by
     # default: the gauges are per-process globals, noisy under tests.
@@ -2318,11 +2304,6 @@ class ExperimentConfig:
                 f"run.obs.trace_max_events must be >= 0, "
                 f"got {obs.trace_max_events}"
             )
-        if obs.phase_cost_flops not in ("analytic", "xla"):
-            raise ValueError(
-                f"unknown run.obs.phase_cost_flops "
-                f"{obs.phase_cost_flops!r}; expected 'analytic' or 'xla'"
-            )
         if obs.hbm_budget_mb < 0:
             raise ValueError(
                 f"run.obs.hbm_budget_mb must be >= 0, "
@@ -2780,7 +2761,7 @@ def _cifar10_fedavg_100() -> ExperimentConfig:
         # block trains as one fused step — the shared-weight first step
         # feeds the MXU [16·64 = 1024]-row GEMMs where the spatial scan
         # capped every matmul at one client's 64 — the structural answer
-        # to the 41.4% MFU plateau (BENCH_r01–r05; ROADMAP item 1)
+        # to the 41.4% MFU plateau of the spatial layout
         run=RunConfig(compute_dtype="bfloat16", local_param_dtype="bfloat16",
                       cohort_layout="megabatch"),
     )
@@ -2970,8 +2951,7 @@ def _bert_lora_federated() -> ExperimentConfig:
     learning rate (adapter-space steps move a ~3k-coordinate subspace,
     so the stable lr sits well above the full-model config's 0.5).
     Scale this up with `colearn store build` + ``data.store.dir`` +
-    ``data.placement=stream`` — the bench ships ``bert_lora_1m``, the
-    10⁶-client store-backed twin."""
+    ``data.placement=stream``."""
     return ExperimentConfig(
         name="bert_lora_federated",
         algorithm="fedavg",

@@ -16,12 +16,6 @@ Three pillars, each its own module, all host-side and engine-agnostic:
   flags scattered into a device-resident per-client store, periodic
   ``client_ledger`` JSONL records, and the ``colearn clients``
   attack-attribution report.
-- :mod:`roofline` — the performance observatory: an analytic per-phase
-  FLOP/HBM-byte cost model (``phase_cost`` JSONL records, engine-
-  parity-pinned like the wire counters), the ``colearn mfu`` waterfall
-  that decomposes headline MFU into padding/host/non-matmul/residual
-  components, and the ``colearn bench-report`` trajectory gates over
-  ``BENCH_r*.json`` + the checked-in ``BENCH_BUDGETS.json``.
 - :mod:`population` — the federation health observatory
   (``run.obs.population``): population/data-plane telemetry for the
   million-client structures — HLL-style unique-client coverage,
@@ -60,18 +54,5 @@ from colearn_federated_learning_tpu.obs.population import (  # noqa: F401
     HLLCounter,
     PopulationTracker,
     SpaceSavingSketch,
-)
-from colearn_federated_learning_tpu.obs.roofline import (  # noqa: F401
-    MXU_TILE_ROWS,
-    PEAK_BF16_FLOPS,
-    PEAK_F32_FLOPS,
-    PEAK_HBM_BYTES_PER_SEC,
-    analytic_lora_step_flops,
-    analytic_step_flops,
-    layout_gemm_rows,
-    mfu_basis,
-    mxu_tile_pad_fraction,
-    round_phase_costs,
-    waterfall,
 )
 from colearn_federated_learning_tpu.obs.spans import Tracer  # noqa: F401

@@ -26,13 +26,10 @@ boundary replica rows with two ring neighbours (or everything under
 ``full``), so the modeled traffic is symmetric — reported as equal
 upload/download halves of the sweep volume.
 
-The performance observatory (:mod:`~colearn_federated_learning_tpu.
-obs.roofline`) extends the same analytic-purity discipline from wire
-bytes to FLOPs/HBM bytes per round-program phase; its ``local_train``
-byte floor consumes :func:`round_host_input_bytes`, and the waterfall's
-padding component consumes :func:`round_shape_stats`'s
-``padded_step_fraction`` gauge. :func:`block_step_counts` says how many
-of those padded steps the megabatch block trainer does not execute.
+The round records carry :func:`round_host_input_bytes` and
+:func:`round_shape_stats`'s ``padded_step_fraction`` gauge;
+:func:`block_step_counts` says how many of those padded steps the
+megabatch block trainer does not execute.
 """
 
 from __future__ import annotations

@@ -14,7 +14,8 @@ produce, so execution is bitwise-identical — and harvests, per
 compiled program:
 
 * ``cost_analysis()`` FLOPs / bytes-accessed (XLA's cost model of the
-  optimized HLO — the measured half of the ``colearn mfu`` drift gate),
+  optimized HLO: the FLOP count inside the program, beside the
+  benchmark's shape count in ``benchmark/flops/``),
 * ``memory_analysis()`` argument / output / temp / generated-code
   bytes (the predicted HBM working set; donation-aliased bytes are
   counted once),
@@ -627,23 +628,6 @@ class ExecutableRegistry:
             "programs": len(names),
             "peak_bytes": int(self.peak_bytes),
         }
-
-    def measured_round_flops(self) -> Optional[Tuple[str, float]]:
-        """(program, per-round flops) of the dominant compiled round
-        program by XLA cost_analysis — the measured side of the
-        measured-vs-analytic drift join. None when no round program
-        compiled or the backend reports no cost analysis."""
-        best: Optional[Tuple[str, float]] = None
-        for name, entry in self._programs.items():
-            if not name.startswith("round."):
-                continue
-            fl = (entry.get("stats") or {}).get("flops")
-            if fl is None:
-                continue
-            per_round = float(fl) / max(1, int(entry.get("rounds_per_call") or 1))
-            if best is None or per_round > best[1]:
-                best = (name, per_round)
-        return best
 
     def preflight_report(self) -> Dict[str, Any]:
         programs = []

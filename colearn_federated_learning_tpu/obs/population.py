@@ -31,7 +31,7 @@ window covering four planes:
   participation sketch (:class:`SpaceSavingSketch`), never a dense
   ``[num_clients]`` histogram.
 
-Purity discipline (the wire-counter/roofline contract): every tracked
+Purity discipline (the wire counters' contract): every tracked
 quantity is a pure function of host-side facts that are identical
 across the sharded, sequential, and fused engines (the cohort schedule,
 the pager's slot bookkeeping, the slab index tensors), so the
@@ -599,9 +599,8 @@ class PopulationTracker:
             out["store_gather_bytes"] = int(total_bytes)
             total_ms = sum(s["ms"] for s in stats)
             if total_ms:
-                # wall-clock store throughput — the budget-gated
-                # data-plane headline (BENCH_BUDGETS
-                # store_gather_mbps_min via `colearn bench-report`)
+                # wall-clock store throughput — the data-plane
+                # headline
                 out["store_gather_mbps"] = round(
                     total_bytes / (1 << 20) / (total_ms / 1e3), 1
                 )
@@ -1073,7 +1072,7 @@ def population_report(records: List[Dict[str, Any]]) -> Dict[str, Any]:
             report["store"]["gather_workers"] = gather_workers
         if store["gather_ms"]:
             # wall-clock gather throughput — the data-plane headline
-            # (`store_gather_mbps`, budget-gated by `colearn bench-report`)
+            # (`store_gather_mbps`)
             report["store"]["store_gather_mbps"] = round(
                 store["bytes_gathered"] / (1 << 20)
                 / (store["gather_ms"] / 1e3), 1

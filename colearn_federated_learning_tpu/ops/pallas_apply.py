@@ -48,45 +48,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from colearn_federated_learning_tpu.obs.roofline import (
-    SERVER_APPLY_PASSES_FUSED,
-    round_phase_costs,
-)
-
-
-def reduce_apply_cost(k: int, n_coords: int) -> dict:
-    """Analytic cost annotation of one :func:`fused_reduce_apply` call
-    (obs/roofline.py is the single source of truth — the driver's
-    ``phase_cost`` records and this annotation can never drift apart):
-    the ``aggregation`` + ``server_apply`` phases of the fused cost
-    model. The kernel's whole point is visible in the byte model: the
-    stack is read ONCE, params/momentum are one read-modify-write
-    (``SERVER_APPLY_PASSES_FUSED`` = 4 passes), and the mean-delta
-    intermediate (2 params-sized HBM passes on the unfused chain)
-    never materializes."""
-    costs = round_phase_costs(
-        k=k, steps=1, batch=1, n_coords=n_coords, compute_bytes=4,
-        step_flops=0, aggregator="weighted_mean", fused_apply=True,
-    )
-    return {
-        "flops": costs["aggregation"]["flops"]
-        + costs["server_apply"]["flops"],
-        "bytes": costs["aggregation"]["bytes"]
-        + costs["server_apply"]["bytes"],
-    }
-
-
-def delta_apply_cost(n_coords: int) -> dict:
-    """Analytic cost annotation of one :func:`fused_delta_apply` call:
-    the psum-path kernel touches the delta once and params/momentum as
-    one read-modify-write — ``SERVER_APPLY_PASSES_FUSED`` params-sized
-    HBM passes total (vs 6 on the unfused optax chain)."""
-    n = int(n_coords)
-    return {
-        "flops": 4 * n,
-        "bytes": SERVER_APPLY_PASSES_FUSED * n * 4,
-    }
-
 # one kernel tile of the flat param vector: [_SUB, _LANE] f32 = 32 KiB
 # VMEM per operand (the [K, _SUB, _LANE] stack block stays ≤ 2 MiB at
 # cohort 64) — the (8, 128)-aligned shape the TPU vector unit wants

@@ -1208,9 +1208,9 @@ def make_sharded_round_fn(model, client_cfg, dp_cfg, task, mesh, server_update,
                 apply_upload_attack,
             )
 
-            # scope name matches the obs/roofline.py cost-model phase
-            # (`attack_transform`) so device profiles join the analytic
-            # FLOP/byte model by name
+            # the scope name is what a device profile is read by: the
+            # benchmark's trace reduction (benchmark/run.py SCOPES)
+            # finds the attack's device time under it
             with jax.named_scope("round_attack_transform"):
                 deltas = apply_upload_attack(
                     deltas, byz, keys, attack, attack_scale, attack_eps,
@@ -2351,8 +2351,8 @@ def make_sequential_round_fn(model, client_cfg, dp_cfg, task, server_update,
                 )
 
                 # same scope name as the sharded engine's _wire_stack —
-                # the cost-model phase taxonomy (obs/roofline.py) is
-                # engine-invariant down to the device-trace labels
+                # the phase names are engine-invariant down to the
+                # device-trace labels
                 with jax.named_scope("round_attack_transform"):
                     stacked = apply_upload_attack(
                         stacked, jnp.asarray(byz), keys, attack, attack_scale,

@@ -30,7 +30,6 @@ from colearn_federated_learning_tpu.client.trainer import (
     make_local_train_fn,
     make_loss_fn,
     shared_weight_phase,
-    windowed_conv_share,
 )
 from colearn_federated_learning_tpu.config import DPConfig, ExperimentConfig
 from colearn_federated_learning_tpu.data import build_federated_data
@@ -62,15 +61,6 @@ from colearn_federated_learning_tpu.obs import executables as exec_mod
 from colearn_federated_learning_tpu.obs.executables import (
     ExecutableRegistry,
     HbmBudgetError,
-)
-from colearn_federated_learning_tpu.obs.roofline import (
-    PEAK_HBM_BYTES_PER_SEC,
-    analytic_lora_step_flops,
-    analytic_step_flops,
-    layout_gemm_rows,
-    mfu_basis,
-    mxu_tile_pad_fraction,
-    round_phase_costs,
 )
 from colearn_federated_learning_tpu.parallel import mesh as mesh_lib
 from colearn_federated_learning_tpu.parallel.round_engine import (
@@ -898,21 +888,6 @@ class Experiment:
                 tracer=self.tracer,
             )
         self._counters_on = obs.counters
-        # analytic per-phase FLOP/HBM-byte cost records (obs/roofline):
-        # pure function of config + realized grid, so both engines (and
-        # the fused path) log identical numbers — parity-pinned like
-        # the wire counters. Rides the counters infrastructure.
-        # Centralized synchronous rounds only: the gossip/fedbuff round
-        # programs have different phase structure and would be
-        # mis-modeled by the cohort-upload taxonomy.
-        self._phase_cost_on = (
-            obs.counters and obs.phase_cost
-            and not (self.gossip or self.fedbuff)
-        )
-        # round -> (k, steps, batch, host_input_bytes): what the
-        # dispatch path keeps; the flush makes the record of it
-        self._phase_grids: Dict[int, tuple] = {}
-        self._step_flops_cache = None
         # Federation health observatory (run.obs.population, obs/
         # population.py): population/data-plane telemetry — coverage,
         # draw split, staleness, pager/store health, fairness — folded
@@ -1190,119 +1165,6 @@ class Experiment:
             )["upload_bytes"]
             self._wire_reduction_cache = full / max(up, 1)
         return self._wire_reduction_cache
-
-    # ------------------------------------------------------------------
-    # analytic phase-cost model (obs/roofline.py)
-
-    def _compute_itemsize(self) -> int:
-        """Bytes per element at the EFFECTIVE compute precision — the
-        same bf16-if-either-dtype-is-bf16 rule as the MFU basis."""
-        basis, _ = mfu_basis(
-            self.cfg.run.compute_dtype, self.cfg.run.local_param_dtype,
-            self.cfg.run.param_dtype,
-        )
-        return 2 if basis == "bf16_peak" else 4
-
-    def _xla_step_flops(self) -> Optional[int]:
-        """XLA-counted FLOPs of one scan-free train step (fwd+bwd on one
-        batch) — the bench's ``model_tflops_per_round`` machinery, but
-        lowered from eval_shape structs so no params are materialized.
-        None when the backend exposes no cost model."""
-        from colearn_federated_learning_tpu.client.trainer import (
-            make_loss_fn,
-            normalize_input,
-        )
-
-        bs = self.cfg.client.batch_size
-        try:
-            dummy = jax.ShapeDtypeStruct(
-                (1,) + self.fed.train_x.shape[1:], self.fed.train_x.dtype
-            )
-            p_shapes = jax.eval_shape(
-                lambda d: self.model.init(
-                    jax.random.PRNGKey(0), normalize_input(d), train=False
-                )["params"],
-                dummy,
-            )
-            x_s = jax.ShapeDtypeStruct(
-                (bs,) + self.fed.train_x.shape[1:], self.fed.train_x.dtype
-            )
-            y_s = jax.ShapeDtypeStruct(
-                (bs,) + self.fed.train_y.shape[1:], self.fed.train_y.dtype
-            )
-            m_s = jax.ShapeDtypeStruct((bs,), jnp.float32)
-            step = jax.value_and_grad(make_loss_fn(self.model, self.task))
-            compiled = jax.jit(step).lower(p_shapes, x_s, y_s, m_s).compile()
-            ca = compiled.cost_analysis()
-            if isinstance(ca, list):
-                ca = ca[0]
-            if not ca or "flops" not in ca:
-                return None
-            return int(ca["flops"])
-        except Exception:
-            return None
-
-    def _train_step_flops(self) -> tuple:
-        """(flops, source) of ONE train step on one batch, cached for
-        the run. ``run.obs.phase_cost_flops`` picks the source: the
-        dense 6·P·B analytic approximation (default, zero compiles) or
-        XLA's cost model (exact, one extra compile; falls back to
-        analytic when the backend has no cost model)."""
-        if self._step_flops_cache is None:
-            coords, _ = self._param_stats()
-            bs = self.cfg.client.batch_size
-            x = self.fed.train_x
-            # token corpora: the matmul unit is a token, not an example
-            units = bs * (
-                int(x.shape[1])
-                if x.ndim == 2 and np.issubdtype(x.dtype, np.integer)
-                else 1
-            )
-            flops, source = None, "analytic"
-            if self.cfg.run.obs.phase_cost_flops == "xla":
-                flops = self._xla_step_flops()
-                if flops is not None:
-                    source = "xla"
-            if flops is None:
-                if self._lora:
-                    # adapter-aware step cost (obs/roofline.py): the
-                    # frozen base still runs the forward + the
-                    # activation-gradient backward; only the factor
-                    # weight-gradients are trainable — 6·P_adapter·B
-                    # would understate the step by ~P_full/P_adapter
-                    # and 6·P_full·B would overstate it
-                    full_coords, _ = self._full_param_stats()
-                    flops = analytic_lora_step_flops(
-                        full_coords, coords, units
-                    )
-                    source = "analytic_lora"
-                else:
-                    flops = analytic_step_flops(coords, units)
-            self._step_flops_cache = (int(flops), source)
-        return self._step_flops_cache
-
-    def _phase_cost(self, k: int, steps: int, batch: int,
-                    host_input_bytes: int) -> Dict[str, Dict[str, int]]:
-        """Analytic per-phase FLOP/byte costs for one round on its
-        REALIZED (bucketed) grid — a pure function of the config and
-        the grid, so the sharded, sequential, and fused engines record
-        identical numbers (parity-pinned in tests/test_roofline.py).
-        The dispatch path only keeps the grid's four integers
-        (``_phase_grids``); this runs when the flush writes the round's
-        `phase_cost` JSONL record."""
-        cfg = self.cfg
-        step_flops, _ = self._train_step_flops()
-        coords, _ = self._param_stats()
-        return round_phase_costs(
-            k=k, steps=steps, batch=batch, n_coords=coords,
-            compute_bytes=self._compute_itemsize(), step_flops=step_flops,
-            aggregator=cfg.server.aggregator,
-            attack=bool(self._attack_upload),
-            ledger=bool(self._ledger_on),
-            reputation=bool(cfg.server.reputation.enabled),
-            fused_apply=bool(cfg.server.fused_apply),
-            host_input_bytes=int(host_input_bytes),
-        )
 
     def _check_memory_budget(self) -> None:
         """Construction-time HBM pre-flight (VERDICT r4 missing-#4):
@@ -1888,10 +1750,9 @@ class Experiment:
         [K, 2] mask SPEC instead of the full float32 mask.
         ``build_slab=False`` skips the per-round stream slab — the fused
         chunk path gathers ONE union slab over the whole chunk instead."""
-        # named control-plane sub-spans (children of round.host_inputs
-        # in the waterfall — roofline excludes them from host_exposed
-        # totals so nothing double-counts): exactly the work the device
-        # control plane removes, attributable line by line
+        # named control-plane sub-spans (children of round.host_inputs):
+        # exactly the work the device control plane removes,
+        # attributable line by line
         with self.tracer.span("round.host_inputs.sampler"):
             if self.gossip and self._gossip_partial == 0:
                 # full participation: row i of the round tensors IS
@@ -2370,9 +2231,6 @@ class Experiment:
                 if self._bucket_ladder is not None:
                     stats["shape_bucket_steps"] = steps_g
             self._comm_stats[round_idx] = stats
-            if self._phase_cost_on:
-                self._phase_grids[round_idx] = (
-                    rows, steps_g, batch_g, stats["host_input_bytes"])
         if not place:
             # fuse>1 requires hbm placement (validate), so slab is None
             return (cohort, idx, mask, n_ex,
@@ -3127,10 +2985,6 @@ class Experiment:
                     self.shape.local_epochs,
                 ))
                 self._comm_stats[ridx] = stats
-                if self._phase_cost_on:
-                    self._phase_grids[ridx] = (
-                        len(cohort), self.shape.steps,
-                        self.shape.batch_size, 0)
                 fail = {
                     key: int(s[src]) for key, src in (
                         ("churn_unavailable", "unavailable"),
@@ -4396,78 +4250,6 @@ class Experiment:
                                           or cfg.run.param_dtype)}
                    if self._lora else {}),
             })
-        if start_round == 0 and self._phase_cost_on:
-            # the static half of the cost model (obs/roofline.py): the
-            # per-round `phase_cost` records carry only the per-grid
-            # numbers; `colearn mfu` joins the two. peak_flops follows
-            # the run's mfu_basis so a bf16 run is never decomposed
-            # against the f32 roof (or vice versa).
-            step_flops, flop_source = self._train_step_flops()
-            coords, p_bytes = self._param_stats()
-            basis, peak = mfu_basis(
-                cfg.run.compute_dtype, cfg.run.local_param_dtype,
-                cfg.run.param_dtype,
-            )
-            # cohort-layout GEMM geometry (obs/roofline.py): the rows
-            # each shared-weight train GEMM feeds the MXU under this
-            # run's layout, and the row-tile padding they waste — the
-            # attribution `colearn mfu` prints next to the waterfall
-            # (the megabatch layout's whole point is driving this pad
-            # fraction to ~0 without touching any wire shape)
-            lanes = (
-                int(self.mesh.shape[mesh_lib.CLIENT_AXIS])
-                if self.mesh is not None else 1
-            )
-            k_round = int(self._poisson_cap or cfg.server.cohort_size)
-            k_local = max(1, k_round // max(1, lanes))
-            # megabatch × LoRA runs the decomposed apply (frozen base
-            # as a closure constant), so the un-batched-weight GEMMs
-            # cover EVERY local step, not just the shared-weight step 0
-            lora_all_steps = bool(
-                cfg.model.lora.enabled
-                and cfg.run.cohort_layout == "megabatch"
-            )
-            rows = layout_gemm_rows(
-                cfg.run.cohort_layout, k_local, cfg.client.batch_size,
-                lora_all_steps=lora_all_steps,
-            )
-            self.logger.log({
-                "event": "phase_cost_model",
-                "step_flops": int(step_flops),
-                "flop_source": flop_source,
-                "n_coords": int(coords),
-                # the FULL model's coordinate count (== n_coords unless
-                # model.lora is on) — the adapter-aware step-FLOP model
-                # is a function of both, so the record carries both
-                "n_coords_full": int(self._full_param_stats()[0]),
-                "param_bytes": int(p_bytes),
-                "compute_bytes": int(self._compute_itemsize()),
-                "mfu_basis": basis,
-                "peak_flops": float(peak),
-                "peak_hbm_bytes_per_sec": float(PEAK_HBM_BYTES_PER_SEC),
-                # the device that ran — the peaks above describe
-                # roofline.PEAK_DEVICE_KIND whatever this says
-                "device_kind": jax.local_devices()[0].device_kind,
-                "n_chips": int(self.n_chips),
-                "process_index": int(self._process_index),
-                "cohort_layout": cfg.run.cohort_layout,
-                "clients_per_lane": int(k_local),
-                "gemm_rows": int(rows),
-                "lora_all_steps": lora_all_steps,
-                "mxu_tile_pad_fraction": round(
-                    mxu_tile_pad_fraction(rows), 4
-                ),
-                # whether the megabatch block trainer runs its
-                # shared-weight first step, and the share its rule reads
-                # (client/trainer.py); false off that layout
-                "windowed_conv_share": round(
-                    windowed_conv_share(state["params"]), 4
-                ),
-                "shared_weight_phase": bool(
-                    cfg.run.cohort_layout == "megabatch"
-                    and shared_weight_phase(state["params"])
-                ),
-            })
         if start_round == 0 and self._poisson:
             self.logger.log({
                 "event": "poisson_sampling",
@@ -4772,16 +4554,6 @@ class Experiment:
                     if k in record:
                         self._run_totals[k] += int(record[k])
                 self.logger.log(record)
-                grid = self._phase_grids.pop(ridx, None)
-                if grid is not None:
-                    # the analytic cost record rides next to the round
-                    # it describes — `colearn mfu` joins these with the
-                    # spans records into the waterfall
-                    self.logger.log({
-                        "event": "phase_cost", "round": ridx + 1,
-                        "process_index": int(self._process_index),
-                        "phases": self._phase_cost(*grid),
-                    })
             last_round = pending[-1][0] + 1
             self._rounds_done = max(self._rounds_done, last_round)
             pending.clear()
@@ -5100,7 +4872,7 @@ class Experiment:
             fail = self._fail_stats.pop(r, None)
             cohort = self._digest_cohorts.pop(r, None)
             for scratch in (self._async_stats, self._hier_stats,
-                            self._attack_stats, self._phase_grids):
+                            self._attack_stats):
                 scratch.pop(r, None)
             if r + 1 > window_start:
                 # rounds at or before the window start were digested

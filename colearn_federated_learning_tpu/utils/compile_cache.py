@@ -1,7 +1,7 @@
 """Persistent XLA compilation cache placement (process start-up).
 
 One rule, applied by every entry point that compiles (``cli.main``,
-``bench.py``, ``chip_smoke.py``) before its first compile:
+``benchmark/run.py``, ``chip_smoke.py``) before its first compile:
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: jax already reads the directory
   from the environment — the program sets no directory in code, so

@@ -7,6 +7,7 @@ compiles: the three child interpreters start together in one fixture
 and only import/inspect.
 """
 
+import importlib.util
 import json
 import os
 import subprocess
@@ -27,7 +28,7 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _IMPORT_PROBE = """
 import json, sys
 sys.path.insert(0, {root!r})
-import bench, chip_smoke, __graft_entry__
+import chip_smoke, __graft_entry__
 import colearn_federated_learning_tpu.cli
 import colearn_federated_learning_tpu.server.round_driver
 from jax._src import xla_bridge
@@ -105,9 +106,8 @@ def test_cache_dir_from_environment_is_left_alone(children, monkeypatch):
 
 
 def test_importing_entry_points_initialises_no_backend(children):
-    """bench's --matrix parent and __graft_entry__'s dry-run parent
-    spawn children that need the device: the import alone must not
-    take it."""
+    """__graft_entry__'s dry-run parent spawns a child that needs the
+    device: the import alone must not take it."""
     rc, stdout, stderr = children["unset"]
     assert rc == 0, stderr[-2000:]
     assert json.loads(stdout.splitlines()[-1])["backends"] == []
@@ -119,6 +119,29 @@ def test_chip_smoke_refuses_cpu_before_compiling(children):
     assert "not 'tpu'" in stderr
     assert '"ok"' not in stdout  # no result line
     assert "Compiling" not in stderr  # JAX_LOG_COMPILES=1 saw none
+
+
+@pytest.mark.parametrize("kind,known", [
+    ("TPU v5 lite", True), ("TPU v5 lite (described)", False),
+], ids=["known", "unknown"])
+def test_chip_smoke_takes_device_kinds_from_the_benchmarks_peaks(kind, known):
+    """One table of peaks in the repo: the benchmark's, which refuses a
+    device it has no row for; the smoke refuses the same devices."""
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(_ROOT, "chip_smoke.py"))
+    chip_smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(chip_smoke)
+    peaks_file = os.path.join(_ROOT, "benchmark", "harness", "peaks.json")
+    assert chip_smoke.PEAKS_FILE == peaks_file
+    with open(peaks_file) as f:
+        assert (kind in json.load(f)) == known
+    if known:
+        chip_smoke.require_known_device_kind(kind)
+    else:
+        with pytest.raises(RuntimeError, match="benchmark/harness/peaks.json"):
+            chip_smoke.require_known_device_kind(kind)
+        with pytest.raises(RuntimeError, match="peaks.json"):
+            chip_smoke.require_known_device_kind("_source")  # a note, no row
 
 
 class _Lowered:

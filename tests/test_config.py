@@ -8,30 +8,36 @@ from colearn_federated_learning_tpu.config import (
 )
 
 
+# BASELINE.json:7-11 — the five capability configs, plus the
+# 1000-client north-star scale config (BASELINE.json:5) and the
+# beyond-reference decentralized / adversarial / adapter-plane
+# showcases (vit_lora_dp: the ViT injection map under example-DP)
+NAMED_CONFIGS = sorted([
+    "mnist_fedavg_2",
+    "cifar10_fedavg_100",
+    "cifar10_fedavg_1000",
+    "femnist_fedprox_500",
+    "shakespeare_fedavg",
+    "imagenet_silo_dp",
+    "cifar10_gossip_16",
+    "cifar10_krum_byzantine",
+    "bert_lora_federated",
+    "vit_lora_dp",
+    "keye_silo_lm",  # PR 25: the sparse-expert language decoder
+    "axk1_silo_lora",  # PR 29: adapters on a frozen latent-attention base
+    "mellum2_silo_lm",  # PR 31: layers in periods, banded attention
+])
+
+
 def test_named_configs_exist():
-    # BASELINE.json:7-11 — the five capability configs, plus the
-    # 1000-client north-star scale config (BASELINE.json:5) and the
-    # beyond-reference decentralized / adversarial / adapter-plane
-    # showcases (vit_lora_dp: the ViT injection map under example-DP)
-    assert list_named_configs() == sorted([
-        "mnist_fedavg_2",
-        "cifar10_fedavg_100",
-        "cifar10_fedavg_1000",
-        "femnist_fedprox_500",
-        "shakespeare_fedavg",
-        "imagenet_silo_dp",
-        "cifar10_gossip_16",
-        "cifar10_krum_byzantine",
-        "bert_lora_federated",
-        "vit_lora_dp",
-        "keye_silo_lm",  # PR 25: the sparse-expert language decoder
-        "axk1_silo_lora",  # PR 29: adapters on a frozen latent-attention base
-        "mellum2_silo_lm",  # PR 31: layers in periods, banded attention
-    ])
-    for name in list_named_configs():
-        cfg = get_named_config(name)
-        assert cfg.name == name
-        cfg.validate()
+    assert list_named_configs() == NAMED_CONFIGS
+
+
+@pytest.mark.parametrize("name", NAMED_CONFIGS)
+def test_named_config_validates(name):
+    cfg = get_named_config(name)
+    assert cfg.name == name
+    cfg.validate()
 
 
 def test_yaml_roundtrip(tmp_path):

@@ -1,11 +1,17 @@
 """docs/CONFIG.md is generated from the live dataclasses — regenerate
 and diff so a config change can't silently leave the doc stale.
 docs/DESIGN.md's layer-map module list is checked against the real tree
-so a moved/renamed module can't silently orphan the architecture doc."""
+so a moved/renamed module can't silently orphan the architecture doc.
+``cli.py``'s usage block and every subcommand's ``--help`` are held to
+the parser, so a subcommand cannot be half removed."""
 
+import argparse
 import os
 import re
 
+import pytest
+
+from colearn_federated_learning_tpu import cli
 from colearn_federated_learning_tpu.utils.docgen import config_reference_markdown
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -44,3 +50,31 @@ def test_config_reference_is_current():
         "import config_reference_markdown; "
         "open('docs/CONFIG.md','w').write(config_reference_markdown())\""
     )
+
+
+SUBCOMMANDS = ("fit", "evaluate", "export", "configs", "store", "summarize",
+               "clients", "watch", "population", "check", "diff", "replay",
+               "preflight")
+
+
+def _subparsers(parser):
+    return next(a for a in parser._actions
+                if isinstance(a, argparse._SubParsersAction)).choices
+
+
+def test_cli_usage_block_lists_the_parsers_subcommands():
+    """The module docstring's ``colearn <sub>`` lines, the parser and
+    the list the help test runs over name the same subcommands."""
+    named = re.findall(r"^    colearn ([\w-]+)", cli.__doc__, re.M)
+    in_parser = _subparsers(cli.build_parser())
+    assert sorted(set(named)) == sorted(in_parser) == sorted(SUBCOMMANDS)
+    assert "build|info" in cli.__doc__
+    assert sorted(_subparsers(in_parser["store"])) == ["build", "info"]
+
+
+@pytest.mark.parametrize("sub", [*SUBCOMMANDS, "store build", "store info"])
+def test_cli_subcommand_help(sub, capsys):
+    with pytest.raises(SystemExit) as done:
+        cli.main([*sub.split(), "--help"])
+    assert done.value.code == 0
+    assert f"usage: colearn {sub}" in capsys.readouterr().out

@@ -3,8 +3,7 @@ AOT registry records + HBM watermarks, the bitwise no-op contract,
 fingerprint/flop rerun parity across {sharded, sequential} × {fuse 1, 4},
 CPU degradation to partial records, the OOM preflight (driver + CLI +
 budget abort), retrace forensics on the shape-bucket ladder, and the
-measured-vs-analytic flop drift surfaces (`colearn mfu` column,
-`bench-report` gate)."""
+registry's FLOP count against the benchmark's shape count."""
 
 import json
 import os
@@ -21,12 +20,6 @@ from colearn_federated_learning_tpu.obs.executables import (
     HbmBudgetError,
     format_preflight_report,
     instrument,
-)
-from colearn_federated_learning_tpu.obs.roofline import (
-    bench_report,
-    format_mfu_report,
-    load_bench_history,
-    mfu_report,
 )
 from colearn_federated_learning_tpu.obs.summary import (
     format_summary,
@@ -347,61 +340,85 @@ def test_summarize_pre_pr20_log_never_keyerrors(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# measured-vs-analytic drift: mfu column + bench gate
+# the two FLOP answers that remain, against each other
 # ---------------------------------------------------------------------------
 
-
-def test_mfu_measured_column_and_drift(tmp_path):
-    _, _, records = _fit(_tiny_cfg(out=str(tmp_path)))
-    report = mfu_report(records)
-    meas = report["measured"]
-    assert meas["round_program"] == "round.sync"
-    assert meas["round_flops_measured"] > 0
-    assert meas["flop_model_drift_pct"] is not None
-    table = format_mfu_report(report)
-    assert "measured" in table and "drift" in table
-    # a pre-PR-20 log renders the column n/a, never a KeyError
-    old = [r for r in records if r.get("event") != "executable_compiled"]
-    report_old = mfu_report(old)
-    assert report_old["measured"] is None
-    assert "measured flops: n/a" in format_mfu_report(report_old)
-
-
-def _write_history(tmp_path, drifts):
-    for i, drift in enumerate(drifts, start=1):
-        extra = {} if drift is None else {"flop_model_drift_pct": drift}
-        (tmp_path / f"BENCH_r{i:02d}.json").write_text(json.dumps(
-            {"n": 1, "parsed": {"value": 3.5, "extra": extra}}))
-    return str(tmp_path)
-
-
-def test_flop_drift_gate_fires_only_over_budget(tmp_path):
-    entries = load_bench_history(_write_history(tmp_path, [None, -21.7]))
-    assert entries[0]["flop_model_drift_pct"] is None
-    assert entries[1]["flop_model_drift_pct"] == -21.7
-    assert bench_report(
-        entries, {"flop_drift_pct_max": 40.0})["violations"] == []
-    # the ceiling is on |drift|: -21.7 trips a 10 budget
-    violations = bench_report(
-        entries, {"flop_drift_pct_max": 10.0})["violations"]
-    assert len(violations) == 1
-    assert "flop_model_drift_pct" in violations[0]
+# XLA's cost analysis counts the body of a `while` once, whatever its
+# trip count (CPU, PR 47: the compiled programs' known_trip_count), so
+# the registry's figure is that of ONE trip through the round program's
+# nested loops, and "the same work" from shapes is one trip's examples:
+#   dry_r18_fused: fused rounds (2) x local steps (2) x clients of the
+#     megabatch block (4); a trip trains one client's batch.
+#   dry_vit_dp: clients (2) x local steps (2) x DP microbatches (2); a
+#     trip trains one microbatch.
+# `reading` is registry / shape count as read at commit 838c17d on the
+# CPU backend. The ResNet's sits inside the 40 % band around 1 that
+# `flop_drift_pct_max` used to keep. The ViT's does not, for reasons
+# that are the shape count's own rules (benchmark/harness/flops.py):
+# DP-SGD's norm pass and clipped pass (privacy/dp.py: two Gram matrices
+# and one weighted product a kernel, per-example gradients for every
+# other leaf) are re-evaluation it leaves out, and at hidden 32 the
+# elementwise work it leaves out as "under 1 %" at published widths
+# (LayerNorm, GELU, softmax, the float32 clip-and-noise over every
+# leaf, which XLA counts at one FLOP an element) is of the order of the
+# products. So the test states that reading and keeps the band around it.
+_SHAPE_COUNT_CASES = {
+    "dry_resnet": dict(
+        workload="dry_r18_fused", reading=0.97,
+        trip_examples=lambda cfg: cfg.client.batch_size),
+    "dry_vit": dict(
+        workload="dry_vit_dp", reading=2.78,
+        trip_examples=lambda cfg: cfg.dp.microbatch_size),
+}
+_FLOP_BAND = 0.40
 
 
-def test_flop_drift_gate_na_tolerant(tmp_path):
-    # a history that predates the extra (r01–r19): never a gate
-    entries = load_bench_history(_write_history(tmp_path, [None, None]))
-    assert bench_report(
-        entries, {"flop_drift_pct_max": 0.001})["violations"] == []
+@pytest.mark.parametrize("config_name", sorted(_SHAPE_COUNT_CASES))
+def test_registry_flops_agree_with_the_shape_count(config_name):
+    """``cost_analysis`` flops of the compiled round program (what the
+    registry harvests) against forward MACs x 6 x examples from
+    ``benchmark/flops/<family>.py``: a loop restructured, a pass added
+    to the trainer or a family's count gone wrong moves one and not the
+    other."""
+    import sys
 
+    beside_the_benchmarks_tests = os.path.join(os.path.dirname(__file__),
+                                               "benchmark")
+    if beside_the_benchmarks_tests not in sys.path:
+        sys.path.insert(0, beside_the_benchmarks_tests)
+    import bench_paths  # noqa: F401  (puts benchmark/ on sys.path)
+    from harness import catalog, flops, window
 
-def test_checked_in_history_passes_repo_budgets(capsys):
-    budgets = json.load(open("BENCH_BUDGETS.json"))
-    assert "flop_drift_pct_max" in budgets
-    assert cli.main(["bench-report", "--dir",
-                     "tests/fixtures/bench_history",
-                     "--baseline", "BENCH_BUDGETS.json"]) == 0
-    assert "gates: PASS" in capsys.readouterr().out
+    from colearn_federated_learning_tpu.config import resolve_config
+
+    case = _SHAPE_COUNT_CASES[config_name]
+    cell = catalog.load_workload(case["workload"])
+    assert cell["config"] == config_name
+    config = catalog.load_config(config_name)
+    cfg = resolve_config(cell["named_config"],
+                         catalog.experiment_overrides(cell, config, 0))
+    exp = Experiment(cfg, echo=False)
+    run = window.Run(exp, 0)
+    exec_mod.install(exp._exec_reg)
+    try:
+        run.start()
+        run.first_dispatch()
+    finally:
+        exp._stop_prefetch()
+        exec_mod.uninstall()
+    compiled = [r for r in exp._exec_reg.drain_records()
+                if r["event"] == "executable_compiled"
+                and r["name"].startswith("round.")]
+    assert len(compiled) == 1, [r["name"] for r in compiled]
+    if compiled[0]["flops"] is None:
+        pytest.skip(f"backend {compiled[0]['backend']!r} gives no cost model")
+    shape_count = (flops.train_flops_per_example(config["flops"])
+                   * case["trip_examples"](cfg))
+    ratio = compiled[0]["flops"] / shape_count
+    assert abs(ratio / case["reading"] - 1.0) <= _FLOP_BAND, (
+        f"{compiled[0]['name']}: registry {compiled[0]['flops']:.4g} FLOPs, "
+        f"shape count of one trip {shape_count:.4g}: ratio {ratio:.3f}, "
+        f"read {case['reading']} at 838c17d")
 
 
 def test_lowering_runs_on_a_frame_with_a_chunk_of_its_own():

@@ -3,6 +3,7 @@
 import json
 
 import numpy as np
+import pytest
 
 from colearn_federated_learning_tpu.config import DataConfig
 from colearn_federated_learning_tpu.data import build_federated_data
@@ -68,19 +69,20 @@ def test_char_vocab_reserves_unk():
     assert v["a"] == 1  # most frequent first
 
 
-def test_all_named_configs_build_data():
+@pytest.mark.parametrize("name", [
+    "mnist_fedavg_2", "cifar10_fedavg_100", "femnist_fedprox_500",
+    "shakespeare_fedavg", "imagenet_silo_dp"])
+def test_named_config_builds_data(name):
     """Every advertised BASELINE config must produce a usable federation
     (regression: femnist_fedprox_500 used to crash at partition time)."""
     from colearn_federated_learning_tpu.config import get_named_config
 
-    for name in ["mnist_fedavg_2", "cifar10_fedavg_100", "femnist_fedprox_500",
-                  "shakespeare_fedavg", "imagenet_silo_dp"]:
-        cfg = get_named_config(name)
-        kwargs = dict(cfg.model.kwargs)
-        if "image_size" in kwargs:
-            # the partition is what is asserted, and it does not read the
-            # pixels: 2,048 images of 224 x 224 are half a minute to draw
-            kwargs["image_size"] = 32
-        fed = build_federated_data(cfg.data, seed=0, **kwargs)
-        assert fed.num_clients == cfg.data.num_clients, name
-        assert min(len(ix) for ix in fed.client_indices) >= 1, name
+    cfg = get_named_config(name)
+    kwargs = dict(cfg.model.kwargs)
+    if "image_size" in kwargs:
+        # the partition is what is asserted, and it does not read the
+        # pixels: 2,048 images of 224 x 224 are half a minute to draw
+        kwargs["image_size"] = 32
+    fed = build_federated_data(cfg.data, seed=0, **kwargs)
+    assert fed.num_clients == cfg.data.num_clients
+    assert min(len(ix) for ix in fed.client_indices) >= 1

@@ -769,7 +769,7 @@ def test_trace_export_merges_per_host_fragments(tmp_path):
     assert set(lanes) == {0, 1} and "host 1" in lanes[1]
 
 
-def test_spans_and_phase_cost_records_carry_process_index(tmp_path):
+def test_spans_records_carry_process_index(tmp_path):
     cfg = get_named_config("mnist_fedavg_2")
     cfg.apply_overrides({
         "server.num_rounds": 2, "server.eval_every": 0,
@@ -785,11 +785,71 @@ def test_spans_and_phase_cost_records_carry_process_index(tmp_path):
     Experiment(cfg, echo=False).fit()
     path = os.path.join(str(tmp_path), f"{cfg.name}.metrics.jsonl")
     recs = load_records(path)
-    tagged = [r for r in recs
-              if r.get("event") in ("spans", "phase_cost",
-                                    "phase_cost_model")]
-    assert tagged, "expected spans + phase_cost records"
+    tagged = [r for r in recs if r.get("event") == "spans"]
+    assert tagged, "expected spans records"
     assert all(r.get("process_index") == 0 for r in tagged)
+
+
+# two rounds of `mnist_fedavg_2` as commit 838c17d logged them (cohort 2,
+# batch 8), with the two record types that commit was the last to write
+_LOG_OF_838C17D = (
+    '{"event": "precision", "param_dtype": "float32", "compute_dtype": '
+    '"float32", "local_param_dtype": "float32", "fused_apply": false, '
+    '"double_buffer": true, "control_plane": "host", '
+    '"time": 1791202926.6288173, "schema": 1}',
+    '{"round": 1, "train_loss": 2.46985125541687, "examples": 32.0, '
+    '"upload_bytes": 493648, "download_bytes": 493648, '
+    '"host_input_bytes": 152, "time": 1791202927.8084252, "schema": 1}',
+    '{"round": 2, "train_loss": 2.4448280334472656, "examples": 32.0, '
+    '"upload_bytes": 493648, "download_bytes": 493648, '
+    '"host_input_bytes": 152, "rounds_per_sec": 1.7245, '
+    '"client_updates_per_sec_per_chip": 3.4491, '
+    '"time": 1791202927.8085961, "schema": 1}',
+    '{"event": "spans", "round": 2, "phases": {"round": {"count": 2, '
+    '"total_ms": 1122.298, "max_ms": 1120.954, "self_ms": 0.109}}, '
+    '"process_index": 0, "time": 1791202927.8088, "schema": 1}',
+    '{"event": "run_summary", "rounds": 2, "wall_time_sec": 13.079, '
+    '"compiles": 51, "compile_ms": 4410.381, "upload_bytes": 987296, '
+    '"download_bytes": 987296, "time": 1791202927.886285, "schema": 1}',
+)
+_RECORDS_OF_838C17D = {
+    "phase_cost": (
+        '{"event": "phase_cost", "round": 1, "process_index": 0, "phases": '
+        '{"local_train": {"flops": 11847552, "bytes": 3949336}, '
+        '"aggregation": {"flops": 246824, "bytes": 987296}, "server_apply": '
+        '{"flops": 246824, "bytes": 1480944}}, "time": 1791202927.808547, '
+        '"schema": 1}'),
+    "phase_cost_model": (
+        '{"event": "phase_cost_model", "step_flops": 2961888, "flop_source": '
+        '"analytic", "n_coords": 61706, "n_coords_full": 61706, '
+        '"param_bytes": 246824, "compute_bytes": 4, "mfu_basis": "f32_peak", '
+        '"peak_flops": 98500000000000.0, "peak_hbm_bytes_per_sec": '
+        '819000000000.0, "device_kind": "cpu", "n_chips": 1, '
+        '"process_index": 0, "cohort_layout": "spatial", '
+        '"clients_per_lane": 2, "gemm_rows": 8, "lora_all_steps": false, '
+        '"mxu_tile_pad_fraction": 0.9375, "windowed_conv_share": 0.0389, '
+        '"shared_weight_phase": false, "time": 1791202926.6485455, '
+        '"schema": 1}'),
+}
+
+
+@pytest.mark.parametrize("record", sorted(_RECORDS_OF_838C17D))
+@pytest.mark.parametrize("reader,rounds_shown", [
+    (["summarize"], "rounds: 2"), (["watch", "--once"], "round 2"),
+], ids=["summarize", "watch"])
+def test_logs_of_earlier_versions_still_read(tmp_path, capsys, reader,
+                                             rounds_shown, record):
+    """A record type no reader knows any more is passed over: an
+    append-only log outlives the version that wrote it."""
+    lines = list(_LOG_OF_838C17D)
+    lines.insert(2, _RECORDS_OF_838C17D[record])
+    path = tmp_path / "old.metrics.jsonl"
+    path.write_text("\n".join(lines) + "\n")
+    assert cli.main([*reader, str(path)]) == 0
+    out = capsys.readouterr()
+    assert rounds_shown in out.out, out.out
+    # its phases are FLOP counts, not spans: no row of the timing table
+    assert "local_train" not in out.out and not out.err
 
 
 # ---------------------------------------------------------------------------

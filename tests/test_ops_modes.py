@@ -1,5 +1,5 @@
 """Ops-mode coverage (SURVEY.md §5 tracing/sanitize): the --profile and
---sanitize paths must actually execute, including the bench configuration
+--sanitize paths must actually execute, including the benchmark's configuration
 where out_dir is empty (profile falls back to cwd-relative)."""
 
 import os
@@ -42,7 +42,7 @@ def test_profile_round_writes_trace(tmp_path):
 
 
 def test_profile_round_with_empty_out_dir(tmp_path, monkeypatch):
-    """bench.py runs with out_dir=''; the trace must land under cwd, not '/'."""
+    """benchmark/run.py runs with out_dir=''; the trace must land under cwd, not '/'."""
     monkeypatch.chdir(tmp_path)
     cfg = _tiny_cfg(None, profile_round=0)
     exp = Experiment(cfg, echo=False)
